@@ -25,9 +25,10 @@ push_iters·rectangle), traced launches per class, and wall time.  The
 gated property is frontier-proportionality: sorted resolution work must
 stay strictly under the scatter rectangle whenever push iterations ran,
 the sorted/scatter work ratio must not regress vs the baseline, and the
-in-kernel permutation gather must move frontier-proportional bytes —
-``gather_work`` strictly under ``push_iters · n_pad · width`` with the
-scatter path reporting exactly 0 (it performs no permutation gather).
+permutation gather must read only real slots — ``gather_work`` (one slot
+list of the dst-major rectangle per push iteration) strictly under
+``push_iters · n_pad · width`` with the scatter path reporting exactly 0
+(it performs no permutation gather).
 
 ``--engines pallas`` also runs the batched-throughput section (DESIGN.md
 §9): a B-source sweep of one query shape served sequentially (the source
@@ -218,9 +219,10 @@ def bench_resolution(g, gname: str, weighted: bool, name: str) -> dict:
     edge work — sorted must stay frontier-proportional (Σ nnz of the
     resolution tiles actually processed), strictly under the scatter path's
     `push_iters · n_pad · width` rectangle cost, with bit-identical values —
-    and GATHER work: the candidate slots the in-kernel permutation gather
-    reads, strictly under the full rectangle per push iteration (skipped
-    tiles move zero bytes) and 0 under scatter (no permutation gather).
+    and GATHER work: the candidate slots the permutation gather reads (the
+    real slots of the dst-major rectangle), strictly under the full
+    rectangle per push iteration and 0 under scatter (no permutation
+    gather).
     Wall time is reported, never gated (interpret-mode CPU noise)."""
     from repro.graph.structure import push_resolution_cached
     from repro.kernels import edge_reduce as er
@@ -959,16 +961,16 @@ def compare_baseline(current: dict, baseline: dict,
                     f"{key}: sorted resolution work "
                     f"{r['resolve_work_sorted']:.0f} ≥ push_iters·|E| = "
                     f"{full_nnz:.0f} — tile compaction disengaged")
-            # in-kernel gather bounds (DESIGN.md §10): the permutation
-            # gather must be frontier-proportional — strictly under the
-            # full `push_iters · n_pad · width` rectangle it replaced —
-            # and the scatter path performs no permutation gather at all.
+            # gather bounds (DESIGN.md §10): the permutation gather reads
+            # the real slots only — strictly under the padded
+            # `push_iters · n_pad · width` rectangle — and the scatter path
+            # performs no permutation gather at all.
             full_rect = r["push_iters"] * r.get("rectangle", 0)
             if full_rect and not (r["gather_work_sorted"] < full_rect):
                 errors.append(
                     f"{key}: gather work {r['gather_work_sorted']:.0f} ≥ "
-                    f"push_iters·rectangle = {full_rect:.0f} — the in-kernel "
-                    "gather stopped skipping tiles")
+                    f"push_iters·rectangle = {full_rect:.0f} — the "
+                    "gather reads padding slots")
             if "gather_work_scatter" in r and r["gather_work_scatter"] != 0:
                 errors.append(
                     f"{key}: scatter path reports gather work "

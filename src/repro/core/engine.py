@@ -125,11 +125,11 @@ class ExecStats:
                                     # "scatter", 0 on pull iterations;
                                     # summed over shards when sharded)
     gather_work: float = 0.0        # candidate slots read through the
-                                    # in-kernel permutation gather (pallas
-                                    # engines; equals resolve_work under
-                                    # "sorted" — skipped tiles move zero
-                                    # bytes — and 0 under "scatter", which
-                                    # performs no permutation gather)
+                                    # permutation gather (pallas engines;
+                                    # the dst-major rectangle's real slots
+                                    # per push iteration under "sorted", 0
+                                    # under "scatter", which performs no
+                                    # permutation gather)
     shards: int = 0                 # shard count of the sharded engines
                                     # (distributed / pallas_sharded)
     shard_launches: int = 0         # traced pallas launches PER SHARD

@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import shard_map
 from repro.core.fusion import FusedRound, Lex, Prim
 from repro.graph import segment
 from repro.graph.partition import partition_edges
@@ -706,10 +705,14 @@ def iterate_distributed(g: Graph, comps, plans, mesh, axes=("data",),
                 active_n[None])
 
     pspec = P(axes)
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(pspec, pspec, pspec, pspec, pspec),
-                   out_specs=(tuple(P() for _ in comps), P(axes), P(axes),
-                              P(axes), P(axes), P(axes)))
+    # check_vma off: the shard-local work carry varies over the mesh axis
+    # while the loop's initial carry does not; replication of k is asserted
+    # on the host instead.
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(pspec, pspec, pspec, pspec, pspec),
+                       out_specs=(tuple(P() for _ in comps), P(axes),
+                                  P(axes), P(axes), P(axes), P(axes)),
+                       check_vma=False)
     state, k, work, div, resid, active_n = fn(
         part.src, part.dst, part.weight, part.capacity, part.mask)
     k_host = np.asarray(k)

@@ -47,7 +47,7 @@ from repro.core.guard import GraphValidationError
 from repro.graph import structure
 from repro.graph.structure import (
     BlockedELL, Graph, PushResolution, _check_edge_arrays, _fill_order_slots,
-    _padded_width, from_edges)
+    _padded_width, from_edges, slot_list)
 
 # Global patch/rebuild accounting (bench + tests; reset like SWEEP_STATS).
 MUTATION_STATS = {
@@ -142,12 +142,14 @@ def _patch_ell(ell: BlockedELL, row_old, k_old, keep,
     if row_ins.size:
         np.add.at(tile_nnz, (np.asarray(row_ins, np.int64) // bv,
                              k_ins // be), 1)
+    pos, nbr = slot_list(nbrs, mask)
     patched = BlockedELL(
         n=ell.n, n_pad=ell.n_pad, width=ell.width,
         block_v=bv, block_e=be,
         nbrs=jnp.asarray(nbrs), weight=jnp.asarray(ws),
         capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
-        tile_nnz=jnp.asarray(tile_nnz), direction=ell.direction)
+        tile_nnz=jnp.asarray(tile_nnz), slot_pos=jnp.asarray(pos),
+        slot_nbr=jnp.asarray(nbr), direction=ell.direction)
     return patched, k_ins
 
 
@@ -188,14 +190,15 @@ def _resolution_from_slots(n, src, dst, k_in, k_out, w_in, w_out,
     contrib = np.full((n_tiles, c_max), -1, dtype=np.int32)
     slot = np.arange(r_ids.size) - np.searchsorted(r_ids, r_ids)
     contrib[r_ids, slot] = s_ids
+    in2out = in2out.astype(np.int32)
+    pos, src_pos = slot_list(in2out, valid)
     return PushResolution(
         n=n, n_pad=n_pad, width=w_in, out_width=w_out,
-        block_v=block_v, block_e=block_e,
-        in2out=jnp.asarray(in2out.astype(np.int32)),
-        valid=jnp.asarray(valid),
-        src_tile=jnp.asarray(src_tile.astype(np.int32)),
+        block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
+        src_tile=src_tile.astype(np.int32),
         tile_nnz=jnp.asarray(tile_nnz),
-        contrib=jnp.asarray(contrib))
+        contrib=jnp.asarray(contrib),
+        slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos))
 
 
 def mutate_edges(g: Graph, insert=None, delete=None, *,

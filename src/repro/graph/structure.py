@@ -16,6 +16,7 @@ import weakref
 from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -339,6 +340,8 @@ class BlockedELL:
     capacity: jnp.ndarray   # [n_pad, width] float32
     mask: jnp.ndarray       # [n_pad, width] bool
     tile_nnz: jnp.ndarray   # [n_pad/block_v, width/block_e] int32
+    slot_pos: jnp.ndarray   # [E] int32 flat positions of the real slots
+    slot_nbr: jnp.ndarray   # [E] int32 their neighbour ids (slot_list)
     direction: str = "in"   # "in" (rows = dst, pull) | "out" (rows = src, push)
 
     @property
@@ -380,12 +383,31 @@ def _fill_order_slots(row_of: np.ndarray, n: int) -> np.ndarray:
     return out
 
 
-def to_blocked_ell(g: Graph, block_v: int = 8, block_e: int = 128,
-                   direction: str = "in") -> BlockedELL:
-    """Build the blocked-ELL layout keyed by dst (``direction="in"``, the
-    pull sweep's predecessor lists) or by src (``direction="out"``, the push
-    sweep's successor lists).  Both directions carry the same per-edge
-    weight/capacity so the synthesized P functions see identical edges."""
+def slot_list(idx, mask):
+    """The real slots of an [n_pad, width] layout as ``(pos, idx)``: their
+    flat positions, ascending, and the value of the index rectangle ``idx``
+    at each.  The sweeps gather per-slot values through this list
+    (``edge_reduce.gather_slots``): a TPU gather costs per index, and a
+    rectangle padded to the max degree holds mostly padding."""
+    pos = np.flatnonzero(np.asarray(mask))
+    return (pos.astype(np.int32),
+            np.asarray(idx).reshape(-1)[pos].astype(np.int32))
+
+
+def stack_slot_lists(lists, size: int):
+    """Per-shard ``slot_list`` pairs stacked to ``[k, E_max]``.  A shorter
+    list pads with distinct positions ≥ ``size`` (the flat rectangle size),
+    which ``gather_slots`` drops, and index 0."""
+    e_max = max(p.shape[0] for p, _ in lists)
+    pos = np.stack([np.concatenate([p, size + np.arange(e_max - p.shape[0])])
+                    for p, _ in lists]).astype(np.int32)
+    idx = np.stack([np.pad(i, (0, e_max - i.shape[0])) for _, i in lists])
+    return pos, idx.astype(np.int32)
+
+
+def _blocked_ell_host(g: Graph, block_v: int, block_e: int, direction: str):
+    """The numpy arrays of ``to_blocked_ell``: (width, n_pad, nbrs, weight,
+    capacity, mask, tile_nnz)."""
     src, dst, w, c = g.host_edges()
     n = g.n
     if direction == "in":
@@ -408,11 +430,25 @@ def to_blocked_ell(g: Graph, block_v: int = 8, block_e: int = 128,
     tile_nnz = mask.reshape(n_pad // block_v, block_v,
                             width // block_e, block_e) \
         .sum(axis=(1, 3)).astype(np.int32)
-    return BlockedELL(n=n, n_pad=n_pad, width=width,
+    return width, n_pad, nbrs, ws, cs, mask, tile_nnz
+
+
+def to_blocked_ell(g: Graph, block_v: int = 8, block_e: int = 128,
+                   direction: str = "in") -> BlockedELL:
+    """Build the blocked-ELL layout keyed by dst (``direction="in"``, the
+    pull sweep's predecessor lists) or by src (``direction="out"``, the push
+    sweep's successor lists).  Both directions carry the same per-edge
+    weight/capacity so the synthesized P functions see identical edges."""
+    width, n_pad, nbrs, ws, cs, mask, tile_nnz = _blocked_ell_host(
+        g, block_v, block_e, direction)
+    pos, nbr = slot_list(nbrs, mask)
+    return BlockedELL(n=g.n, n_pad=n_pad, width=width,
                       block_v=block_v, block_e=block_e,
                       nbrs=jnp.asarray(nbrs), weight=jnp.asarray(ws),
                       capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
-                      tile_nnz=jnp.asarray(tile_nnz), direction=direction)
+                      tile_nnz=jnp.asarray(tile_nnz),
+                      slot_pos=jnp.asarray(pos), slot_nbr=jnp.asarray(nbr),
+                      direction=direction)
 
 
 _ELL_CACHE: dict = {}
@@ -477,12 +513,21 @@ class ShardedELL:
     mask: jnp.ndarray       # [k, n_pad, width] bool
     tile_nnz: jnp.ndarray   # [k, n_pad/block_v, width/block_e] int32
     row_deg: jnp.ndarray    # [k, n_pad] float32 real slots per row
+    slot_pos: jnp.ndarray   # [k, E_max] int32 (stack_slot_lists)
+    slot_nbr: jnp.ndarray   # [k, E_max] int32
     num_edges: int          # Σ real edges across shards (== graph |E|)
+
+
+def _put(a, sharding):
+    """Host array → device: the default device, or split across a mesh by
+    ``sharding`` straight from the host (a stacked per-shard layout staged
+    whole on one chip first would need k chips' worth of its memory)."""
+    return jnp.asarray(a) if sharding is None else jax.device_put(a, sharding)
 
 
 def to_sharded_ell(g: Graph, k: int, strategy: str = "contiguous",
                    block_v: int = 8, block_e: int = 128,
-                   direction: str = "in") -> ShardedELL:
+                   direction: str = "in", sharding=None) -> ShardedELL:
     """Build the stacked per-shard blocked-ELL layout of a k-way vertex-cut.
 
     Each shard's layout is built by the exact single-device rules
@@ -490,34 +535,40 @@ def to_sharded_ell(g: Graph, k: int, strategy: str = "contiguous",
     the widest shard; a shard's local reduction over its slice is therefore
     bit-identical to a single-device sweep over that shard's edge subset,
     which is what makes the cross-shard monoid combine exact (DESIGN.md
-    §11)."""
+    §11).  ``sharding`` (a sharding of the leading shard axis) places every
+    array across the mesh; None keeps them on the default device."""
     from repro.graph.partition import shard_subgraphs  # lazy: partition
     # imports this module at top level
     subs = shard_subgraphs(g, k, strategy)
-    ells = [to_blocked_ell(sg, block_v=block_v, block_e=block_e,
-                           direction=direction) for sg in subs]
-    width = max(e.width for e in ells)
-    n_pad = ells[0].n_pad
+    ells = [_blocked_ell_host(sg, block_v, block_e, direction)
+            for sg in subs]
+    width = max(e[0] for e in ells)
+    n_pad = ells[0][1]
     n_i, n_j = n_pad // block_v, width // block_e
 
     def widen(a, fill):
-        out = np.full((n_pad, width), fill, dtype=np.asarray(a).dtype)
-        out[:, :a.shape[1]] = np.asarray(a)
+        if a.shape[1] == width:
+            return a
+        out = np.full((n_pad, width), fill, dtype=a.dtype)
+        out[:, :a.shape[1]] = a
         return out
 
-    nbrs = np.stack([widen(e.nbrs, 0) for e in ells])
-    ws = np.stack([widen(e.weight, 0.0) for e in ells])
-    cs = np.stack([widen(e.capacity, 0.0) for e in ells])
-    mask = np.stack([widen(e.mask, False) for e in ells])
+    nbrs = np.stack([widen(e[2], 0) for e in ells])
+    ws = np.stack([widen(e[3], 0.0) for e in ells])
+    cs = np.stack([widen(e[4], 0.0) for e in ells])
+    mask = np.stack([widen(e[5], False) for e in ells])
     tile_nnz = mask.reshape(k, n_i, block_v, n_j, block_e) \
         .sum(axis=(2, 4)).astype(np.int32)
     row_deg = mask.sum(axis=2).astype(np.float32)
+    slot_pos, slot_nbr = stack_slot_lists(
+        [slot_list(nb, m) for nb, m in zip(nbrs, mask)], n_pad * width)
     return ShardedELL(
         k=k, n=g.n, n_pad=n_pad, width=width, block_v=block_v,
         block_e=block_e, direction=direction, strategy=strategy,
-        nbrs=jnp.asarray(nbrs), weight=jnp.asarray(ws),
-        capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
-        tile_nnz=jnp.asarray(tile_nnz), row_deg=jnp.asarray(row_deg),
+        nbrs=_put(nbrs, sharding), weight=_put(ws, sharding),
+        capacity=_put(cs, sharding), mask=_put(mask, sharding),
+        tile_nnz=_put(tile_nnz, sharding), row_deg=_put(row_deg, sharding),
+        slot_pos=_put(slot_pos, sharding), slot_nbr=_put(slot_nbr, sharding),
         num_edges=int(mask.sum()))
 
 
@@ -526,19 +577,20 @@ _SHARDED_ELL_CACHE: dict = {}
 
 def sharded_ell_cached(g: Graph, k: int, strategy: str = "contiguous",
                        block_v: int = 8, block_e: int = 128,
-                       direction: str = "in") -> ShardedELL:
+                       direction: str = "in", sharding=None) -> ShardedELL:
     """Memoized ``to_sharded_ell`` — cached per (graph, k, strategy, tile
-    shape, direction) exactly like ``blocked_ell_cached`` (identity key,
-    weakref-guarded, finalizer-evicted), so repeated sharded queries never
-    re-partition or re-pad."""
-    key = (id(g), k, strategy, block_v, block_e, direction)
+    shape, direction, sharding) exactly like ``blocked_ell_cached``
+    (identity key, weakref-guarded, finalizer-evicted), so repeated sharded
+    queries never re-partition or re-pad."""
+    key = (id(g), k, strategy, block_v, block_e, direction, sharding)
     hit = _SHARDED_ELL_CACHE.get(key)
     if hit is not None:
         ref, ell = hit
         if ref() is g:
             return ell
     ell = to_sharded_ell(g, k, strategy=strategy, block_v=block_v,
-                         block_e=block_e, direction=direction)
+                         block_e=block_e, direction=direction,
+                         sharding=sharding)
     _SHARDED_ELL_CACHE[key] = (weakref.ref(g), ell)
     weakref.finalize(g, _SHARDED_ELL_CACHE.pop, key, None)
     return ell
@@ -584,11 +636,13 @@ class PushResolution:
     out_width: int          # the out rectangle's width (gather domain)
     block_v: int
     block_e: int
-    in2out: jnp.ndarray     # [n_pad, width] int32 flat out-rectangle index
-    valid: jnp.ndarray      # [n_pad, width] bool
-    src_tile: jnp.ndarray   # [n_pad, width] int32 flat out-tile id
+    in2out: np.ndarray      # [n_pad, width] int32 flat out-rectangle index
+    valid: np.ndarray       # [n_pad, width] bool
+    src_tile: np.ndarray    # [n_pad, width] int32 flat out-tile id
     tile_nnz: jnp.ndarray   # [n_pad/block_v, width/block_e] int32
     contrib: jnp.ndarray    # [n_tiles, c_max] int32 out-tile ids, −1 pad
+    slot_pos: jnp.ndarray   # [E] int32 flat positions of the valid slots
+    slot_src: jnp.ndarray   # [E] int32 their in2out (slot_list)
 
 
 def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
@@ -614,35 +668,34 @@ def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
     w_out = max(_padded_width(np.bincount(src, minlength=n), block_e),
                 int(min_out_width))
     n_pad = ((n + block_v - 1) // block_v) * block_v
-    in2out = np.zeros((n_pad, w_in), dtype=np.int64)
-    valid = np.zeros((n_pad, w_in), dtype=bool)
-    k_out = _fill_order_slots(src, n)
-    k_in = _fill_order_slots(dst, n)
-    in2out[dst, k_in] = src.astype(np.int64) * w_out + k_out
-    valid[dst, k_in] = True
     if n_pad * w_out >= 2 ** 31:
         raise ValueError(
             f"out rectangle {n_pad}×{w_out} overflows int32 flat indices; "
             "the dst-sorted resolution layout needs an int64 gather path "
             "for graphs this hub-heavy")
-    n_j_out = w_out // block_e
-    out_row = in2out // w_out
-    out_col = in2out % w_out
-    src_tile = (out_row // block_v) * n_j_out + out_col // block_e
-    tile_nnz = valid.reshape(n_pad // block_v, block_v,
-                             w_in // block_e, block_e) \
-        .sum(axis=(1, 3)).astype(np.int32)
-    # Contributing out-tile lists: for each resolution tile, the unique
-    # out-layout tiles whose real slots land in it (one host pass over the
-    # edges).  Per-edge tile coordinates need no rectangle materialisation:
-    # the edge at dst-major slot (dst, k_in) sits in resolution tile
-    # (dst//block_v, k_in//block_e) and came from out-tile
-    # (src//block_v, k_out//block_e).
+    k_out = _fill_order_slots(src, n)
+    k_in = _fill_order_slots(dst, n)
+    # Per-edge tile coordinates: the edge at dst-major slot (dst, k_in) sits
+    # in resolution tile (dst//block_v, k_in//block_e) and came from
+    # out-tile (src//block_v, k_out//block_e).  The rectangles are written
+    # per edge; nothing is computed over the padded slots.
     n_j_in = w_in // block_e
+    n_j_out = w_out // block_e
     n_tiles = (n_pad // block_v) * n_j_in
     n_out_tiles = (n_pad // block_v) * n_j_out
     r_tile = (dst // block_v).astype(np.int64) * n_j_in + k_in // block_e
     s_tile = (src // block_v).astype(np.int64) * n_j_out + k_out // block_e
+    in2out = np.zeros((n_pad, w_in), dtype=np.int32)
+    valid = np.zeros((n_pad, w_in), dtype=bool)
+    src_tile = np.zeros((n_pad, w_in), dtype=np.int32)
+    in2out[dst, k_in] = src.astype(np.int64) * w_out + k_out
+    valid[dst, k_in] = True
+    src_tile[dst, k_in] = s_tile
+    tile_nnz = np.bincount(r_tile, minlength=n_tiles).astype(np.int32) \
+        .reshape(n_pad // block_v, n_j_in)
+    # Contributing out-tile lists: for each resolution tile, the unique
+    # out-layout tiles whose real slots land in it (one host pass over the
+    # edges).
     pair = np.unique(r_tile * n_out_tiles + s_tile)
     r_ids = pair // n_out_tiles
     s_ids = pair % n_out_tiles
@@ -653,14 +706,14 @@ def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
     # via searchsorted, exactly like _fill_order_slots
     slot = np.arange(r_ids.size) - np.searchsorted(r_ids, r_ids)
     contrib[r_ids, slot] = s_ids
+    pos, src_pos = slot_list(in2out, valid)
     return PushResolution(
         n=n, n_pad=n_pad, width=w_in, out_width=w_out,
-        block_v=block_v, block_e=block_e,
-        in2out=jnp.asarray(in2out.astype(np.int32)),
-        valid=jnp.asarray(valid),
-        src_tile=jnp.asarray(src_tile.astype(np.int32)),
+        block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
+        src_tile=src_tile,
         tile_nnz=jnp.asarray(tile_nnz),
-        contrib=jnp.asarray(contrib))
+        contrib=jnp.asarray(contrib),
+        slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos))
 
 
 _RES_CACHE: dict = {}
@@ -709,22 +762,25 @@ class ShardedPushResolution:
     block_v: int
     block_e: int
     strategy: str
-    in2out: jnp.ndarray     # [k, n_pad, width] int32
-    valid: jnp.ndarray      # [k, n_pad, width] bool
-    src_tile: jnp.ndarray   # [k, n_pad, width] int32
+    in2out: np.ndarray      # [k, n_pad, width] int32
+    valid: np.ndarray       # [k, n_pad, width] bool
+    src_tile: np.ndarray    # [k, n_pad, width] int32
     tile_nnz: jnp.ndarray   # [k, n_pad/block_v, width/block_e] int32
     contrib: jnp.ndarray    # [k, n_tiles, c_max] int32, −1 pad
+    slot_pos: jnp.ndarray   # [k, E_max] int32 (stack_slot_lists)
+    slot_src: jnp.ndarray   # [k, E_max] int32
 
 
 def to_sharded_push_resolution(g: Graph, k: int, strategy: str = "contiguous",
-                               block_v: int = 8,
-                               block_e: int = 128) -> ShardedPushResolution:
+                               block_v: int = 8, block_e: int = 128,
+                               sharding=None) -> ShardedPushResolution:
     """Build the stacked per-shard push-resolution stack of a k-way
     vertex-cut.  The widened widths are computed FIRST (max over shards of
     each shard's own padded widths — the same rule ``to_sharded_ell`` pads
     with) and every shard's permutation is built against them, so in2out is
     valid for the widened out rectangles by construction rather than by a
-    fragile post-hoc index fixup."""
+    fragile post-hoc index fixup.  ``sharding`` places the device arrays
+    as in ``to_sharded_ell``."""
     from repro.graph.partition import shard_subgraphs  # lazy (see above)
     subs = shard_subgraphs(g, k, strategy)
     w_in = w_out = 0
@@ -744,14 +800,20 @@ def to_sharded_push_resolution(g: Graph, k: int, strategy: str = "contiguous",
         out[:, :c.shape[1]] = np.asarray(c)
         return out
 
+    slot_pos, slot_src = stack_slot_lists(
+        [(np.asarray(r.slot_pos), np.asarray(r.slot_src)) for r in rs],
+        rs[0].n_pad * w_in)
     return ShardedPushResolution(
         k=k, n=g.n, n_pad=rs[0].n_pad, width=w_in, out_width=w_out,
         block_v=block_v, block_e=block_e, strategy=strategy,
-        in2out=jnp.asarray(np.stack([np.asarray(r.in2out) for r in rs])),
-        valid=jnp.asarray(np.stack([np.asarray(r.valid) for r in rs])),
-        src_tile=jnp.asarray(np.stack([np.asarray(r.src_tile) for r in rs])),
-        tile_nnz=jnp.asarray(np.stack([np.asarray(r.tile_nnz) for r in rs])),
-        contrib=jnp.asarray(np.stack([widen_contrib(r.contrib) for r in rs])))
+        in2out=np.stack([r.in2out for r in rs]),
+        valid=np.stack([r.valid for r in rs]),
+        src_tile=np.stack([r.src_tile for r in rs]),
+        tile_nnz=_put(np.stack([np.asarray(r.tile_nnz) for r in rs]),
+                      sharding),
+        contrib=_put(np.stack([widen_contrib(r.contrib) for r in rs]),
+                     sharding),
+        slot_pos=_put(slot_pos, sharding), slot_src=_put(slot_src, sharding))
 
 
 _SHARDED_RES_CACHE: dict = {}
@@ -759,20 +821,21 @@ _SHARDED_RES_CACHE: dict = {}
 
 def sharded_push_resolution_cached(g: Graph, k: int,
                                    strategy: str = "contiguous",
-                                   block_v: int = 8,
-                                   block_e: int = 128) -> ShardedPushResolution:
+                                   block_v: int = 8, block_e: int = 128,
+                                   sharding=None) -> ShardedPushResolution:
     """Memoized ``to_sharded_push_resolution`` — cached per (graph, k,
-    strategy, tile shape) exactly like ``sharded_ell_cached`` (identity key,
-    weakref-guarded, finalizer-evicted), so repeated sharded push queries
-    never re-partition or re-sort."""
-    key = (id(g), k, strategy, block_v, block_e)
+    strategy, tile shape, sharding) exactly like ``sharded_ell_cached``
+    (identity key, weakref-guarded, finalizer-evicted), so repeated sharded
+    push queries never re-partition or re-sort."""
+    key = (id(g), k, strategy, block_v, block_e, sharding)
     hit = _SHARDED_RES_CACHE.get(key)
     if hit is not None:
         ref, res = hit
         if ref() is g:
             return res
     res = to_sharded_push_resolution(g, k, strategy=strategy,
-                                     block_v=block_v, block_e=block_e)
+                                     block_v=block_v, block_e=block_e,
+                                     sharding=sharding)
     _SHARDED_RES_CACHE[key] = (weakref.ref(g), res)
     weakref.finalize(g, _SHARDED_RES_CACHE.pop, key, None)
     return res

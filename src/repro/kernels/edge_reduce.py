@@ -6,14 +6,25 @@ become a dst-tiled, degree-padded ELL sweep where every Pallas grid step
 processes a fully regular ``(BLOCK_V dst vertices × BLOCK_E predecessor
 slots)`` tile in VMEM:
 
-  1. gather the predecessor values ``state[srcs]`` (VREG gather from the
-     VMEM-resident state vector),
+  1. XLA gathers the predecessor values ``state[srcs]`` (and every other
+     per-source quantity P reads) into ``[n_pad, width]`` operands before
+     the launch — Mosaic lowers no in-kernel vector gather — with
+     frontier-inactive and padding slots already set to the reduction
+     identity, so the operands stream through VMEM as plain tiles.  The
+     gather reads only the real slots, through the layout's slot list,
+     and scatters them into the rectangle (``gather_slots``),
   2. apply the synthesized propagation function P (a jnp-traceable closure
      from repro.core.synthesis — the paper's "kernel function" IS the
      kernel body),
-  3. masked-reduce along the slot axis with the reduction monoid R, and
-  4. accumulate across slot-tiles in the output block (the grid's minor
-     axis walks the slot tiles, so ``out_ref`` accumulation is safe).
+  3. reduce along the slot axis with the reduction monoid R, and
+  4. write one candidate per (row, slot-tile) into a lane-dense
+     ``[n_pad/128, n_tiles, 128]`` output block that stays resident while
+     the grid walks one 128-row group (``_store_lane_dense``).
+
+The per-tile activity bitmap rides in SMEM as the scalar-prefetch operand
+of a ``PrefetchScalarGridSpec``; a tile whose bit is 0 skips its body.
+Per-row vectors the push sweep reads (its own row's state, the frontier,
+the degrees) stream as ``(1, 1, 128)`` lane rows (``_lane_rows``).
 
 Three sweep entry points:
 
@@ -49,7 +60,8 @@ the earlier levels' propagated values and mask to tie slots.
 
 Padding slots and frontier-inactive sources carry the reduction identity
 (condition C6 makes that sound).  Tiles default to (8, 128): the VPU lane
-layout, and the slot axis a multiple of 128.
+layout, and the slot axis a multiple of 128.  ``block_v`` must divide 128
+(the lane-dense outputs pack 128 // block_v row tiles per lane row).
 """
 from __future__ import annotations
 
@@ -59,11 +71,24 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.graph import segment
 
 BLOCK_V = 8
 BLOCK_E = 128
+LANES = 128
+_INT32_MIN = -(2 ** 31)
+
+
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """THE interpret decision of every Pallas call in the repo: an explicit
+    ``interpret`` wins; None means compiled (Mosaic) on a TPU backend and
+    the Pallas interpreter on any other."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return bool(interpret)
+
 
 # boolean monoids run as int32 min/max inside the kernel
 _INT_OP = {"or": "max", "and": "min"}
@@ -85,12 +110,9 @@ _INT_OP = {"or": "max", "and": "min"}
 # "resolve_work" likewise accumulates the runtime resolution edge work
 # (Σ tile_nnz of the resolution tiles actually processed — the quantity
 # fusion_bench gates as frontier-proportional).  "gather_work" counts the
-# candidate slots actually read through the in2out permutation by the
-# in-kernel gather (Σ tile_nnz of the ACTIVE resolution tiles per push
-# iteration): skipped tiles gather zero bytes, so the counter is strictly
-# below the full out-rectangle n_pad·width the pre-kernel XLA gather used
-# to touch every iteration — the frontier-proportional data-movement
-# quantity fusion_bench gates.
+# candidate slots read through the in2out permutation: XLA gathers the real
+# slots of the dst-major rectangle (its slot list, ``gather_slots``) before
+# the resolution kernel, once per sorted push iteration.
 SWEEP_STATS = {"launches": 0, "pull_launches": 0, "push_launches": 0,
                "resolve_launches": 0,
                "pull_iters": 0, "push_iters": 0, "resolve_work": 0.0,
@@ -158,76 +180,293 @@ def _fold_tile_candidates(plans, plan_specs, ident_scalars, outs):
 
 
 # ---------------------------------------------------------------------------
+# Operand plumbing shared by every sweep: which env entries P reads, SMEM
+# tile activity, lane rows in, lane-dense candidates out.
+# ---------------------------------------------------------------------------
+
+
+class _ReadProbe(dict):
+    """Env mapping that records the keys a propagation closure reads."""
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.read = set()
+
+    def __getitem__(self, key):
+        self.read.add(key)
+        return super().__getitem__(key)
+
+    def get(self, key, default=None):
+        self.read.add(key)
+        return super().get(key, default)
+
+
+def env_keys_read(p_fns, dtypes) -> frozenset:
+    """Env entries the synthesized P closures read, found by one abstract
+    trace of each closure on a single tile.  The sweeps gather and stream
+    only these: each one is an ``[n_pad, width]`` operand per launch."""
+    read = set()
+    tile = (BLOCK_V, BLOCK_E)
+    for fn, dt in zip(p_fns, dtypes):
+        def trace(n, f32, i32, fn=fn):
+            env = _ReadProbe({"n": n, "w": f32, "c": f32, "esrc": i32,
+                              "edst": i32, "outdeg": f32, "wdeg": f32,
+                              "nv": jnp.float32(1)})
+            out = fn(env)
+            read.update(env.read)
+            return out
+        jax.eval_shape(trace, jax.ShapeDtypeStruct(tile, dt),
+                       jax.ShapeDtypeStruct(tile, jnp.float32),
+                       jax.ShapeDtypeStruct(tile, jnp.int32))
+    return frozenset(read)
+
+
+def _check_block_v(block_v: int) -> None:
+    if LANES % block_v:
+        raise ValueError(f"block_v must divide {LANES}, got {block_v}")
+
+
+def gather_slots(table, fill, shape, slots=None, idx=None, mask=None):
+    """``table[idx]`` on every real slot of an ``shape`` = [n_pad, width]
+    layout, ``fill`` on the rest.
+
+    ``slots = (pos, sidx)`` is the layout's ``structure.slot_list``: the
+    real slots' flat positions (ascending; positions ≥ n_pad·width are
+    padding and dropped) and the table index each reads.  XLA then gathers
+    E values and scatters them into the rectangle — a TPU gather costs per
+    index, and a rectangle padded to the max degree is mostly padding.
+    Without ``slots`` it is the plain per-slot gather over ``idx`` under
+    ``mask``; both give the same array bit for bit."""
+    if slots is None:
+        return jnp.where(mask, table[idx], jnp.asarray(fill, table.dtype))
+    pos, sidx = slots
+    flat = jnp.full((shape[0] * shape[1],), fill, table.dtype)
+    flat = flat.at[pos].set(table[sidx], mode="drop", unique_indices=True,
+                            indices_are_sorted=True)
+    return flat.reshape(shape)
+
+
+def _bits(x):
+    """x as int32 carrying its exact 32-bit pattern (NaNs and -0.0
+    included); narrower dtypes widen by value."""
+    if jnp.dtype(x.dtype).itemsize == 4:
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+    return x.astype(jnp.int32)
+
+
+def _unbits(b, dtype):
+    if jnp.dtype(dtype).itemsize == 4:
+        return jax.lax.bitcast_convert_type(b, dtype)
+    return b.astype(dtype)
+
+
+def _step_lanes(i, block_v):
+    """(lane offset of grid row-step ``i`` inside its 128-row group, the
+    (block_v, 128) one-hot "row r ↔ lane off + r" mask)."""
+    off = (i % (LANES // block_v)) * block_v
+    sub = jax.lax.broadcasted_iota(jnp.int32, (block_v, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (block_v, LANES), 1)
+    return off, lane == off + sub
+
+
+def _lane_rows(v):
+    """[n] per-vertex vector → [ceil(n/128), 1, 128] lane rows: a
+    ``(1, 1, 128)`` block carries the values of 128 // block_v row tiles
+    (a ``(block_v,)`` block would break the TPU's (8, 128) tiling)."""
+    n = v.shape[0]
+    n_g = -(-n // LANES)
+    return jnp.pad(v, (0, n_g * LANES - n)).reshape(n_g, 1, LANES)
+
+
+def _lane_row_spec(block_v):
+    g = LANES // block_v
+    return pl.BlockSpec((1, 1, LANES), lambda i, j, *_: (i // g, 0, 0))
+
+
+def _rows_of_step(ref, i, block_v):
+    """The (block_v, 1) values of row tile ``i`` out of its lane-row block,
+    moved lanes → sublanes exactly (a one-hot max over the bit patterns)."""
+    _off, onehot = _step_lanes(i, block_v)
+    col = jnp.max(jnp.where(onehot, _bits(ref[0]), _INT32_MIN), axis=1,
+                  keepdims=True)
+    return _unbits(col, ref.dtype)
+
+
+def _lane_dense_spec(n_j, block_v):
+    g = LANES // block_v
+    return pl.BlockSpec((1, n_j, LANES), lambda i, j, *_: (i // g, 0, 0))
+
+
+def _fill_on_first_visit(out_refs, fills, i, j, block_v):
+    """Identity-fill each lane-dense output block when the grid enters its
+    128-row group: row tiles that skip keep ⊥ (= the identity, C6)."""
+    @pl.when((j == 0) & (i % (LANES // block_v) == 0))
+    def _fill():
+        for ref, fill in zip(out_refs, fills):
+            ref[...] = jnp.full(ref.shape, fill, ref.dtype)
+
+
+def _store_lane_dense(out_ref, col, i, j, block_v):
+    """Write grid step (i, j)'s (block_v, 1) per-row result into the
+    resident ``(1, n_j, 128)`` block: sublane j, lanes off .. off+block_v.
+    The sublane → lane move is a one-hot max over the bit patterns, so every
+    value (NaNs and -0.0 included) lands bit-exact."""
+    n_j = out_ref.shape[1]
+    off, onehot = _step_lanes(i, block_v)
+    row = jnp.max(jnp.where(onehot, _bits(col), _INT32_MIN), axis=0,
+                  keepdims=True)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (n_j, LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_j, LANES), 1)
+    hit = (sub == j) & (lane >= off) & (lane < off + block_v)
+    out_ref[0] = jnp.where(hit, _unbits(row, out_ref.dtype), out_ref[0])
+
+
+def _lane_dense_rows(out, n_pad):
+    """[n_pad/128, n_j, 128] lane-dense candidates → [n_pad, n_j]."""
+    n_g, n_j, _ = out.shape
+    return out.transpose(0, 2, 1).reshape(n_g * LANES, n_j)[:n_pad]
+
+
+def _pack_tile_bits(tile_act):
+    """[n_i, n_j] activity bitmap → one bit per tile, 32 tiles per int32
+    word (tile t = i·n_j + j at bit t % 32 of word t // 32): SMEM holds
+    1 MiB on a v5e, which a word per tile would exhaust at 2^18 tiles."""
+    flags = (jnp.asarray(tile_act).reshape(-1) != 0).astype(jnp.uint32)
+    n_w = -(-flags.shape[0] // 32)
+    flags = jnp.pad(flags, (0, n_w * 32 - flags.shape[0])).reshape(n_w, 32)
+    words = jnp.sum(flags << jnp.arange(32, dtype=jnp.uint32), axis=1)
+    return jax.lax.bitcast_convert_type(words, jnp.int32)
+
+
+def _tile_active(bits_ref, i, j, n_j):
+    """Scalar test of tile (i, j)'s bit in the SMEM bitmap."""
+    t = i * n_j + j
+    word = bits_ref[t // 32]
+    return jax.lax.shift_right_logical(word, t % 32) & 1 != 0
+
+
+def _tile_call(kern, tile_act, args, in_specs, out_shapes, out_specs, *,
+               grid, interpret):
+    """``pallas_call`` over a (row tile, slot tile) grid with the packed
+    activity bitmap (``_pack_tile_bits``) as the SMEM scalar-prefetch
+    operand."""
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
+        out_specs=out_specs)
+    outs = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shapes,
+                          interpret=interpret)(
+        _pack_tile_bits(tile_act), *args)
+    return list(outs) if isinstance(outs, (tuple, list)) else [outs]
+
+
+def _tile_lex_chain(props, plan_specs, idents, tie):
+    """The per-tile lexicographic reduction chain: per plan, reduce each
+    level over the slot axis with ties of the earlier levels masking it to
+    the identity.  ``tie`` None means every slot competes (operands already
+    carry ⊥ outside the mask).  Returns one (block_v, 1) column per level."""
+    cols = []
+    for spec in plan_specs:
+        t = tie
+        for l, (pos, op) in enumerate(spec):
+            ident = jnp.asarray(idents[pos], props[pos].dtype)
+            vals = props[pos] if t is None else jnp.where(t, props[pos], ident)
+            best = _row_reduce(op, vals, axis=1)[:, None]
+            cols.append(best)
+            if l + 1 < len(spec):
+                eq = props[pos] == best
+                t = eq if t is None else t & eq
+    return cols
+
+
+# ---------------------------------------------------------------------------
 # Fused single-pass sweep: all plans × lex levels (+ has-pred) in one launch.
 # ---------------------------------------------------------------------------
 
 
-def _fused_kernel(tile_act_ref, srcs_ref, w_ref, c_ref, mask_ref, active_ref,
-                  outdeg_ref, wdeg_ref, *rest, n_comps, plan_specs,
-                  hp_positions, p_fns, idents, nv, block_v):
-    """One (BLOCK_V, BLOCK_E) tile of the fused sweep.
+def _reduce_kernel(tile_act_ref, *refs, n_comps, env_names, has_act,
+                   plan_specs, hp_positions, p_fns, idents, nv, block_v,
+                   n_j):
+    """One (BLOCK_V, BLOCK_E) tile of the pull sweep or of the sorted push
+    resolution.
 
-    ``rest`` = the per-component state vectors (``n_comps`` of them) followed
-    by the output refs: one [block_v, 1] candidate block per plan per lex
-    level, then one [block_v, 1] has-pred block per entry of
-    ``hp_positions``.  ``plan_specs`` is static: per plan a tuple of
-    (state position, monoid) levels, primary first.
+    ``refs`` = ``n_comps`` value tiles (gathered neighbour states, or the
+    gathered push candidates; ⊥ outside the mask), the env tiles named by
+    ``env_names``, an int32 frontier-mask tile when ``has_act``, then the
+    lane-dense outputs: one per plan per lex level, then one has-pred output
+    per entry of ``hp_positions``.  ``p_fns`` None means the values are
+    already propagated (resolution).  ``plan_specs`` is static: per plan a
+    tuple of (state position, monoid) levels, primary first.
 
-    Every output block is owned by exactly one grid step — no cross-step
-    accumulation — so cross-tile lexicographic resolution can run outside
-    the kernel on the [n_pad, n_tiles] candidates.
-    """
+    Every (row, slot tile) candidate is written by exactly one grid step —
+    no cross-step accumulation — so cross-tile lexicographic resolution can
+    run outside the kernel on the [n_pad, n_tiles] candidates."""
     i = pl.program_id(0)
-    state_refs = rest[:n_comps]
-    out_refs = rest[n_comps:]
+    j = pl.program_id(1)
+    n_env = len(env_names)
+    val_refs = refs[:n_comps]
+    env_refs = refs[n_comps:n_comps + n_env]
+    act_ref = refs[n_comps + n_env] if has_act else None
+    out_refs = refs[n_comps + n_env + int(has_act):]
+    fills = [idents[pos] for spec in plan_specs for pos, _op in spec] \
+        + [0] * len(hp_positions)
+    _fill_on_first_visit(out_refs, fills, i, j, block_v)
 
-    # Identity-fill every output first: tiles skipped below contribute ⊥
-    # (= the identity, C6) bit-for-bit.
-    oi = 0
-    for spec in plan_specs:
-        for (pos, _op) in spec:
-            out_refs[oi][...] = jnp.full(out_refs[oi].shape, idents[pos],
-                                         out_refs[oi].dtype)
-            oi += 1
-    for _pos in hp_positions:
-        out_refs[oi][...] = jnp.zeros(out_refs[oi].shape, out_refs[oi].dtype)
-        oi += 1
-
-    @pl.when(tile_act_ref[0, 0] != 0)
+    @pl.when(_tile_active(tile_act_ref, i, j, n_j))
     def _tile_body():
-        srcs = srcs_ref[...]
-        raw_mask = mask_ref[...]
-        mask = raw_mask & (active_ref[...][srcs] != 0)
-        rows = i * block_v + jax.lax.broadcasted_iota(jnp.int32, srcs.shape, 0)
-        env = {"w": w_ref[...], "c": c_ref[...], "esrc": srcs, "edst": rows,
-               "outdeg": outdeg_ref[...][srcs], "wdeg": wdeg_ref[...][srcs],
-               "nv": jnp.float32(nv)}
-        gathered, props = [], []
-        for k in range(n_comps):                 # ONE gather per component
-            nvals = state_refs[k][...][srcs]
-            p = jnp.asarray(p_fns[k]({"n": nvals, **env}), nvals.dtype)
-            gathered.append(nvals)
-            props.append(jnp.where(nvals == idents[k], idents[k], p))
-        oi = 0
-        for spec in plan_specs:
-            tie = mask
-            for l, (pos, op) in enumerate(spec):
-                ident = jnp.asarray(idents[pos], props[pos].dtype)
-                vals = jnp.where(tie, props[pos], ident)
-                best = _row_reduce(op, vals, axis=1)
-                out_refs[oi][...] = best[:, None].astype(out_refs[oi].dtype)
-                oi += 1
-                if l + 1 < len(spec):
-                    tie = tie & (props[pos] == best[:, None])
+        vals = [r[...] for r in val_refs]
+        if p_fns is None:
+            props = vals
+        else:
+            env = {name: r[...] for name, r in zip(env_names, env_refs)}
+            env["edst"] = i * block_v + jax.lax.broadcasted_iota(
+                jnp.int32, vals[0].shape, 0)
+            env["nv"] = jnp.float32(nv)
+            props = []
+            for k, nvals in enumerate(vals):
+                p = jnp.asarray(p_fns[k]({"n": nvals, **env}), nvals.dtype)
+                props.append(jnp.where(nvals == idents[k], idents[k], p))
+        tie = (act_ref[...] != 0) if has_act else None
+        cols = _tile_lex_chain(props, plan_specs, idents, tie)
         for pos in hp_positions:                 # fused has-pred probe
-            nb = (raw_mask & (gathered[pos] != idents[pos])).astype(jnp.int32)
-            out_refs[oi][...] = jnp.max(nb, axis=1)[:, None]
-            oi += 1
+            nb = (vals[pos] != idents[pos]).astype(jnp.int32)
+            cols.append(jnp.max(nb, axis=1, keepdims=True))
+        for out_ref, col in zip(out_refs, cols):
+            _store_lane_dense(out_ref, col.astype(out_ref.dtype), i, j,
+                              block_v)
+
+
+def _reduce_sweep(vals, env, act, tile_act, *, plan_specs, hp_positions,
+                  p_fns, idents, nv, block_v, block_e, interpret):
+    """Launch ``_reduce_kernel`` over [n_pad, width] value operands ``vals``
+    (plus the named env operands and an optional frontier mask) and return
+    the per-level (then has-pred) [n_pad, n_tiles] candidate arrays."""
+    _check_block_v(block_v)
+    n_pad, width = vals[0].shape
+    n_j = width // block_e
+    env_names = tuple(env)
+    args = list(vals) + [env[k] for k in env_names]
+    if act is not None:
+        args.append(act.astype(jnp.int32))
+    tile = pl.BlockSpec((block_v, block_e), lambda i, j, *_: (i, j))
+    out_dtypes = [vals[pos].dtype for spec in plan_specs
+                  for pos, _op in spec] + [jnp.int32] * len(hp_positions)
+    n_g = -(-n_pad // LANES)
+    kern = functools.partial(
+        _reduce_kernel, n_comps=len(vals), env_names=env_names,
+        has_act=act is not None, plan_specs=plan_specs,
+        hp_positions=hp_positions, p_fns=p_fns, idents=idents, nv=nv,
+        block_v=block_v, n_j=n_j)
+    outs = _tile_call(
+        kern, tile_act, args, [tile] * len(args),
+        [jax.ShapeDtypeStruct((n_g, n_j, LANES), dt) for dt in out_dtypes],
+        [_lane_dense_spec(n_j, block_v)] * len(out_dtypes),
+        grid=(n_pad // block_v, n_j), interpret=interpret)
+    return [_lane_dense_rows(o, n_pad) for o in outs]
 
 
 def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
                     outdeg, *, plans, idents, p_fns, nv,
-                    need_haspred: bool = False, wdeg=None,
+                    need_haspred: bool = False, wdeg=None, slots=None,
                     block_v: int = BLOCK_V, block_e: int = BLOCK_E,
                     interpret: Optional[bool] = None,
                     return_candidates: bool = False):
@@ -239,6 +478,8 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
     active    [n_pad] int32 frontier (1 = source eligible)
     outdeg    [n_pad] float32 (gathered per edge into the P environment)
     wdeg      [n_pad] float32 weighted out-degree (env "wdeg"; None → 1s)
+    slots     the layout's (slot_pos, slot_nbr) list: gathers go through it
+              (``gather_slots``); None gathers per slot
     plans     static: per plan a tuple of (comp, op) lex levels, primary first
     idents    {comp: identity scalar};  p_fns {comp: propagation closure}
 
@@ -246,57 +487,48 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
     reduction of that level, ``hp[comp]`` the [n_pad] bool has-pred vector
     (empty dict unless ``need_haspred``).  With ``return_candidates`` the raw
     per-tile candidate arrays are appended: ``(red, hp, cands)``.
+
+    The neighbour gathers run in XLA before the launch (``gather_slots``):
+    each component's ``state[srcs]`` becomes an [n_pad, width] operand
+    holding ⊥ wherever the slot is padding or (for value-only sweeps) its
+    source is frontier-inactive, and only the env entries P reads
+    (``env_keys_read``) are streamed.  A has-pred sweep masks the values by
+    the real slots only and streams the frontier mask separately.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     comps_order = comps_in_plan_order(plans)
     pos_of = {c: k for k, c in enumerate(comps_order)}
     ident_scalars = _ident_scalars(comps_order, states, idents)
     plan_specs = tuple(tuple((pos_of[c], _INT_OP.get(op, op)) for c, op in spec)
                        for spec in plans)
     hp_positions = tuple(range(len(comps_order))) if need_haspred else ()
-
-    n_pad, width = srcs.shape
-    n_i, n_j = n_pad // block_v, width // block_e
-    grid = (n_i, n_j)
-
-    tile = pl.BlockSpec((block_v, block_e), lambda i, j: (i, j))
-    one = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    full = lambda a: pl.BlockSpec(a.shape, lambda i, j: (0,) * a.ndim)
-    cand = pl.BlockSpec((block_v, 1), lambda i, j: (i, j))
+    fns = tuple(p_fns[c] for c in comps_order)
+    keys = env_keys_read(fns, [states[c].dtype for c in comps_order])
 
     if wdeg is None:
         wdeg = jnp.ones_like(outdeg)
-    args = [tile_act, srcs, weight, capacity, mask,
-            jnp.asarray(active, jnp.int32), outdeg, wdeg]
-    specs = [one, tile, tile, tile, tile, full(active), full(outdeg),
-             full(wdeg)]
-    for c in comps_order:
-        args.append(states[c])
-        specs.append(full(states[c]))
-
-    out_shapes, out_specs = [], []
-    for spec in plans:
-        for c, _op in spec:
-            out_shapes.append(jax.ShapeDtypeStruct((n_pad, n_j),
-                                                   states[c].dtype))
-            out_specs.append(cand)
-    for _ in hp_positions:
-        out_shapes.append(jax.ShapeDtypeStruct((n_pad, n_j), jnp.int32))
-        out_specs.append(cand)
-
-    kern = functools.partial(
-        _fused_kernel, n_comps=len(comps_order), plan_specs=plan_specs,
-        hp_positions=hp_positions,
-        p_fns=tuple(p_fns[c] for c in comps_order),
-        idents=ident_scalars, nv=float(nv), block_v=block_v)
-
-    outs = pl.pallas_call(
-        kern, grid=grid, in_specs=specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=interpret)(*args)
+    gather = functools.partial(gather_slots, shape=srcs.shape, slots=slots,
+                               idx=srcs, mask=mask)
+    active = jnp.asarray(active, jnp.int32)
+    act = gather(active, 0) != 0
+    # a value-only sweep masks frontier-inactive sources to ⊥ per vertex,
+    # before the gather; the has-pred sweep streams the frontier instead
+    vals = [gather(states[c] if need_haspred else jnp.where(
+                active != 0, states[c],
+                jnp.asarray(ident_scalars[k], states[c].dtype)),
+                ident_scalars[k])
+            for k, c in enumerate(comps_order)]
+    per_slot = {"w": lambda: weight, "c": lambda: capacity,
+                "esrc": lambda: srcs, "outdeg": lambda: gather(outdeg, 0),
+                "wdeg": lambda: gather(wdeg, 0)}
+    env = {k: get() for k, get in per_slot.items() if k in keys}
+    outs = _reduce_sweep(
+        vals, env, act if need_haspred else None, tile_act,
+        plan_specs=plan_specs, hp_positions=hp_positions, p_fns=fns,
+        idents=ident_scalars, nv=float(nv), block_v=block_v,
+        block_e=block_e, interpret=interpret)
     SWEEP_STATS["launches"] += 1
     SWEEP_STATS["pull_launches"] += 1
-    outs = list(outs) if isinstance(outs, (tuple, list)) else [outs]
 
     # Cross-tile lexicographic resolution (the "short second pass"): a fold
     # of the plan_merge recurrence over the tile axis, in plain jnp — zero
@@ -311,12 +543,14 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
     return red, hp
 
 
-def tile_activity(srcs, mask, tile_nnz, active_i32, block_v: int, block_e: int):
+def tile_activity(srcs, mask, tile_nnz, active_i32, block_v: int, block_e: int,
+                  slots=None):
     """Frontier-aware per-tile activity bitmap: a tile runs iff it has real
-    slots AND at least one frontier-active source.  One gather + block
-    reduction in XLA — far cheaper than the propagation work it skips."""
+    slots AND at least one frontier-active source.  One gather (through
+    ``slots`` when given, see ``gather_slots``) + block reduction in XLA —
+    far cheaper than the propagation work it skips."""
     n_i, n_j = tile_nnz.shape
-    act = (active_i32[srcs] != 0) & mask
+    act = gather_slots(active_i32, 0, srcs.shape, slots, srcs, mask) != 0
     any_act = act.reshape(n_i, block_v, n_j, block_e).any(axis=(1, 3))
     return ((tile_nnz > 0) & any_act).astype(jnp.int32)
 
@@ -364,14 +598,15 @@ def resolution_tile_activity(res_contrib, push_tile_act, res_tile_nnz):
 # ---------------------------------------------------------------------------
 
 
-def _push_kernel(tile_act_ref, dsts_ref, w_ref, c_ref, mask_ref, active_ref,
-                 outdeg_ref, wdeg_ref, *rest, n_comps, p_fns, idents, nv,
-                 block_v):
+def _push_kernel(tile_act_ref, mask_ref, *refs, n_comps, edge_names,
+                 row_names, p_fns, idents, nv, block_v, n_j):
     """One (BLOCK_V sources × BLOCK_E successor slots) tile of the push sweep.
 
-    ``rest`` = the per-component state row blocks (``n_comps`` of them,
-    [block_v] slices — push reads its OWN row's state, no gather) followed by
-    one [block_v, block_e] per-edge candidate output per component.
+    ``refs`` = the edge tiles named by ``edge_names``, then lane-row blocks
+    (``_lane_rows``) of the frontier, of each of the ``n_comps`` component
+    states (push reads its OWN row's state, no gather) and of the per-row
+    env entries named by ``row_names``, then one [block_v, block_e] per-edge
+    candidate output per component.
 
     The kernel's job is the propagation half of Defs. 3/4: apply every
     synthesized P to the row's state across the row's out-edges, masking
@@ -379,25 +614,33 @@ def _push_kernel(tile_act_ref, dsts_ref, w_ref, c_ref, mask_ref, active_ref,
     (C6) so the dst-keyed scatter outside absorbs them as no-ops.  Inactive
     tiles short-circuit via ``pl.when`` and emit identities bit-for-bit."""
     i = pl.program_id(0)
-    state_refs = rest[:n_comps]
-    out_refs = rest[n_comps:]
+    j = pl.program_id(1)
+    n_e, n_r = len(edge_names), len(row_names)
+    edge_refs = refs[:n_e]
+    active_ref = refs[n_e]
+    state_refs = refs[n_e + 1:n_e + 1 + n_comps]
+    row_refs = refs[n_e + 1 + n_comps:n_e + 1 + n_comps + n_r]
+    out_refs = refs[n_e + 1 + n_comps + n_r:]
 
     for k in range(n_comps):
         out_refs[k][...] = jnp.full(out_refs[k].shape, idents[k],
                                     out_refs[k].dtype)
 
-    @pl.when(tile_act_ref[0, 0] != 0)
+    @pl.when(_tile_active(tile_act_ref, i, j, n_j))
     def _tile_body():
-        dsts = dsts_ref[...]
-        mask = mask_ref[...] & (active_ref[...][:, None] != 0)
-        rows = i * block_v + jax.lax.broadcasted_iota(jnp.int32, dsts.shape, 0)
-        env = {"w": w_ref[...], "c": c_ref[...], "esrc": rows, "edst": dsts,
-               "outdeg": jnp.broadcast_to(outdeg_ref[...][:, None],
-                                          dsts.shape),
-               "wdeg": jnp.broadcast_to(wdeg_ref[...][:, None], dsts.shape),
-               "nv": jnp.float32(nv)}
+        shape = out_refs[0].shape
+        mask = (mask_ref[...] != 0) & \
+            (_rows_of_step(active_ref, i, block_v) != 0)
+        env = {name: r[...] for name, r in zip(edge_names, edge_refs)}
+        env.update({name: jnp.broadcast_to(_rows_of_step(r, i, block_v),
+                                           shape)
+                    for name, r in zip(row_names, row_refs)})
+        env["esrc"] = i * block_v + jax.lax.broadcasted_iota(jnp.int32,
+                                                             shape, 0)
+        env["nv"] = jnp.float32(nv)
         for k in range(n_comps):
-            nvals = jnp.broadcast_to(state_refs[k][...][:, None], dsts.shape)
+            nvals = jnp.broadcast_to(
+                _rows_of_step(state_refs[k], i, block_v), shape)
             ident = jnp.asarray(idents[k], nvals.dtype)
             p = jnp.asarray(p_fns[k]({"n": nvals, **env}), nvals.dtype)
             p = jnp.where(nvals == ident, ident, p)        # C3: ⊥ stays ⊥
@@ -409,6 +652,7 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
                          active, outdeg, *, plans, idents, p_fns, nv,
                          need_haspred: bool = False, wdeg=None,
                          resolution: str = "scatter", res=None,
+                         res_slots=None,
                          block_v: int = BLOCK_V, block_e: int = BLOCK_E,
                          interpret: Optional[bool] = None,
                          return_candidates: bool = False):
@@ -432,13 +676,14 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
 
     ``"sorted"`` — the dst-sorted segment-reduction path.  ``res`` must be
     ``(in2out, valid, res_tile_act)`` from ``structure.PushResolution`` +
-    ``resolution_tile_activity``: a second Pallas tile pass lex-reduces
+    ``resolution_tile_activity`` (in2out and valid may be None when
+    ``res_slots``, the resolution's (slot_pos, slot_src) list, carries the
+    gather — see ``gather_slots``): a second Pallas tile pass lex-reduces
     only the resolution tiles whose candidates came from frontier-active
-    out-tiles, gathering each kept tile's candidates through the dst-major
-    permutation INSIDE the kernel (row v = the contiguous candidate
-    segment of dst v; skipped tiles move zero candidate bytes), finishing
-    with the SAME cross-tile fold as the pull sweep — resolution work is
-    Σ tile_nnz of processed resolution tiles, and the reduction is
+    out-tiles, over the candidates XLA gathered through the dst-major
+    permutation (row v = the contiguous candidate segment of dst v),
+    finishing with the SAME cross-tile fold as the pull sweep — resolution
+    work is Σ tile_nnz of processed resolution tiles, and the reduction is
     bit-identical to the pull sweep's tree (even for float sums).
 
     ``"scatter"`` — the reference full-rectangle scatter pass in plain jnp
@@ -454,57 +699,59 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     states — no extra sweep launch).  ``return_candidates`` appends the raw
     [n_pad, width] per-edge candidate arrays (out-layout positions).
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     if resolution not in ("scatter", "sorted"):
         raise ValueError(f"resolution must be 'scatter' or 'sorted', "
                          f"got {resolution!r}")
     if resolution == "sorted" and res is None:
         raise ValueError("resolution='sorted' needs res=(in2out, valid, "
                          "res_tile_act) from structure.PushResolution")
+    _check_block_v(block_v)
     comps_order = comps_in_plan_order(plans)
     pos_of = {c: k for k, c in enumerate(comps_order)}
     ident_scalars = _ident_scalars(comps_order, states, idents)
+    fns = tuple(p_fns[c] for c in comps_order)
+    keys = env_keys_read(fns, [states[c].dtype for c in comps_order])
 
     n_pad, width = dsts.shape
-    n_i, n_j = n_pad // block_v, width // block_e
-    grid = (n_i, n_j)
-
-    tile = pl.BlockSpec((block_v, block_e), lambda i, j: (i, j))
-    one = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    vrow = pl.BlockSpec((block_v,), lambda i, j: (i,))
+    n_j = width // block_e
 
     if wdeg is None:
         wdeg = jnp.ones_like(outdeg)
-    args = [tile_act, dsts, weight, capacity, mask,
-            jnp.asarray(active, jnp.int32), outdeg, wdeg]
-    specs = [one, tile, tile, tile, tile, vrow, vrow, vrow]
-    for c in comps_order:
-        args.append(states[c])
-        specs.append(vrow)
-
-    out_shapes = [jax.ShapeDtypeStruct((n_pad, width), states[c].dtype)
-                  for c in comps_order]
-    out_specs = [tile for _ in comps_order]
-
+    edge = {k: a for k, a in (("w", weight), ("c", capacity), ("edst", dsts))
+            if k in keys}
+    rows = {k: a for k, a in (("outdeg", outdeg), ("wdeg", wdeg))
+            if k in keys}
+    args = [mask] + list(edge.values()) \
+        + [_lane_rows(jnp.asarray(active, jnp.int32))] \
+        + [_lane_rows(states[c]) for c in comps_order] \
+        + [_lane_rows(a) for a in rows.values()]
+    tile = pl.BlockSpec((block_v, block_e), lambda i, j, *_: (i, j))
+    in_specs = [tile] * (1 + len(edge)) \
+        + [_lane_row_spec(block_v)] * (1 + len(comps_order) + len(rows))
     kern = functools.partial(
-        _push_kernel, n_comps=len(comps_order),
-        p_fns=tuple(p_fns[c] for c in comps_order),
-        idents=ident_scalars, nv=float(nv), block_v=block_v)
-
-    outs = pl.pallas_call(
-        kern, grid=grid, in_specs=specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=interpret)(*args)
+        _push_kernel, n_comps=len(comps_order), edge_names=tuple(edge),
+        row_names=tuple(rows), p_fns=fns, idents=ident_scalars,
+        nv=float(nv), block_v=block_v, n_j=n_j)
+    outs = _tile_call(
+        kern, tile_act, args, in_specs,
+        [jax.ShapeDtypeStruct((n_pad, width), states[c].dtype)
+         for c in comps_order],
+        [tile] * len(comps_order), grid=(n_pad // block_v, n_j),
+        interpret=interpret)
     SWEEP_STATS["launches"] += 1
     SWEEP_STATS["push_launches"] += 1
-    outs = list(outs) if isinstance(outs, (tuple, list)) else [outs]
 
     if resolution == "sorted":
         in2out, valid, res_tile_act = res
+        res_shape = (res_tile_act.shape[0] * block_v,
+                     res_tile_act.shape[1] * block_e)
+        res_gather = functools.partial(gather_slots, shape=res_shape,
+                                       slots=res_slots, idx=in2out,
+                                       mask=valid)
         red = _resolve_push_sorted(
-            outs, in2out, valid, res_tile_act, plans=plans,
+            outs, res_gather, res_tile_act, plans=plans,
             comps_order=comps_order, ident_scalars=ident_scalars,
-            dtypes=[states[c].dtype for c in comps_order],
             block_v=block_v, block_e=block_e, interpret=interpret)
     else:
         # Dst-keyed lexicographic scatter resolution (reference path): the
@@ -538,9 +785,8 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
             ident = jnp.asarray(ident_scalars[pos_of[c]], states[c].dtype)
             nonbot = (mask & (states[c][:, None] != ident)).astype(jnp.int32)
             if resolution == "sorted":
-                in2out, valid, _res_tile_act = res
-                hp[c] = jnp.any(
-                    valid & (nonbot.reshape(-1)[in2out] != 0), axis=1)
+                hp[c] = jnp.any(res_gather(nonbot.reshape(-1), 0) != 0,
+                                axis=1)
             else:
                 hp[c] = segment.scatter_reduce(
                     "or", jnp.zeros((n_pad,), jnp.int32), nonbot.reshape(-1),
@@ -550,97 +796,29 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     return red, hp
 
 
-def _resolve_kernel(tile_act_ref, valid_ref, in2out_ref, *rest, n_comps,
-                    plan_specs, idents):
-    """One (BLOCK_V dst rows × BLOCK_E candidate slots) tile of the
-    dst-sorted push resolution.
-
-    ``rest`` = the push sweep's FULL out-rectangle candidate arrays
-    (``n_comps`` whole-array refs — every grid step maps the same (0, 0)
-    block) followed by one [block_v, 1] output per plan per lex level.
-    The permutation gather lives HERE, under ``pl.when``: each active tile
-    reads its own ``in2out`` block and gathers its candidates out of the
-    out rectangle, identity-filling invalid slots — so a tile whose
-    ``tile_act`` bit is 0 (all candidates born in skipped out-tiles, or all
-    padding) short-circuits and performs ZERO gather work, where the old
-    pre-kernel XLA gather touched the full rectangle every iteration.  The
-    reduction body is exactly the reduction half of ``_fused_kernel`` —
-    same lex chain, same tie masking, same per-tile candidate outputs — so
-    the fold that finishes the job is the pull sweep's
-    ``_fold_tile_candidates`` and the overall reduction tree is
-    bit-identical to pull's."""
-    cand_refs = rest[:n_comps]
-    out_refs = rest[n_comps:]
-
-    oi = 0
-    for spec in plan_specs:
-        for (pos, _op) in spec:
-            out_refs[oi][...] = jnp.full(out_refs[oi].shape, idents[pos],
-                                         out_refs[oi].dtype)
-            oi += 1
-
-    @pl.when(tile_act_ref[0, 0] != 0)
-    def _tile_body():
-        mask = valid_ref[...]
-        idx = in2out_ref[...]
-        cands = []
-        for k in range(n_comps):
-            ident = jnp.asarray(idents[k], cand_refs[k].dtype)
-            got = cand_refs[k][...].reshape(-1)[idx]
-            cands.append(jnp.where(mask, got, ident))
-        oi = 0
-        for spec in plan_specs:
-            tie = mask
-            for l, (pos, op) in enumerate(spec):
-                ident = jnp.asarray(idents[pos], cands[pos].dtype)
-                vals = jnp.where(tie, cands[pos], ident)
-                best = _row_reduce(op, vals, axis=1)
-                out_refs[oi][...] = best[:, None].astype(out_refs[oi].dtype)
-                oi += 1
-                if l + 1 < len(spec):
-                    tie = tie & (cands[pos] == best[:, None])
-
-
-def _resolve_push_sorted(cand_outs, in2out, valid, res_tile_act, *, plans,
-                         comps_order, ident_scalars, dtypes, block_v, block_e,
+def _resolve_push_sorted(cand_outs, res_gather, res_tile_act, *, plans,
+                         comps_order, ident_scalars, block_v, block_e,
                          interpret):
     """Dst-sorted segment-reduction resolution (DESIGN.md §10).
 
-    Runs the ``_resolve_kernel`` tile pass over the resolution tiles
-    ``res_tile_act`` keeps, with the permutation gather INSIDE the kernel:
-    the raw out-rectangle candidates go in whole (a (0, 0)-mapped
-    whole-array BlockSpec per component, the pull sweep's ``full`` idiom)
-    and each active tile gathers only its own slots through its ``in2out``
-    block — skipped tiles move zero candidate bytes.  Finishes with the
-    pull sweep's cross-tile fold."""
+    XLA gathers each component's candidates through the dst-major
+    permutation (``res_gather``: ``cand.ravel()[in2out]``, ⊥ on invalid
+    slots) into the [n_pad, width_in] rectangle where row v is the
+    contiguous candidate segment of dst v; the pull sweep's ``_reduce_kernel`` then lex-reduces
+    the resolution tiles ``res_tile_act`` keeps — the same chain, the same
+    tie masking, the same lane-dense candidates — and the pull sweep's
+    cross-tile fold finishes the job, so the whole reduction tree is
+    bit-identical to pull's."""
     pos_of = {c: k for k, c in enumerate(comps_order)}
     plan_specs = tuple(tuple((pos_of[c], _INT_OP.get(op, op)) for c, op in s)
                        for s in plans)
-    n_pad, w_in = valid.shape
-    n_i, n_j = n_pad // block_v, w_in // block_e
-    grid = (n_i, n_j)
-
-    tile = pl.BlockSpec((block_v, block_e), lambda i, j: (i, j))
-    one = pl.BlockSpec((1, 1), lambda i, j: (i, j))
-    full = lambda a: pl.BlockSpec(a.shape, lambda i, j: (0,) * a.ndim)
-    cand = pl.BlockSpec((block_v, 1), lambda i, j: (i, j))
-
-    args = [res_tile_act, valid, in2out] + list(cand_outs)
-    specs = [one, tile, tile] + [full(c) for c in cand_outs]
-    out_shapes, out_specs = [], []
-    for spec in plans:
-        for c, _op in spec:
-            out_shapes.append(jax.ShapeDtypeStruct((n_pad, n_j),
-                                                   dtypes[pos_of[c]]))
-            out_specs.append(cand)
-
-    kern = functools.partial(_resolve_kernel, n_comps=len(comps_order),
-                             plan_specs=plan_specs, idents=ident_scalars)
-    outs = pl.pallas_call(
-        kern, grid=grid, in_specs=specs, out_specs=out_specs,
-        out_shape=out_shapes, interpret=interpret)(*args)
+    vals = [res_gather(c.reshape(-1), ident_scalars[k])
+            for k, c in enumerate(cand_outs)]
+    outs = _reduce_sweep(
+        vals, {}, None, res_tile_act, plan_specs=plan_specs, hp_positions=(),
+        p_fns=None, idents=ident_scalars, nv=0.0, block_v=block_v,
+        block_e=block_e, interpret=interpret)
     SWEEP_STATS["resolve_launches"] += 1
-    outs = list(outs) if isinstance(outs, (tuple, list)) else [outs]
     red, _ = _fold_tile_candidates(plans, plan_specs, ident_scalars, outs)
     return red
 
@@ -719,8 +897,7 @@ def ell_level_reduce(ell, op: str, p_fns: Sequence[Callable],
 
     Returns the [n_pad] per-vertex partial reduction.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     n_levels = len(states)
     assert len(bests) == n_levels - 1
     kernel_op = _INT_OP.get(op, op)

@@ -103,9 +103,37 @@ def _exec_cache_get(key):
     return hit[0]                      # churn the hot executor survives
 
 
-def _exec_cache_put(key, run, comps) -> None:
+class _Recorded:
+    """A jitted executor that remembers the abstract arguments of its last
+    call, so ``compiled_executor_texts`` can show what the device ran."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.arg_specs = None
+
+    def __call__(self, *args):
+        self.arg_specs = jax.tree.map(self._spec, args)
+        return self.fn(*args)
+
+    @staticmethod
+    def _spec(a):
+        """Shape, dtype and — for an array split over several devices —
+        its sharding (single-device arguments follow the default device,
+        as uncommitted arrays do at the call)."""
+        sharding = getattr(a, "sharding", None)
+        if sharding is not None and len(sharding.device_set) < 2:
+            sharding = None
+        return jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                    sharding=sharding)
+
+
+def _exec_cache_put(key, run, comps):
+    """Insert a built executor (or a chunked ``(init, step)`` pair) and
+    return it wrapped in ``_Recorded`` — the object callers must run."""
     while len(_EXEC_CACHE) >= _EXEC_CACHE_MAX:
         _EXEC_CACHE.popitem(last=False)          # evict least-recently-USED
+    run = tuple(map(_Recorded, run)) if isinstance(run, tuple) \
+        else _Recorded(run)
     # The key carries id(p_fn)/id(init_fn)/id(e_fn).  Keep strong references
     # to exactly those closures in the value so a GC'd kernel set can never
     # hand its id to a new closure while the entry is alive (the id-reuse
@@ -113,6 +141,21 @@ def _exec_cache_put(key, run, comps) -> None:
     # are tiny, so pinning them is the simpler mirror).
     keyed = tuple((cr.p_fn, cr.init_fn, cr.e_fn) for cr in comps)
     _EXEC_CACHE[key] = (run, keyed)
+    return run
+
+
+def compiled_executor_texts() -> list:
+    """Optimized HLO text of every cached executor that has run, compiled
+    again from the abstract arguments of its last call (with a persistent
+    compilation cache that is a lookup).  A Mosaic kernel shows up in it as
+    a ``tpu_custom_call``; an interpreted one does not."""
+    texts = []
+    for run, _keyed in _EXEC_CACHE.values():
+        for rec in (run if isinstance(run, tuple) else (run,)):
+            if rec.arg_specs is not None:
+                texts.append(
+                    rec.fn.lower(*rec.arg_specs).compile().as_text())
+    return texts
 
 
 def _comps_key(comps):
@@ -181,12 +224,17 @@ def _padded_init_state(comps, n, n_pad, srcs):
                  for s, cr in zip(base, comps))
 
 
+# executor arguments per blocked-ELL direction: nbrs, weight, capacity, mask,
+# tile_nnz, slot_pos, slot_nbr (the sharded executor appends row_deg)
+_ELL_ARGS = 7
+
+
 def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                            interpret, use, dense_threshold, switch_k,
                            push_resolution, batch=False, sentinel=True,
                            chunked=False, warm=False):
     """Trace + jit the whole fixpoint once.  The returned function takes the
-    blocked-ELL arrays (one 5-tuple per direction in ``use``, pull first),
+    blocked-ELL arrays (``_ELL_ARGS`` per direction in ``use``, pull first),
     out-degrees (plain + weighted), the dst-sorted resolution arrays (when
     the push direction resolves ``"sorted"``), AND the per-component query
     sources as arguments (NOT closure constants): ``run(*arrays, srcs)``
@@ -243,8 +291,9 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
 
     def _split(arrays):
         """(ELL dict, out_deg, wdeg, resolution arrays|None, rest)."""
-        ell = {d: arrays[5 * i:5 * i + 5] for i, d in enumerate(use)}
-        idx = 5 * len(use)
+        ell = {d: arrays[_ELL_ARGS * i:_ELL_ARGS * (i + 1)]
+               for i, d in enumerate(use)}
+        idx = _ELL_ARGS * len(use)
         out_deg = arrays[idx]
         wdeg = arrays[idx + 1]
         idx += 2
@@ -261,7 +310,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
         share."""
         ell, out_deg, wdeg, res_arrays, _ = _split(arrays)
         if sorted_res:
-            res_in2out, res_valid, res_contrib, res_nnz = res_arrays
+            res_pos, res_src, res_contrib, res_nnz = res_arrays
         n_pad = ell[use[0]][0].shape[0]
         out_deg_pad = jnp.zeros(n_pad, jnp.float32).at[:n].set(
             jnp.maximum(out_deg, 1).astype(jnp.float32))
@@ -279,11 +328,11 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             """One fused sweep + its dst-keyed resolution.  Returns
             (red, hp, resolution edge work, gather work): 0/0 for pull (the
             cross-tile fold is O(n_pad·n_tiles) elementwise — not edge
-            work), the kept resolution tiles' Σ nnz for sorted push (the
-            in-kernel gather reads exactly those slots — skipped tiles move
-            zero candidate bytes), and rectangle/0 for the reference
+            work), the kept resolution tiles' Σ nnz / the dst-major
+            rectangle for sorted push (XLA gathers every slot of it before
+            the resolution kernel), and rectangle/0 for the reference
             scatter (full-rectangle work, no permutation gather)."""
-            nbrs, weight, capacity, mask, _nnz = ell[d]
+            nbrs, weight, capacity, mask, _nnz, s_pos, s_nbr = ell[d]
             states = {c: state_d[c] for c in comps_order}
             common = dict(plans=plan_levels, idents=idents, p_fns=p_fns,
                           nv=float(n), need_haspred=need_hp, wdeg=wdeg_pad,
@@ -292,7 +341,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             if d == "pull":
                 red, hp = _er.fused_ell_sweep(
                     nbrs, weight, capacity, mask, tile_act, states,
-                    active_i32, out_deg_pad, **common)
+                    active_i32, out_deg_pad, slots=(s_pos, s_nbr), **common)
                 return red, hp, jnp.float32(0), jnp.float32(0)
             if sorted_res:
                 res_tile_act = _er.resolution_tile_activity(
@@ -300,9 +349,10 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 red, hp = _er.fused_ell_push_sweep(
                     nbrs, weight, capacity, mask, tile_act, states,
                     active_i32, out_deg_pad, resolution="sorted",
-                    res=(res_in2out, res_valid, res_tile_act), **common)
+                    res=(None, None, res_tile_act),
+                    res_slots=(res_pos, res_src), **common)
                 res_w = jnp.sum(res_nnz * res_tile_act).astype(jnp.float32)
-                return red, hp, res_w, res_w
+                return red, hp, res_w, jnp.sum(res_nnz).astype(jnp.float32)
             red, hp = _er.fused_ell_push_sweep(
                 nbrs, weight, capacity, mask, tile_act, states,
                 active_i32, out_deg_pad, resolution="scatter", **common)
@@ -314,10 +364,11 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             work is the real slots inside the tiles actually processed."""
             def branch(args):
                 state_d, active_i32 = args
-                nbrs, _w, _c, mask, tile_nnz = ell[d]
+                nbrs, _w, _c, mask, tile_nnz, s_pos, s_nbr = ell[d]
                 if d == "pull":
                     tile_act = _er.tile_activity(nbrs, mask, tile_nnz,
-                                                 active_i32, block_v, block_e)
+                                                 active_i32, block_v, block_e,
+                                                 slots=(s_pos, s_nbr))
                 else:
                     tile_act = _er.tile_activity_push(tile_nnz, active_i32,
                                                       block_v)
@@ -435,7 +486,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
     if batch:
         # everything but srcs (ELL tuples, degrees, resolution arrays) is
         # shared across the batch
-        n_shared = 5 * len(use) + 2 + (4 if sorted_res else 0)
+        n_shared = _ELL_ARGS * len(use) + 2 + (4 if sorted_res else 0)
         if warm:
             def run_warm(*all_args):
                 arrays = all_args[:n_shared + 1]      # shared + this row's srcs
@@ -503,21 +554,19 @@ def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
            sentinel, chunked, warm)
     run = _exec_cache_get(key)
     if run is None:
-        run = _build_pallas_executor(comps, plans, g.n, max_iter, tol,
-                                     block_v, block_e, interpret, use,
-                                     dense_threshold, switch_k,
-                                     push_resolution, batch=batch,
-                                     sentinel=sentinel, chunked=chunked,
-                                     warm=warm)
-        _exec_cache_put(key, run, comps)
+        run = _exec_cache_put(key, _build_pallas_executor(
+            comps, plans, g.n, max_iter, tol, block_v, block_e, interpret,
+            use, dense_threshold, switch_k, push_resolution, batch=batch,
+            sentinel=sentinel, chunked=chunked, warm=warm), comps)
     args = []
     for d in use:
         e = ells[d]
-        args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz]
+        args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz,
+                 e.slot_pos, e.slot_nbr]
     args.append(g.out_deg)
     args.append(w_out_deg(g))
     if res is not None:
-        args += [res.in2out, res.valid, res.contrib, res.tile_nnz]
+        args += [res.slot_pos, res.slot_src, res.contrib, res.tile_nnz]
     return run, args
 
 
@@ -648,8 +697,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         to the legacy-kwarg path for identical decisions.
     """
     n = g.n
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _er.resolve_interpret(interpret)
     max_iter = max_iter if max_iter is not None else 2 * n + 4
     idempotent = all(iterate.plan_idempotent(p) for p in plans)
     use, dense_threshold, switch_k, push_resolution = _apply_plan(
@@ -791,8 +839,7 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
     whose ``iterations`` / ``edge_work`` / ``push_iters`` / ``pull_iters``
     are per-query [B] vectors."""
     n = g.n
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _er.resolve_interpret(interpret)
     max_iter = max_iter if max_iter is not None else 2 * n + 4
     idempotent = all(iterate.plan_idempotent(p) for p in plans)
     srcs = jnp.asarray(sources, jnp.int32)
@@ -891,12 +938,13 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                             push_resolution, mesh, axes):
     """Trace + jit the sharded fixpoint once per (plan structure, kernel set,
     graph shape, direction set, resolution, mesh).  The returned function
-    takes one 6-tuple of STACKED ``[k, ...]`` sharded-ELL arrays per
-    direction in ``use`` (nbrs, weight, capacity, mask, tile_nnz, row_deg —
-    split on the shard axis by ``shard_map``), then (when the push
-    direction resolves ``"sorted"``) the 4 stacked per-shard resolution
-    arrays of ``structure.ShardedPushResolution`` (in2out, valid, contrib,
-    tile_nnz — also shard-split), the replicated degree vectors, and the
+    takes one 8-tuple of STACKED ``[k, ...]`` sharded-ELL arrays per
+    direction in ``use`` (nbrs, weight, capacity, mask, tile_nnz, slot_pos,
+    slot_nbr, row_deg — split on the shard axis by ``shard_map``), then
+    (when the push direction resolves ``"sorted"``) the 4 stacked per-shard
+    resolution arrays of ``structure.ShardedPushResolution`` (slot_pos,
+    slot_src, contrib, tile_nnz — also shard-split), the replicated degree
+    vectors, and the
     traced per-component query sources: ``run(*arrays, srcs)``.
 
     Inside ``shard_map`` every shard runs the SAME fused Pallas sweeps as
@@ -914,13 +962,12 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
     collective-safe.  The push sweep resolves its dst-keyed reduction
     shard-locally with the dst-sorted segment pass by default (each shard's
     own ``PushResolution`` stack over its widened out-layout — the
-    in-kernel gather and the frontier-proportional tile skipping work
+    permutation gather and the frontier-proportional tile skipping work
     per shard exactly as on one device, and the cross-shard monoid/lex
     combine contract is unchanged); ``"scatter"`` keeps the per-shard
     reference scatter as the oracle (DESIGN.md §11)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
 
     ax = _axes_tuple(axes)
     comps_by_idx = {cr.idx: cr for cr in comps}
@@ -935,10 +982,11 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
         ell = {}
         idx = 0
         for d in use:
-            ell[d] = tuple(a[0] for a in arrays[idx:idx + 6])  # [1,...] → [...]
-            idx += 6
+            # [1, ...] → [...]
+            ell[d] = tuple(a[0] for a in arrays[idx:idx + _ELL_ARGS + 1])
+            idx += _ELL_ARGS + 1
         if sorted_res:
-            res_in2out, res_valid, res_contrib, res_nnz = \
+            res_pos, res_src, res_contrib, res_nnz = \
                 tuple(a[0] for a in arrays[idx:idx + 4])
             idx += 4
         out_deg = arrays[idx]
@@ -981,7 +1029,7 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             Returns (red, hp, resolution edge work, gather work) exactly
             like the single-device ``sweep`` — the sorted push resolve runs
             shard-locally over this shard's own ``PushResolution`` slice."""
-            nbrs, weight, capacity, mask, _nnz, _rdeg = ell[d]
+            nbrs, weight, capacity, mask, _nnz, s_pos, s_nbr, _rdeg = ell[d]
             states = {c: state_d[c] for c in comps_order}
             common = dict(plans=plan_levels, idents=idents, p_fns=p_fns,
                           nv=float(n), need_haspred=need_hp, wdeg=wdeg_pad,
@@ -990,7 +1038,7 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             if d == "pull":
                 red, hp = _er.fused_ell_sweep(
                     nbrs, weight, capacity, mask, tile_act, states,
-                    active_i32, out_deg_pad, **common)
+                    active_i32, out_deg_pad, slots=(s_pos, s_nbr), **common)
                 return red, hp, jnp.float32(0), jnp.float32(0)
             if sorted_res:
                 res_tile_act = _er.resolution_tile_activity(
@@ -998,9 +1046,10 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 red, hp = _er.fused_ell_push_sweep(
                     nbrs, weight, capacity, mask, tile_act, states,
                     active_i32, out_deg_pad, resolution="sorted",
-                    res=(res_in2out, res_valid, res_tile_act), **common)
+                    res=(None, None, res_tile_act),
+                    res_slots=(res_pos, res_src), **common)
                 res_w = jnp.sum(res_nnz * res_tile_act).astype(jnp.float32)
-                return red, hp, res_w, res_w
+                return red, hp, res_w, jnp.sum(res_nnz).astype(jnp.float32)
             red, hp = _er.fused_ell_push_sweep(
                 nbrs, weight, capacity, mask, tile_act, states,
                 active_i32, out_deg_pad, resolution="scatter", **common)
@@ -1012,10 +1061,11 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             the real slots inside the tiles THIS shard processed."""
             def branch(args):
                 state_d, active_i32 = args
-                nbrs, _w, _c, mask, tile_nnz, _rdeg = ell[d]
+                nbrs, _w, _c, mask, tile_nnz, s_pos, s_nbr, _rdeg = ell[d]
                 if d == "pull":
                     tile_act = _er.tile_activity(nbrs, mask, tile_nnz,
-                                                 active_i32, block_v, block_e)
+                                                 active_i32, block_v, block_e,
+                                                 slots=(s_pos, s_nbr))
                 else:
                     tile_act = _er.tile_activity_push(tile_nnz, active_i32,
                                                       block_v)
@@ -1039,7 +1089,7 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                         # identical (integer-exact) edge mass and picks the
                         # same direction as the single-device engine.
                         local_mass = jnp.sum(active.astype(jnp.float32)
-                                             * ell["push"][5])
+                                             * ell["push"][-1])
                         e_frontier = jax.lax.psum(local_mass, ax)
                         use_push = e_frontier <= num_edges_g / switch_k
                     else:
@@ -1112,17 +1162,16 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 gather_work[None], div[None], resid[None], active_n[None])
 
     pspec = P(ax)
-    in_specs = tuple([pspec] * (6 * len(use))
+    in_specs = tuple([pspec] * ((_ELL_ARGS + 1) * len(use))
                      + ([pspec] * 4 if sorted_res else [])
                      + [P(), P(), P()])
     out_specs = (tuple(P() for _ in comps), P(ax), P(ax), P(ax), P(ax),
                  P(ax), P(ax), P(ax), P(ax))
-    # check_vma off: the pre-graduation checker rejects collectives inside
-    # while_loop bodies, and the graduated checker cannot see through
-    # interpret-mode pallas_call — replication of state/k/pushes is a
-    # engine-level contract asserted on the host instead.
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, check_vma=False)
+    # check_vma off: the checker cannot see through pallas_call —
+    # replication of state/k/pushes is an engine-level contract asserted on
+    # the host instead.
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -1131,11 +1180,15 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
                       switch_k, push_resolution):
     """Cache lookup / build of the compiled sharded fixpoint, plus the
     stacked argument prefix it runs on."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
     ax = _axes_tuple(axes)
     k_shards = int(np.prod([mesh.shape[a] for a in ax]))
+    split = NamedSharding(mesh, P(ax))     # layouts go host → their shards
     ells = {d: sharded_ell_cached(
         g, k_shards, strategy=strategy, block_v=block_v, block_e=block_e,
-        direction={"pull": "in", "push": "out"}[d]) for d in use}
+        direction={"pull": "in", "push": "out"}[d], sharding=split)
+        for d in use}
     if len(use) != 2:                # pinned direction: no switch traced
         dense_threshold = None
         switch_k = None
@@ -1147,19 +1200,20 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
            _mesh_cache_key(mesh, ax))
     run = _exec_cache_get(key)
     if run is None:
-        run = _build_sharded_executor(comps, plans, g.n, max_iter, tol,
-                                      block_v, block_e, interpret, use,
-                                      dense_threshold, switch_k,
-                                      push_resolution, mesh, ax)
-        _exec_cache_put(key, run, comps)
+        run = _exec_cache_put(key, _build_sharded_executor(
+            comps, plans, g.n, max_iter, tol, block_v, block_e, interpret,
+            use, dense_threshold, switch_k, push_resolution, mesh, ax),
+            comps)
     args = []
     for d in use:
         e = ells[d]
-        args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz, e.row_deg]
+        args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz,
+                 e.slot_pos, e.slot_nbr, e.row_deg]
     if push_resolution == "sorted":
         sres = sharded_push_resolution_cached(
-            g, k_shards, strategy=strategy, block_v=block_v, block_e=block_e)
-        args += [sres.in2out, sres.valid, sres.contrib, sres.tile_nnz]
+            g, k_shards, strategy=strategy, block_v=block_v, block_e=block_e,
+            sharding=split)
+        args += [sres.slot_pos, sres.slot_src, sres.contrib, sres.tile_nnz]
     args.append(g.out_deg)
     args.append(w_out_deg(g))
     return run, args, k_shards
@@ -1191,8 +1245,8 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     dst-keyed resolution exactly like the single-device engine: "sorted"
     (default) resolves through each shard's own precomputed dst-major
     segment layout (``structure.to_sharded_push_resolution`` — per-shard
-    ``PushResolution`` stacks over the widened out-layout, in-kernel gather
-    and frontier-proportional tile skipping included), "scatter" keeps the
+    ``PushResolution`` stacks over the widened out-layout, permutation
+    gather and frontier-proportional tile skipping included), "scatter" keeps the
     per-shard reference full-rectangle XLA scatter as the oracle.  Both are
     exact for the idempotent min/max plans and feed the same cross-shard
     monoid/lex combine, so the choice never changes results.
@@ -1203,8 +1257,7 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     executed) on top of the usual pallas stats (including ``resolve_work``
     and ``gather_work``, summed over shards)."""
     n = g.n
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _er.resolve_interpret(interpret)
     max_iter = max_iter if max_iter is not None else 2 * n + 4
     idempotent = all(iterate.plan_idempotent(p) for p in plans)
     if plan is not None:

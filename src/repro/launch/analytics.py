@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import subprocess
 import sys
 
@@ -76,8 +77,11 @@ def main(argv=None):
     args, rest = ap.parse_known_args(argv)
 
     if args.dryrun:
+        # the dry-run lowers for forced host devices: pin the child to the
+        # CPU so it never claims an accelerator this process may hold
         return subprocess.call(
-            [sys.executable, "-m", "repro.launch.analytics_dryrun"] + rest)
+            [sys.executable, "-m", "repro.launch.analytics_dryrun"] + rest,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if rest:
         ap.error(f"unrecognized arguments: {rest}")
     if not args.smoke:

@@ -25,8 +25,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
-
 
 def build_step(mesh, n, e, max_iter=64):
     """One fused WSP (lex min-length → max-capacity) fixpoint under
@@ -91,7 +89,7 @@ def build_step(mesh, n, e, max_iter=64):
         return state, it
 
     espec = P(axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(espec, espec, espec, espec, espec, P()),
         out_specs=(tuple(P() for _ in comps), P()),

@@ -36,7 +36,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.configs as configs
-from repro.compat import shard_map
 from repro.launch.mesh import batch_axes, mesh_devices
 from repro.models import dlrm as dlrm_mod
 from repro.models import gnn as gnn_mod
@@ -407,7 +406,7 @@ def _gnn_dist_workload(arch, shape_name, shape, mesh, smoke):
     bspecs = {k_: P(axes, None) if v.ndim == 2 else P(axes)
               for k_, v in batch_abs.items()}
     bsh = {k_: NamedSharding(mesh, sp) for k_, sp in bspecs.items()}
-    step = shard_map(
+    step = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: rep, params_abs),
                   jax.tree.map(lambda _: rep, opt_abs), bspecs),

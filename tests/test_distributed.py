@@ -63,8 +63,7 @@ def test_compressed_cross_pod_allreduce():
             red, st = error_feedback_update({'w': g}, CompressState({'w': e}),
                                             'pod')
             return red['w'][None], st.error['w'][None]
-        from repro.compat import shard_map
-        fn = shard_map(f, mesh=mesh, in_specs=(P('pod'), P('pod')),
+        fn = jax.shard_map(f, mesh=mesh, in_specs=(P('pod'), P('pod')),
                        out_specs=(P('pod'), P('pod')))
         e0 = jnp.zeros((4, 256), jnp.float32)
         red, e1 = fn(g_all, e0)
@@ -184,8 +183,7 @@ def test_mgn_dist_multishard_matches_reference():
         mesh = jax.make_mesh((4,), ('d',))
         bspecs = {kk: P('d', None) if v.ndim == 2 else P('d')
                   for kk, v in batch.items()}
-        from repro.compat import shard_map
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda params, bb: G.mgn_loss_dist(cfg, params, bb, ('d',)),
             mesh=mesh, in_specs=(jax.tree.map(lambda _: P(), p), bspecs),
             out_specs=P(), check_vma=False)

@@ -326,7 +326,8 @@ def test_sharded_resolution_matches_single_device(resolution):
         import numpy as np, jax, json
         from jax.sharding import Mesh
         from repro.core import usecases as U, fusion, engine
-        from repro.graph.structure import rmat_graph
+        from repro.graph.structure import (rmat_graph,
+                                           sharded_push_resolution_cached)
         resolution = {resolution!r}
         g = rmat_graph(16, 48, seed=5)
         prog = fusion.fuse(U.ALL_SPECS['BFS']())
@@ -346,13 +347,18 @@ def test_sharded_resolution_matches_single_device(resolution):
                 if resolution == 'sorted' and rs.stats.push_iters:
                     # the sharded sorted resolve is frontier-proportional:
                     # strictly under the per-shard scatter rectangle, and
-                    # gather bytes == kept resolution slots
+                    # every push iteration gathers each shard's real
+                    # dst-major slots through its slot list
                     sc = engine.run_program(
                         g, prog, engine='pallas_sharded', mesh=mesh,
                         model=model, push_resolution='scatter')
+                    real = int(np.sum(
+                        sharded_push_resolution_cached(g, k).valid))
                     rec = (rec and
                            0 < rs.stats.resolve_work < sc.stats.resolve_work
-                           and rs.stats.gather_work == rs.stats.resolve_work
+                           and rs.stats.resolve_work <= rs.stats.gather_work
+                           and rs.stats.gather_work
+                           == rs.stats.push_iters * real
                            and sc.stats.gather_work == 0)
                 ok[f'{{model}}/k{{k}}'] = bool(rec)
         print(json.dumps(ok))

@@ -389,10 +389,12 @@ def test_gather_work_bounded_by_active_resolution_nnz(frontier):
 
 def test_gather_work_reported_and_under_rectangle():
     """Engine level: gather_work rides the fixpoint into ExecStats and
-    SWEEP_STATS, equals resolve_work under "sorted" (the gather reads
-    exactly the kept resolution slots), stays strictly under the
-    full-rectangle n_pad·width per push iteration, and is 0 under
-    "scatter" (no permutation gather at all)."""
+    SWEEP_STATS, equals the real (valid) slots of the dst-major rectangle
+    per push iteration under "sorted" (XLA gathers them through the slot
+    list before the resolution kernel, whose own work — resolve_work, the
+    kept tiles' real slots — is at most that), stays strictly under the
+    padded n_pad·width rectangle, and is 0 under "scatter" (no permutation
+    gather at all)."""
     g = rmat_graph(256, 2048, seed=17)
     res = to_push_resolution(g)
     prog = fusion.fuse(U.ALL_SPECS["BFS"]())
@@ -402,9 +404,9 @@ def test_gather_work_reported_and_under_rectangle():
     assert srt.stats.push_iters >= 1
     gw = srt.stats.gather_work
     assert er.SWEEP_STATS["gather_work"] == gw
-    assert gw == srt.stats.resolve_work
     rectangle = float(res.n_pad * res.width)
-    assert 0 < gw < srt.stats.push_iters * rectangle
+    assert gw == srt.stats.push_iters * float(np.sum(res.valid))
+    assert 0 < srt.stats.resolve_work <= gw < srt.stats.push_iters * rectangle
     _cold()
     sct = engine.run_program(g, prog, engine="pallas",
                              push_resolution="scatter")

@@ -97,7 +97,8 @@ def test_smoke_dryrun_cells_compile():
         import jax, json
         import repro.configs
         import repro.launch.workloads as W
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh = auto_mesh((2, 2), ("data", "model"))
         done = {}
         for arch, shape, variant in [
                 ("llama3.2-3b", "train_4k", "baseline"),
@@ -122,7 +123,7 @@ def test_smoke_dryrun_cells_compile():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          capture_output=True, text=True, env=env,
                          timeout=560)
