@@ -183,7 +183,8 @@ def seed_sweeps_per_iter(prog) -> int:
 
 def pallas_run_stats(g, prog, model=None):
     """Cold-build the pallas executors, run once, and return (result, sweep
-    stats): trace-time launch counts plus runtime direction counts."""
+    stats): the trace-time launch counts.  The runtime direction counts are
+    the result's ``stats.push_iters`` / ``stats.pull_iters``."""
     from repro.kernels import edge_reduce as er
     engine.clear_program_caches()
     er.reset_sweep_stats()
@@ -203,10 +204,10 @@ def bench_direction(g, gname: str, weighted: bool, name: str) -> dict:
         "iterations": res_auto.stats.iterations,
         "edge_work_auto": float(res_auto.stats.edge_work),
         "edge_work_pull": float(res_pull.stats.edge_work),
-        "sweeps_auto": s_auto["pull_iters"] + s_auto["push_iters"],
-        "sweeps_pull": s_pull["pull_iters"] + s_pull["push_iters"],
-        "push_iters": s_auto["push_iters"],
-        "pull_iters": s_auto["pull_iters"],
+        "sweeps_auto": res_auto.stats.pull_iters + res_auto.stats.push_iters,
+        "sweeps_pull": res_pull.stats.pull_iters + res_pull.stats.push_iters,
+        "push_iters": res_auto.stats.push_iters,
+        "pull_iters": res_auto.stats.pull_iters,
         "launches_traced_auto": s_auto["launches"],
         "launches_traced_pull": s_pull["launches"],
     }
@@ -691,7 +692,7 @@ def run(graph_names=("RM-S",), usecases=SIMPLE + MULTI,
                     uprog = fusion.lower_unfused(spec)
                     launches = ""
                     if eng == "pallas":
-                        _res, sweep = pallas_run_stats(g, fprog)
+                        res_p, sweep = pallas_run_stats(g, fprog)
                         launches = sweep["launches"]
                     t_f, rf = timed(lambda: engine.run_program(
                         g, fprog, engine=eng), repeats=3)
@@ -718,8 +719,8 @@ def run(graph_names=("RM-S",), usecases=SIMPLE + MULTI,
                             # over the program's rounds (≤ 2 per round:
                             # one per lax.cond direction branch)
                             "launches_traced": launches,
-                            "push_iters": sweep["push_iters"],
-                            "pull_iters": sweep["pull_iters"],
+                            "push_iters": res_p.stats.push_iters,
+                            "pull_iters": res_p.stats.pull_iters,
                             "seed_sweeps_per_iter":
                                 seed_sweeps_per_iter(fprog)})
             if "pallas" in engines:

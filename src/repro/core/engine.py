@@ -25,13 +25,13 @@ excluded from vertex reductions per C6 (R(n, ⊥) = n).
 from __future__ import annotations
 
 import dataclasses
-import time
 import warnings
 from typing import Optional, Sequence
 
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import conditions as _conditions
 from repro.core import guard, iterate
 from repro.core import plan as _plan
@@ -115,8 +115,6 @@ class ExecStats:
     rounds: int = 0
     iterations: int = 0
     edge_work: float = 0.0
-    synth_ms: float = 0.0           # wall time inside synthesize_round
-                                    # (~0 on round-cache hits)
     push_iters: int = 0             # runtime per-direction iteration counts
     pull_iters: int = 0             # (direction-aware engines; 0 elsewhere)
     resolve_work: float = 0.0       # push-resolution edge work (pallas
@@ -190,15 +188,6 @@ def _source_overrides(round_, source) -> Optional[dict]:
             if comp.source is not None}
 
 
-def _synthesize_timed(round_, synth_override=None):
-    """(synth dict, wall ms spent synthesizing) — cache hits report ~0."""
-    if synth_override is not None:
-        return synth_override, 0.0
-    t0 = time.perf_counter()
-    synth = synthesize_round(round_)
-    return synth, (time.perf_counter() - t0) * 1e3
-
-
 def _round_runtime(round_, synth):
     comps = iterate.comp_runtimes(round_, {k: v for k, v in synth.items()
                                            if not isinstance(k, tuple)})
@@ -206,6 +195,7 @@ def _round_runtime(round_, synth):
     return comps, plans
 
 
+@obs.span("grafs.validate")
 def _validate_inputs(g, source=None, sources=None):
     """Graph structural validation + query-source range check (guarded
     execution, DESIGN.md §12).  Returns the cached ``GraphCheck`` so the
@@ -366,16 +356,15 @@ def _rescale_warm_state(init_state, comps, n):
 
 
 def _run_iteration(g, round_: FusedRound, engine: str, plan: ExecutionPlan,
-                   mesh, axes, max_iter, tol, synth_override=None,
-                   source=None, graph_check=None, checkpoint_every=None,
-                   ckpt_dir=None, resume=False, init_state=None, delta=None):
+                   mesh, axes, max_iter, tol, source=None, graph_check=None,
+                   checkpoint_every=None, ckpt_dir=None, resume=False,
+                   init_state=None, delta=None):
     """One iteration round under ``plan`` on ``engine`` — which differs from
     ``plan.engine`` only while walking the guard fallback chain, in which
     case the engine-dependent plan fields re-resolve (``degrade_plan``)."""
     eff = _plan.degrade_plan(plan, engine)
     model = eff.model
-    synth, synth_ms = _synthesize_timed(round_, synth_override)
-    comps, plans = _round_runtime(round_, synth)
+    comps, plans = _round_runtime(round_, synthesize_round(round_))
     _check_preconditions(graph_check, comps, plans)
     sources = _source_overrides(round_, source)
     if engine in ("pull", "push"):
@@ -414,9 +403,10 @@ def _run_iteration(g, round_: FusedRound, engine: str, plan: ExecutionPlan,
             max_iter=max_iter, tol=tol, sources=sources, plan=eff)
     else:
         raise ValueError(f"unknown engine {engine}")
-    return res, comps, synth_ms
+    return res, comps
 
 
+@obs.span("grafs.finish")
 def _finish_round(g, round_: FusedRound, env: dict):
     """mlet (vectorized per-vertex maps) + rlet (masked vertex reductions) +
     the round's output expression, over an env already holding the leaf
@@ -438,11 +428,10 @@ def _finish_round(g, round_: FusedRound, env: dict):
     return eval_expr(round_.out, env, jnp)
 
 
-def _accumulate(stats: ExecStats, res, synth_ms: float) -> None:
+def _accumulate(stats: ExecStats, res) -> None:
     stats.rounds += 1
     stats.iterations += res.iterations
     stats.edge_work += res.edge_work
-    stats.synth_ms += synth_ms
     conv = getattr(res, "converged", True)
     if isinstance(conv, (bool, np.bool_)):      # tracer-valued on vmapped runs
         stats.converged = stats.converged and bool(conv)
@@ -472,6 +461,7 @@ def _accumulate(stats: ExecStats, res, synth_ms: float) -> None:
             stats.shard_work = stats.shard_work + sw
 
 
+@obs.span("grafs.run_program")
 def run_program(g, prog: FusedProgram, engine: Optional[str] = None,
                 model: Optional[str] = None, mesh=None, axes=("data",),
                 max_iter: Optional[int] = None, tol: float = 0.0,
@@ -590,12 +580,12 @@ def run_program(g, prog: FusedProgram, engine: Optional[str] = None,
                     source=source, graph_check=chk,
                     checkpoint_every=checkpoint_every, ckpt_dir=ckpt_dir,
                     resume=resume, init_state=init_state, delta=delta_ids)
-            (res, comps, synth_ms), eng_used, events, retries = \
+            (res, comps), eng_used, events, retries = \
                 _dispatch_guarded(call, plan.engine, plan.fallback, ft_config)
             stats.engine_used = eng_used
             stats.fallbacks += tuple(ev.as_tuple() for ev in events)
             stats.exec_retries += retries
-            _accumulate(stats, res, synth_ms)
+            _accumulate(stats, res)
             _check_outcome(res, max_iter_eff, plan.on_nonconverge)
             if return_state:
                 state_out = tuple(np.asarray(s) for s in res.state)
@@ -606,7 +596,8 @@ def run_program(g, prog: FusedProgram, engine: Optional[str] = None,
             prefix = "$vec:" if round_.out_kind == "vertex" else "$scalar:"
             named[prefix + bind_name] = out
         final = out
-    _plan.record_feedback(g, plan.kind, stats)
+    with obs.span("grafs.finish"):
+        _plan.record_feedback(g, plan.kind, stats)
     result = ExecResult(value=final, named=named, stats=stats)
     if return_state:
         return result, state_out
@@ -639,8 +630,7 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
     loop (the reference semantics this path is tested against).
 
     Returns a list of B ``ExecResult``s, each with its own per-query stats
-    (iterations, edge work, push/pull split; ``synth_ms`` is the shared
-    per-round synthesis cost, reported on each).
+    (iterations, edge work, push/pull split).
 
     Guarded execution mirrors ``run_program``: upfront validation (graph +
     every batch source), per-round termination preconditions, per-QUERY
@@ -718,8 +708,7 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
     for bind_name, round_ in prog.rounds:
         envs = [dict(nm) for nm in named]
         if round_.leaves:
-            synth, synth_ms = _synthesize_timed(round_)
-            comps, plans = _round_runtime(round_, synth)
+            comps, plans = _round_runtime(round_, synthesize_round(round_))
             _check_preconditions(chk, comps, plans)
             try:
                 res = kops.iterate_pallas_batch(
@@ -757,7 +746,6 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
                 st.rounds += 1
                 st.iterations += int(iters[b])
                 st.edge_work += float(works[b])
-                st.synth_ms += synth_ms
                 st.push_iters += int(pushes[b])
                 st.pull_iters += int(iters[b]) - int(pushes[b])
                 st.resolve_work += float(res_ws[b])
@@ -811,8 +799,7 @@ def batch_init_state(g, prog: FusedProgram, sources: Sequence) -> tuple:
         raise ValueError("batch_init_state needs a single-round program; "
                          f"got {len(iter_rounds)} iteration rounds")
     round_ = iter_rounds[0]
-    synth, _ = _synthesize_timed(round_)
-    comps, _plans = _round_runtime(round_, synth)
+    comps, _plans = _round_runtime(round_, synthesize_round(round_))
     rows = [iterate._init_state(comps, g.n,
                                 _source_overrides(round_, int(s)))
             for s in sources]
@@ -1030,7 +1017,7 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
     stats = ExecStats(engine_used=eng_used,
                       fallbacks=tuple(ev.as_tuple() for ev in events),
                       exec_retries=retries, plan=plan)
-    _accumulate(stats, res, 0.0)
+    _accumulate(stats, res)
     _check_outcome(res, max_iter_eff, plan.on_nonconverge)
     _plan.record_feedback(g, plan.kind, stats)
     return ExecResult(value=res.state[0], named={}, stats=stats)

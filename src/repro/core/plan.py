@@ -33,6 +33,7 @@ import weakref
 from collections import OrderedDict
 from typing import Optional
 
+from repro import obs
 from repro.core import iterate
 from repro.core.fusion import FusedProgram, Lex
 from repro.core.synthesis import DirectKernels
@@ -400,6 +401,7 @@ def _adapted_resolution(rec: FeedbackRecord) -> Optional[str]:
 # plan_execution — the single resolution point.
 # ---------------------------------------------------------------------------
 
+@obs.span("grafs.plan")
 def plan_execution(g, prog=None, *, engine: Optional[str] = None,
                    model: Optional[str] = None,
                    mesh=None, axes=("data",),

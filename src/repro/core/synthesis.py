@@ -21,6 +21,7 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core import conditions as C
 from repro.core import lang as L
 from repro.core.kernel_lang import (Enumerator, Expr, Lit, Var, FLT, INT, VERT,
@@ -153,6 +154,7 @@ def round_structure_key(round_) -> tuple:
                  for comp in round_.components)
 
 
+@obs.span("grafs.synthesize")
 def synthesize_round(round_) -> dict:
     """Synthesize kernels for every component of a FusedRound.
 

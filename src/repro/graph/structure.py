@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.guard import GraphValidationError
 
 
@@ -105,6 +106,7 @@ def _check_edge_arrays(n: int, src, dst, weight, capacity,
     return None
 
 
+@obs.span("grafs.from_edges")
 def from_edges(n: int, src, dst, weight=None, capacity=None,
                validate: bool = True, self_loops: str = "allow",
                duplicates: str = "allow") -> Graph:
@@ -433,6 +435,7 @@ def _blocked_ell_host(g: Graph, block_v: int, block_e: int, direction: str):
     return width, n_pad, nbrs, ws, cs, mask, tile_nnz
 
 
+@obs.span("grafs.layout.ell")
 def to_blocked_ell(g: Graph, block_v: int = 8, block_e: int = 128,
                    direction: str = "in") -> BlockedELL:
     """Build the blocked-ELL layout keyed by dst (``direction="in"``, the
@@ -442,13 +445,14 @@ def to_blocked_ell(g: Graph, block_v: int = 8, block_e: int = 128,
     width, n_pad, nbrs, ws, cs, mask, tile_nnz = _blocked_ell_host(
         g, block_v, block_e, direction)
     pos, nbr = slot_list(nbrs, mask)
-    return BlockedELL(n=g.n, n_pad=n_pad, width=width,
-                      block_v=block_v, block_e=block_e,
-                      nbrs=jnp.asarray(nbrs), weight=jnp.asarray(ws),
-                      capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
-                      tile_nnz=jnp.asarray(tile_nnz),
-                      slot_pos=jnp.asarray(pos), slot_nbr=jnp.asarray(nbr),
-                      direction=direction)
+    with obs.span("grafs.layout.upload"):
+        return BlockedELL(n=g.n, n_pad=n_pad, width=width,
+                          block_v=block_v, block_e=block_e,
+                          nbrs=jnp.asarray(nbrs), weight=jnp.asarray(ws),
+                          capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
+                          tile_nnz=jnp.asarray(tile_nnz),
+                          slot_pos=jnp.asarray(pos),
+                          slot_nbr=jnp.asarray(nbr), direction=direction)
 
 
 _ELL_CACHE: dict = {}
@@ -645,6 +649,7 @@ class PushResolution:
     slot_src: jnp.ndarray   # [E] int32 their in2out (slot_list)
 
 
+@obs.span("grafs.layout.resolution")
 def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
                        min_width: int = 0,
                        min_out_width: int = 0) -> PushResolution:
@@ -707,13 +712,14 @@ def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
     slot = np.arange(r_ids.size) - np.searchsorted(r_ids, r_ids)
     contrib[r_ids, slot] = s_ids
     pos, src_pos = slot_list(in2out, valid)
-    return PushResolution(
-        n=n, n_pad=n_pad, width=w_in, out_width=w_out,
-        block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
-        src_tile=src_tile,
-        tile_nnz=jnp.asarray(tile_nnz),
-        contrib=jnp.asarray(contrib),
-        slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos))
+    with obs.span("grafs.layout.upload"):
+        return PushResolution(
+            n=n, n_pad=n_pad, width=w_in, out_width=w_out,
+            block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
+            src_tile=src_tile,
+            tile_nnz=jnp.asarray(tile_nnz),
+            contrib=jnp.asarray(contrib),
+            slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos))
 
 
 _RES_CACHE: dict = {}

@@ -104,19 +104,10 @@ _INT_OP = {"or": "max", "and": "min"}
 # RESOLUTION tile passes separately (one per traced push sweep under
 # ``resolution="sorted"``, zero under "scatter"/pull) — they are not edge
 # sweeps, so the sweep-launch contract tests stay direction-symmetric.
-# "pull_iters"/"push_iters" are runtime counters, filled in by
-# ops.iterate_pallas from the while-loop carry after the fixpoint runs:
-# they record which direction each executed iteration actually took;
-# "resolve_work" likewise accumulates the runtime resolution edge work
-# (Σ tile_nnz of the resolution tiles actually processed — the quantity
-# fusion_bench gates as frontier-proportional).  "gather_work" counts the
-# candidate slots read through the in2out permutation: XLA gathers the real
-# slots of the dst-major rectangle (its slot list, ``gather_slots``) before
-# the resolution kernel, once per sorted push iteration.
+# What the executed iterations did (direction split, resolution and gather
+# work) is per query: ``IterationResult`` / ``ExecStats``.
 SWEEP_STATS = {"launches": 0, "pull_launches": 0, "push_launches": 0,
-               "resolve_launches": 0,
-               "pull_iters": 0, "push_iters": 0, "resolve_work": 0.0,
-               "gather_work": 0.0}
+               "resolve_launches": 0}
 
 
 def reset_sweep_stats():
@@ -167,15 +158,16 @@ def _fold_tile_candidates(plans, plan_specs, ident_scalars, outs):
     guarantee of DESIGN.md §10 rests on this).  Returns ({comp: [n_pad]
     reduction}, levels consumed)."""
     red, oi = {}, 0
-    for spec, mapped in zip(plans, plan_specs):
-        tie = jnp.ones(outs[oi].shape, bool)
-        for (c, _op), (pos, op) in zip(spec, mapped):
-            ident = jnp.asarray(ident_scalars[pos], outs[oi].dtype)
-            vals = jnp.where(tie, outs[oi], ident)
-            best = _row_reduce(op, vals, axis=1)
-            red[c] = best
-            tie = tie & (vals == best[:, None])
-            oi += 1
+    with jax.named_scope("grafs.merge"):
+        for spec, mapped in zip(plans, plan_specs):
+            tie = jnp.ones(outs[oi].shape, bool)
+            for (c, _op), (pos, op) in zip(spec, mapped):
+                ident = jnp.asarray(ident_scalars[pos], outs[oi].dtype)
+                vals = jnp.where(tie, outs[oi], ident)
+                best = _row_reduce(op, vals, axis=1)
+                red[c] = best
+                tie = tie & (vals == best[:, None])
+                oi += 1
     return red, oi
 
 
@@ -238,12 +230,16 @@ def gather_slots(table, fill, shape, slots=None, idx=None, mask=None):
     Without ``slots`` it is the plain per-slot gather over ``idx`` under
     ``mask``; both give the same array bit for bit."""
     if slots is None:
-        return jnp.where(mask, table[idx], jnp.asarray(fill, table.dtype))
+        with jax.named_scope("grafs.slot_gather"):
+            return jnp.where(mask, table[idx], jnp.asarray(fill, table.dtype))
     pos, sidx = slots
-    flat = jnp.full((shape[0] * shape[1],), fill, table.dtype)
-    flat = flat.at[pos].set(table[sidx], mode="drop", unique_indices=True,
-                            indices_are_sorted=True)
-    return flat.reshape(shape)
+    with jax.named_scope("grafs.slot_gather"):
+        vals = table[sidx]
+    with jax.named_scope("grafs.slot_scatter"):
+        flat = jnp.full((shape[0] * shape[1],), fill, table.dtype)
+        flat = flat.at[pos].set(vals, mode="drop", unique_indices=True,
+                                indices_are_sorted=True)
+        return flat.reshape(shape)
 
 
 def _bits(x):
@@ -346,15 +342,15 @@ def _tile_active(bits_ref, i, j, n_j):
 
 
 def _tile_call(kern, tile_act, args, in_specs, out_shapes, out_specs, *,
-               grid, interpret):
-    """``pallas_call`` over a (row tile, slot tile) grid with the packed
-    activity bitmap (``_pack_tile_bits``) as the SMEM scalar-prefetch
-    operand."""
+               grid, interpret, name):
+    """``pallas_call`` named ``name`` over a (row tile, slot tile) grid with
+    the packed activity bitmap (``_pack_tile_bits``) as the SMEM
+    scalar-prefetch operand."""
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
         out_specs=out_specs)
     outs = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shapes,
-                          interpret=interpret)(
+                          interpret=interpret, name=name)(
         _pack_tile_bits(tile_act), *args)
     return list(outs) if isinstance(outs, (tuple, list)) else [outs]
 
@@ -436,10 +432,11 @@ def _reduce_kernel(tile_act_ref, *refs, n_comps, env_names, has_act,
 
 
 def _reduce_sweep(vals, env, act, tile_act, *, plan_specs, hp_positions,
-                  p_fns, idents, nv, block_v, block_e, interpret):
-    """Launch ``_reduce_kernel`` over [n_pad, width] value operands ``vals``
-    (plus the named env operands and an optional frontier mask) and return
-    the per-level (then has-pred) [n_pad, n_tiles] candidate arrays."""
+                  p_fns, idents, nv, block_v, block_e, interpret, name):
+    """Launch ``_reduce_kernel`` (as the kernel ``name``) over [n_pad, width]
+    value operands ``vals`` (plus the named env operands and an optional
+    frontier mask) and return the per-level (then has-pred) [n_pad, n_tiles]
+    candidate arrays."""
     _check_block_v(block_v)
     n_pad, width = vals[0].shape
     n_j = width // block_e
@@ -460,7 +457,7 @@ def _reduce_sweep(vals, env, act, tile_act, *, plan_specs, hp_positions,
         kern, tile_act, args, [tile] * len(args),
         [jax.ShapeDtypeStruct((n_g, n_j, LANES), dt) for dt in out_dtypes],
         [_lane_dense_spec(n_j, block_v)] * len(out_dtypes),
-        grid=(n_pad // block_v, n_j), interpret=interpret)
+        grid=(n_pad // block_v, n_j), interpret=interpret, name=name)
     return [_lane_dense_rows(o, n_pad) for o in outs]
 
 
@@ -526,7 +523,7 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
         vals, env, act if need_haspred else None, tile_act,
         plan_specs=plan_specs, hp_positions=hp_positions, p_fns=fns,
         idents=ident_scalars, nv=float(nv), block_v=block_v,
-        block_e=block_e, interpret=interpret)
+        block_e=block_e, interpret=interpret, name="grafs_pull_sweep")
     SWEEP_STATS["launches"] += 1
     SWEEP_STATS["pull_launches"] += 1
 
@@ -550,9 +547,10 @@ def tile_activity(srcs, mask, tile_nnz, active_i32, block_v: int, block_e: int,
     ``slots`` when given, see ``gather_slots``) + block reduction in XLA —
     far cheaper than the propagation work it skips."""
     n_i, n_j = tile_nnz.shape
-    act = gather_slots(active_i32, 0, srcs.shape, slots, srcs, mask) != 0
-    any_act = act.reshape(n_i, block_v, n_j, block_e).any(axis=(1, 3))
-    return ((tile_nnz > 0) & any_act).astype(jnp.int32)
+    with jax.named_scope("grafs.tile_activity"):
+        act = gather_slots(active_i32, 0, srcs.shape, slots, srcs, mask) != 0
+        any_act = act.reshape(n_i, block_v, n_j, block_e).any(axis=(1, 3))
+        return ((tile_nnz > 0) & any_act).astype(jnp.int32)
 
 
 def tile_activity_push(tile_nnz, active_i32, block_v: int):
@@ -566,8 +564,9 @@ def tile_activity_push(tile_nnz, active_i32, block_v: int):
     almost everywhere).  This asymmetry is why the push direction wins the
     sparse tail of BFS/SSSP (DESIGN.md §2)."""
     n_i, _n_j = tile_nnz.shape
-    row_act = (active_i32.reshape(n_i, block_v) != 0).any(axis=1)
-    return ((tile_nnz > 0) & row_act[:, None]).astype(jnp.int32)
+    with jax.named_scope("grafs.tile_activity"):
+        row_act = (active_i32.reshape(n_i, block_v) != 0).any(axis=1)
+        return ((tile_nnz > 0) & row_act[:, None]).astype(jnp.int32)
 
 
 def resolution_tile_activity(res_contrib, push_tile_act, res_tile_nnz):
@@ -585,11 +584,12 @@ def resolution_tile_activity(res_contrib, push_tile_act, res_tile_nnz):
     bitmap keeps IS the resolution edge work fusion_bench gates as
     frontier-proportional."""
     n_i, n_j = res_tile_nnz.shape
-    flat_act = push_tile_act.reshape(-1)
-    hit = (res_contrib >= 0) & \
-        (flat_act[jnp.clip(res_contrib, 0, flat_act.shape[0] - 1)] != 0)
-    any_act = hit.any(axis=1).reshape(n_i, n_j)
-    return ((res_tile_nnz > 0) & any_act).astype(jnp.int32)
+    with jax.named_scope("grafs.res_activity"):
+        flat_act = push_tile_act.reshape(-1)
+        hit = (res_contrib >= 0) & \
+            (flat_act[jnp.clip(res_contrib, 0, flat_act.shape[0] - 1)] != 0)
+        any_act = hit.any(axis=1).reshape(n_i, n_j)
+        return ((res_tile_nnz > 0) & any_act).astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------
@@ -738,7 +738,7 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
         [jax.ShapeDtypeStruct((n_pad, width), states[c].dtype)
          for c in comps_order],
         [tile] * len(comps_order), grid=(n_pad // block_v, n_j),
-        interpret=interpret)
+        interpret=interpret, name="grafs_push_sweep")
     SWEEP_STATS["launches"] += 1
     SWEEP_STATS["push_launches"] += 1
 
@@ -817,7 +817,7 @@ def _resolve_push_sorted(cand_outs, res_gather, res_tile_act, *, plans,
     outs = _reduce_sweep(
         vals, {}, None, res_tile_act, plan_specs=plan_specs, hp_positions=(),
         p_fns=None, idents=ident_scalars, nv=0.0, block_v=block_v,
-        block_e=block_e, interpret=interpret)
+        block_e=block_e, interpret=interpret, name="grafs_resolve")
     SWEEP_STATS["resolve_launches"] += 1
     red, _ = _fold_tile_candidates(plans, plan_specs, ident_scalars, outs)
     return red

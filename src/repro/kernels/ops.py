@@ -51,6 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import iterate
 from repro.core.fusion import Lex
 from repro.graph import segment
@@ -387,19 +388,22 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 if len(use) == 2:
                     # Direction switch: sparse frontier → push (work ∝
                     # active rows), dense frontier → pull (gather tiles).
-                    if switch_k is not None:
-                        # Gemini rule: compare the frontier's outgoing
-                        # EDGE mass against |E|/k — degree data already in
-                        # the layout.  Padding rows carry 0 in out_deg_raw.
-                        e_frontier = jnp.sum(active.astype(jnp.float32)
-                                             * out_deg_raw)
-                        use_push = e_frontier <= num_edges / switch_k
-                    else:
-                        # documented fallback: frontier VERTEX fraction
-                        # over the logical vertex count (padding rows,
-                        # never active after iteration 1, must not dilute).
-                        frac = jnp.sum(active.astype(jnp.float32)) / n
-                        use_push = frac <= dense_threshold
+                    with jax.named_scope("grafs.merge"):
+                        if switch_k is not None:
+                            # Gemini rule: compare the frontier's outgoing
+                            # EDGE mass against |E|/k — degree data already
+                            # in the layout.  Padding rows carry 0 in
+                            # out_deg_raw.
+                            e_frontier = jnp.sum(active.astype(jnp.float32)
+                                                 * out_deg_raw)
+                            use_push = e_frontier <= num_edges / switch_k
+                        else:
+                            # documented fallback: frontier VERTEX fraction
+                            # over the logical vertex count (padding rows,
+                            # never active after iteration 1, must not
+                            # dilute).
+                            frac = jnp.sum(active.astype(jnp.float32)) / n
+                            use_push = frac <= dense_threshold
                     red_t, w_inc, res_w, gat_w = jax.lax.cond(
                         use_push, masked_branch("push"), masked_branch("pull"),
                         (state_d, active_i32))
@@ -413,9 +417,10 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
                 new_d = {}
-                for p in plans:
-                    new_d.update(iterate.plan_merge(p, state_d, red,
-                                                    comps_by_idx))
+                with jax.named_scope("grafs.merge"):
+                    for p in plans:
+                        new_d.update(iterate.plan_merge(p, state_d, red,
+                                                        comps_by_idx))
             else:
                 # full recompute (− models): has-pred probe in the same
                 # launch; only all-padding tiles skip.
@@ -427,19 +432,21 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
                 red = iterate._apply_epilogue(comps, red)
-                new_d = iterate._recompute_merge(plans, comps_by_idx,
-                                                 state_d, red, hp)
+                with jax.named_scope("grafs.merge"):
+                    new_d = iterate._recompute_merge(plans, comps_by_idx,
+                                                     state_d, red, hp)
                 pushes = pushes + (1 if d == "push" else 0)
             new = tuple(new_d[cr.idx] for cr in comps)
-            ch = iterate._changed(comps, new, state, tol)
-            if sentinel:
-                # fold divergence + residual into the existing carry: pure
-                # elementwise reductions, no extra kernel launches.  A fired
-                # sentinel drains the frontier so the loop exits on its own
-                # condition.
-                div = div | iterate._divergence(comps, new)
-                resid = iterate._residual(comps, new, state)
-                ch = ch & ~div
+            with jax.named_scope("grafs.merge"):
+                ch = iterate._changed(comps, new, state, tol)
+                if sentinel:
+                    # fold divergence + residual into the existing carry:
+                    # pure elementwise reductions, no extra kernel launches.
+                    # A fired sentinel drains the frontier so the loop exits
+                    # on its own condition.
+                    div = div | iterate._divergence(comps, new)
+                    resid = iterate._residual(comps, new, state)
+                    ch = ch & ~div
             return (new, ch, k + 1, work, pushes, res_work, gather_work,
                     div, resid)
 
@@ -527,6 +534,7 @@ def _srcs_vector(comps, sources=None):
     return jnp.asarray(vals, jnp.int32)
 
 
+@obs.span("grafs.executor")
 def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
                      interpret, use, dense_threshold, switch_k,
                      push_resolution, batch=False, sentinel=True,
@@ -653,8 +661,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
 
     The returned result carries ``pull_iters``/``push_iters`` — the runtime
     per-direction iteration counts — and ``resolve_work`` — the resolution
-    edge work actually performed — which are also accumulated into
-    ``edge_reduce.SWEEP_STATS`` for benchmarks.
+    edge work actually performed.
 
     Guarded-execution knobs (DESIGN.md §12):
 
@@ -730,8 +737,13 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
                                      block_e, interpret, use, dense_threshold,
                                      switch_k, push_resolution,
                                      sentinel=divergence_sentinel)
+        with obs.span("grafs.dispatch"):
+            out = run(*args, srcs)
+        if not isinstance(out[1], jax.core.Tracer):   # not under a jit
+            with obs.span("grafs.device_wait"):
+                jax.block_until_ready(out)
         (state, k, work, pushes, res_work, gather_work, div, resid,
-         act_n) = run(*args, srcs)
+         act_n) = out
     else:
         pair, args = _pallas_executor(g, comps, plans, max_iter, tol, block_v,
                                       block_e, interpret, use,
@@ -779,29 +791,21 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         (state, active, k, work, pushes, res_work, gather_work, div,
          resid) = carry
         act_n = jnp.sum(active[:n].astype(jnp.int32))
-    k_i = iterate._host(k, int)
-    p_i = iterate._host(pushes, int)
-    rw = iterate._host(res_work, float)
-    gw = iterate._host(gather_work, float)
-    if isinstance(k_i, int) and isinstance(p_i, int):
-        _er.SWEEP_STATS["push_iters"] += p_i
-        _er.SWEEP_STATS["pull_iters"] += k_i - p_i
-    if isinstance(rw, float):
-        _er.SWEEP_STATS["resolve_work"] += rw
-    if isinstance(gw, float):
-        _er.SWEEP_STATS["gather_work"] += gw
-    res = iterate.IterationResult(
-        state=tuple(s[:n] for s in state),
-        iterations=k_i,
-        edge_work=iterate._host(work, float),
-        converged=iterate._host(jnp.logical_and(~div, act_n == 0), bool),
-        diverged=iterate._host(div, bool),
-        active_count=iterate._host(act_n, int),
-        residual=iterate._host(resid, float))
-    res.push_iters = p_i
-    res.pull_iters = k_i - p_i        # valid for ints and tracers alike
-    res.resolve_work = rw
-    res.gather_work = gw
+    with obs.span("grafs.stats_to_host"):
+        k_i = iterate._host(k, int)
+        p_i = iterate._host(pushes, int)
+        res = iterate.IterationResult(
+            state=tuple(s[:n] for s in state),
+            iterations=k_i,
+            edge_work=iterate._host(work, float),
+            converged=iterate._host(jnp.logical_and(~div, act_n == 0), bool),
+            diverged=iterate._host(div, bool),
+            active_count=iterate._host(act_n, int),
+            residual=iterate._host(resid, float))
+        res.push_iters = p_i
+        res.pull_iters = k_i - p_i        # valid for ints and tracers alike
+        res.resolve_work = iterate._host(res_work, float)
+        res.gather_work = iterate._host(gather_work, float)
     return res
 
 
@@ -887,14 +891,6 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
     res.pull_iters = k - pushes
     res.resolve_work = res_work           # [B] per-query resolution work
     res.gather_work = gather_work         # [B] per-query gather work
-    try:
-        _er.SWEEP_STATS["push_iters"] += int(jnp.sum(pushes))
-        _er.SWEEP_STATS["pull_iters"] += int(jnp.sum(k - pushes))
-        _er.SWEEP_STATS["resolve_work"] += float(jnp.sum(res_work))
-        _er.SWEEP_STATS["gather_work"] += float(jnp.sum(gather_work))
-    except (jax.errors.ConcretizationTypeError,
-            jax.errors.TracerArrayConversionError):
-        pass
     return res
 
 
@@ -1294,8 +1290,6 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     p_i = int(push_host[0])
     div_h = bool(np.asarray(div)[0])
     act_h = int(np.asarray(act_n)[0])
-    _er.SWEEP_STATS["push_iters"] += p_i
-    _er.SWEEP_STATS["pull_iters"] += k_i - p_i
     res = iterate.IterationResult(
         state=tuple(s[:n] for s in state),
         iterations=k_i,
@@ -1308,8 +1302,6 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     res.pull_iters = k_i - p_i
     res.resolve_work = float(np.asarray(res_work).sum())
     res.gather_work = float(np.asarray(gather_work).sum())
-    _er.SWEEP_STATS["resolve_work"] += res.resolve_work
-    _er.SWEEP_STATS["gather_work"] += res.gather_work
     res.shards = k_shards
     res.shard_work = tuple(float(w) for w in work_host)
     res.shard_launches = len(use)        # traced sweeps per shard per round

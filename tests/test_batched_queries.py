@@ -10,8 +10,9 @@ compiled pallas executor — never a closure constant — so
 * ``run_direct(engine="pallas")`` defaults to the documented per-iteration
   direction heuristic (regression: ``pull_like`` used to pin push),
 * the executor cache is a true LRU (hits refresh recency),
-* ``ExecStats.synth_ms`` is populated (cold > warm ≈ 0).
+* the ``grafs.synthesize`` span times synthesis (cold > warm ≈ 0).
 """
+import jax
 import numpy as np
 import pytest
 
@@ -270,7 +271,7 @@ def test_run_direct_model_forces_direction(small_graphs):
 
 
 # ---------------------------------------------------------------------------
-# LRU cache behaviour + synth_ms
+# LRU cache behaviour + the synthesis span
 # ---------------------------------------------------------------------------
 
 def test_exec_cache_is_lru(small_graphs, monkeypatch):
@@ -317,14 +318,26 @@ def test_exec_cache_pins_keyed_closures(small_graphs):
 
 
 def test_synth_ms_populated(small_graphs):
-    """Cold runs report the synthesis wall time; warm (round-cache hit)
-    runs report ~0."""
+    """The ``grafs.synthesize`` span times synthesis: a cold run's is the
+    enumerative search, a warm (round-cache hit) run's ~0."""
     g = small_graphs["rmat"]
     _cold()
-    cold = engine.run_program(g, fusion.fuse(U.wsp(0)), engine="pallas")
-    warm = engine.run_program(g, fusion.fuse(U.wsp(0)), engine="pallas")
-    assert cold.stats.synth_ms > 0.0
-    assert warm.stats.synth_ms <= cold.stats.synth_ms
-    assert warm.stats.synth_ms < 50.0      # memo hit: microseconds, not a
+    spans = []
+
+    def listen(event, start, end, **_kw):
+        if event == "/grafs/grafs.synthesize":
+            spans.append(1e3 * (end - start))
+
+    jax.monitoring.register_event_time_span_listener(listen)
+    try:
+        cold = engine.run_program(g, fusion.fuse(U.wsp(0)), engine="pallas")
+        cold_ms = spans[:]
+        warm = engine.run_program(g, fusion.fuse(U.wsp(0)), engine="pallas")
+    finally:
+        jax.monitoring.unregister_event_time_span_listener(listen)
+    warm_ms = spans[len(cold_ms):]
+    assert len(cold_ms) == len(warm_ms) == 1
+    assert cold_ms[0] > warm_ms[0]
+    assert warm_ms[0] < 50.0               # memo hit: microseconds, not a
     np.testing.assert_array_equal(         # fresh enumerative search
         np.asarray(cold.value), np.asarray(warm.value))

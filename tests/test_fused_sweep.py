@@ -104,7 +104,7 @@ def test_prim_only_auto_traces_one_sweep_per_direction(name, small_graphs):
     assert er.SWEEP_STATS["launches"] == 2
     assert er.SWEEP_STATS["pull_launches"] == 1
     assert er.SWEEP_STATS["push_launches"] == 1
-    assert (er.SWEEP_STATS["pull_iters"] + er.SWEEP_STATS["push_iters"]
+    assert (res.stats.pull_iters + res.stats.push_iters
             == res.stats.iterations)
 
 

@@ -180,8 +180,7 @@ def test_identical_decisions_share_executor_cache_entries(g):
     keys0 = set(kops._EXEC_CACHE)
     # the same round through the legacy kwarg surface: no new entry
     rnd = prog.rounds[0][1]
-    synth, _ = engine._synthesize_timed(rnd)
-    comps, plans = engine._round_runtime(rnd, synth)
+    comps, plans = engine._round_runtime(rnd, engine.synthesize_round(rnd))
     kops.iterate_pallas(g, comps, plans, direction="auto", switch_k="auto",
                         push_resolution="sorted")
     assert kops.executor_cache_size() == n0

@@ -174,7 +174,7 @@ def test_direction_optimized_does_less_work_on_sparse_frontier():
     engine.clear_program_caches()
     er.reset_sweep_stats()
     auto = engine.run_program(g, prog, engine="pallas")
-    pushed = er.SWEEP_STATS["push_iters"]
+    pushed = auto.stats.push_iters
     engine.clear_program_caches()
     pull = engine.run_program(g, prog, engine="pallas", model="pull")
     assert pushed >= 1

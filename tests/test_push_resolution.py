@@ -41,6 +41,20 @@ def _cold():
     er.reset_sweep_stats()
 
 
+def _iteration_results(monkeypatch):
+    """Every ``IterationResult`` that ``ops.iterate_pallas`` returns from
+    here on, in call order."""
+    seen = []
+    real = kops.iterate_pallas
+
+    def spy(*args, **kwargs):
+        seen.append(real(*args, **kwargs))
+        return seen[-1]
+
+    monkeypatch.setattr(kops, "iterate_pallas", spy)
+    return seen
+
+
 # ---------------------------------------------------------------------------
 # layout: the dst-major permutation is exact
 # ---------------------------------------------------------------------------
@@ -230,17 +244,19 @@ def test_push_resolution_is_cache_key(small_graphs):
     assert kops.executor_cache_size() == 2              # hit, no new entry
 
 
-def test_resolve_work_reported_and_frontier_proportional():
+def test_resolve_work_reported_and_frontier_proportional(monkeypatch):
     """The engine-level acceptance quantity: on a power-law BFS the sorted
     path's resolution work must stay strictly under the scatter path's
-    full-rectangle cost and be reported through ExecStats + SWEEP_STATS."""
+    full-rectangle cost and be reported from the fixpoint's
+    ``IterationResult`` through ``ExecStats``."""
     g = rmat_graph(256, 2048, seed=17)
     prog = fusion.fuse(U.ALL_SPECS["BFS"]())
     _cold()
+    results = _iteration_results(monkeypatch)
     srt = engine.run_program(g, prog, engine="pallas",
                              push_resolution="sorted")
     rw_sorted = srt.stats.resolve_work
-    assert er.SWEEP_STATS["resolve_work"] == rw_sorted
+    assert results[-1].resolve_work == rw_sorted
     _cold()
     sct = engine.run_program(g, prog, engine="pallas",
                              push_resolution="scatter")
@@ -387,9 +403,9 @@ def test_gather_work_bounded_by_active_resolution_nnz(frontier):
         assert gather == 0.0
 
 
-def test_gather_work_reported_and_under_rectangle():
-    """Engine level: gather_work rides the fixpoint into ExecStats and
-    SWEEP_STATS, equals the real (valid) slots of the dst-major rectangle
+def test_gather_work_reported_and_under_rectangle(monkeypatch):
+    """Engine level: gather_work rides the fixpoint's ``IterationResult``
+    into ExecStats, equals the real (valid) slots of the dst-major rectangle
     per push iteration under "sorted" (XLA gathers them through the slot
     list before the resolution kernel, whose own work — resolve_work, the
     kept tiles' real slots — is at most that), stays strictly under the
@@ -399,11 +415,12 @@ def test_gather_work_reported_and_under_rectangle():
     res = to_push_resolution(g)
     prog = fusion.fuse(U.ALL_SPECS["BFS"]())
     _cold()
+    results = _iteration_results(monkeypatch)
     srt = engine.run_program(g, prog, engine="pallas",
                              push_resolution="sorted")
     assert srt.stats.push_iters >= 1
     gw = srt.stats.gather_work
-    assert er.SWEEP_STATS["gather_work"] == gw
+    assert results[-1].gather_work == gw
     rectangle = float(res.n_pad * res.width)
     assert gw == srt.stats.push_iters * float(np.sum(res.valid))
     assert 0 < srt.stats.resolve_work <= gw < srt.stats.push_iters * rectangle
@@ -411,7 +428,7 @@ def test_gather_work_reported_and_under_rectangle():
     sct = engine.run_program(g, prog, engine="pallas",
                              push_resolution="scatter")
     assert sct.stats.gather_work == 0.0
-    assert er.SWEEP_STATS["gather_work"] == 0.0
+    assert results[-1].gather_work == 0.0
 
 
 # ---------------------------------------------------------------------------
