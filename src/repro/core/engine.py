@@ -128,6 +128,10 @@ class ExecStats:
                                     # per push iteration under "sorted", 0
                                     # under "scatter", which performs no
                                     # permutation gather)
+    activity_reads: int = 0         # contributing-tile table entries the
+                                    # resolution activity test read
+                                    # (contrib_entries × push iterations
+                                    # under "sorted"; pallas engines)
     shards: int = 0                 # shard count of the sharded engines
                                     # (distributed / pallas_sharded)
     shard_launches: int = 0         # traced pallas launches PER SHARD
@@ -439,6 +443,7 @@ def _accumulate(stats: ExecStats, res) -> None:
     li = getattr(res, "pull_iters", 0)
     rw = getattr(res, "resolve_work", 0.0)
     gw = getattr(res, "gather_work", 0.0)
+    ar = getattr(res, "activity_reads", 0)
     if isinstance(pi, int):
         stats.push_iters += pi
     if isinstance(li, int):
@@ -447,6 +452,8 @@ def _accumulate(stats: ExecStats, res) -> None:
         stats.resolve_work += float(rw)
     if isinstance(gw, (int, float)):
         stats.gather_work += float(gw)
+    if isinstance(ar, int):
+        stats.activity_reads += ar
     stats.shards = max(stats.shards, getattr(res, "shards", 0))
     stats.shard_launches += getattr(res, "shard_launches", 0)
     stats.cross_combines += getattr(res, "cross_combines", 0)
@@ -740,6 +747,7 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
             pushes = np.asarray(res.push_iters)
             res_ws = np.asarray(res.resolve_work)
             gat_ws = np.asarray(res.gather_work)
+            acts = np.asarray(res.activity_reads)
             convs = np.asarray(res.converged)
             for b in range(B):
                 st = stats[b]
@@ -750,6 +758,7 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
                 st.pull_iters += int(iters[b]) - int(pushes[b])
                 st.resolve_work += float(res_ws[b])
                 st.gather_work += float(gat_ws[b])
+                st.activity_reads += int(acts[b])
                 st.converged = st.converged and bool(convs[b])
                 for leaf in round_.leaves:
                     envs[b][leaf.name] = res.state[plan_output(leaf.plan)][b]
@@ -936,6 +945,7 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
             pushes = np.asarray(res.push_iters)
             res_ws = np.asarray(res.resolve_work)
             gat_ws = np.asarray(res.gather_work)
+            acts = np.asarray(res.activity_reads)
             outs = [ExecResult(
                 value=res.state[0][b], named={},
                 stats=ExecStats(rounds=1, iterations=int(iters[b]),
@@ -944,6 +954,7 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
                                 pull_iters=int(iters[b]) - int(pushes[b]),
                                 resolve_work=float(res_ws[b]),
                                 gather_work=float(gat_ws[b]),
+                                activity_reads=int(acts[b]),
                                 engine_used="pallas", plan=plan))
                 for b in range(len(iters))]
             for o in outs:

@@ -24,9 +24,9 @@ rebuilt canonically against a patched out rectangle — its ``in2out``
 permutation would address the wrong slots.  The coupling rule: whenever
 either direction's layout is patched, a resolution consistent with the
 ACTUAL slot assignments of both directions is derived and installed
-alongside (``_resolution_from_slots``), and the per-edge slot maps are
-recorded in ``structure._SLOT_CACHE`` so chained mutations keep patching
-from the real positions.
+alongside (``structure.resolution_from_slots``), and the per-edge slot maps
+are recorded in ``structure._SLOT_CACHE`` so chained mutations keep
+patching from the real positions.
 
 Touched-vertex contract (consumed by the delta-seeded fixpoint,
 ``engine.run_program(..., delta=...)``): ``MutationDelta.touched`` is the
@@ -46,8 +46,8 @@ import numpy as np
 from repro.core.guard import GraphValidationError
 from repro.graph import structure
 from repro.graph.structure import (
-    BlockedELL, Graph, PushResolution, _check_edge_arrays, _fill_order_slots,
-    _padded_width, from_edges, slot_list)
+    BlockedELL, Graph, _check_edge_arrays, _fill_order_slots, _padded_width,
+    from_edges, slot_list)
 
 # Global patch/rebuild accounting (bench + tests; reset like SWEEP_STATS).
 MUTATION_STATS = {
@@ -151,54 +151,6 @@ def _patch_ell(ell: BlockedELL, row_old, k_old, keep,
         tile_nnz=jnp.asarray(tile_nnz), slot_pos=jnp.asarray(pos),
         slot_nbr=jnp.asarray(nbr), direction=ell.direction)
     return patched, k_ins
-
-
-def _resolution_from_slots(n, src, dst, k_in, k_out, w_in, w_out,
-                           block_v, block_e) -> PushResolution:
-    """``to_push_resolution`` generalized to EXPLICIT per-edge slot
-    assignments and rectangle widths — the resolution of a patched layout
-    pair, whose slots are no longer the canonical fill order.  Arrays are
-    host_edges (dst-sorted) order; same int32 overflow guard, same contrib
-    construction as the canonical builder."""
-    n_pad = ((n + block_v - 1) // block_v) * block_v
-    in2out = np.zeros((n_pad, w_in), dtype=np.int64)
-    valid = np.zeros((n_pad, w_in), dtype=bool)
-    in2out[dst, k_in] = src.astype(np.int64) * w_out + k_out
-    valid[dst, k_in] = True
-    if n_pad * w_out >= 2 ** 31:
-        raise ValueError(
-            f"out rectangle {n_pad}×{w_out} overflows int32 flat indices; "
-            "the dst-sorted resolution layout needs an int64 gather path "
-            "for graphs this hub-heavy")
-    n_j_out = w_out // block_e
-    out_row = in2out // w_out
-    out_col = in2out % w_out
-    src_tile = (out_row // block_v) * n_j_out + out_col // block_e
-    tile_nnz = valid.reshape(n_pad // block_v, block_v,
-                             w_in // block_e, block_e) \
-        .sum(axis=(1, 3)).astype(np.int32)
-    n_j_in = w_in // block_e
-    n_tiles = (n_pad // block_v) * n_j_in
-    n_out_tiles = (n_pad // block_v) * n_j_out
-    r_tile = (dst // block_v).astype(np.int64) * n_j_in + k_in // block_e
-    s_tile = (src // block_v).astype(np.int64) * n_j_out + k_out // block_e
-    pair = np.unique(r_tile * n_out_tiles + s_tile)
-    r_ids = pair // n_out_tiles
-    s_ids = pair % n_out_tiles
-    counts = np.bincount(r_ids, minlength=n_tiles)
-    c_max = int(max(1, counts.max() if counts.size else 1))
-    contrib = np.full((n_tiles, c_max), -1, dtype=np.int32)
-    slot = np.arange(r_ids.size) - np.searchsorted(r_ids, r_ids)
-    contrib[r_ids, slot] = s_ids
-    in2out = in2out.astype(np.int32)
-    pos, src_pos = slot_list(in2out, valid)
-    return PushResolution(
-        n=n, n_pad=n_pad, width=w_in, out_width=w_out,
-        block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
-        src_tile=src_tile.astype(np.int32),
-        tile_nnz=jnp.asarray(tile_nnz),
-        contrib=jnp.asarray(contrib),
-        slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos))
 
 
 def mutate_edges(g: Graph, insert=None, delete=None, *,
@@ -379,7 +331,7 @@ def mutate_edges(g: Graph, insert=None, delete=None, *,
         # The resolution MUST match the actual slot assignments of both
         # directions (module docstring) — derive and install it whenever
         # either direction is non-canonical.
-        res = _resolution_from_slots(
+        res = structure.resolution_from_slots(
             n, new_src[perm_new], new_dst[perm_new],
             k_in_full, k_out_full, w_in_f, w_out_f, bv, be)
         _install(structure._RES_CACHE, (id(new_g), bv, be), new_g, res)

@@ -623,16 +623,20 @@ class PushResolution:
     ``to_blocked_ell(direction="in")`` slot order, so a reduction over this
     rectangle is bit-identical to the pull sweep's reduction tree).
     ``valid`` marks real slots; ``src_tile[v, k]`` is the flat id of the
-    out-layout grid tile owning the slot.  ``contrib[t]`` is the
-    precomputed *contributing out-tile list* of resolution tile t (flat
-    row-major tile ids, −1 padded to the widest list): the unique out-tiles
-    whose candidates land in t.  The per-iteration activity test
+    out-layout grid tile owning the slot.  ``tile_nnz`` counts real slots
+    per resolution tile (the skip test + the work accounting unit).
+
+    ``contrib`` is the *contributing out-tile lists* of the live resolution
+    tiles (``tile_nnz > 0``) — the unique out-tiles whose candidates land in
+    each — as a compact class table (``contrib_classes``): one
+    ``(tile_ids [t], lists [width, t])`` int32 pair per power-of-two
+    length class, each tile's list a column, −1 padded to the class's
+    longest.  The per-iteration activity test
     (`edge_reduce.resolution_tile_activity`) ORs the push sweep's frontier
-    tile-activity bitmap over these lists — O(tiles·c_max) instead of a
-    dense O(n_pad·width) gather over ``src_tile`` — candidates born in a
-    skipped out-tile are identities, so their resolution tiles skip too,
-    making resolution work frontier-proportional.  ``tile_nnz`` counts real
-    slots per resolution tile (the skip test + the work accounting unit).
+    tile-activity bitmap over these lists — O(live pairs) reads, padded by
+    under 2× (``contrib_entries`` against ``contrib_pairs``) — candidates
+    born in a skipped out-tile are identities, so their resolution tiles
+    skip too, making resolution work frontier-proportional.
     """
     n: int
     n_pad: int
@@ -644,9 +648,124 @@ class PushResolution:
     valid: np.ndarray       # [n_pad, width] bool
     src_tile: np.ndarray    # [n_pad, width] int32 flat out-tile id
     tile_nnz: jnp.ndarray   # [n_pad/block_v, width/block_e] int32
-    contrib: jnp.ndarray    # [n_tiles, c_max] int32 out-tile ids, −1 pad
+    contrib: tuple          # ((tile_ids [t], lists [w, t]) int32, ...)
     slot_pos: jnp.ndarray   # [E] int32 flat positions of the valid slots
     slot_src: jnp.ndarray   # [E] int32 their in2out (slot_list)
+    contrib_entries: int    # Σ t·w over the classes: reads a push round
+    contrib_pairs: int      # real (resolution tile, out tile) pairs
+
+
+def _class_bits(length):
+    """Length class of contributing lists: the b with 2^(b-1) < length ≤
+    2^b, elementwise."""
+    return np.searchsorted(np.int64(1) << np.arange(63, dtype=np.int64),
+                           length)
+
+
+def contrib_classes(r_tile, s_tile, n_out_tiles: int):
+    """THE construction of the compact contributing-out-tile table:
+    ``(classes, pairs)`` from the per-edge resolution tile ``r_tile`` and
+    out tile ``s_tile`` of every real slot.
+
+    The unique (resolution tile, out tile) pairs (``pairs`` of them) give
+    each live resolution tile its list of out tiles.  Live tiles group by
+    the power of two that bounds their list length; each class is one
+    ``(tile_ids [t], lists [width, t])`` int32 pair, tiles in id
+    order, each tile's list a column, ``width`` the class's own longest
+    list and shorter lists −1 padded.  Every list is longer than half its
+    class bound, so the table holds fewer than 2·``pairs`` entries;
+    resolution tiles without real slots have no column."""
+    pair = np.unique(np.asarray(r_tile, np.int64) * n_out_tiles
+                     + np.asarray(s_tile, np.int64))
+    r_ids = pair // n_out_tiles
+    s_ids = (pair % n_out_tiles).astype(np.int32)
+    # pairs come sorted, so each live tile's list is one run of r_ids
+    start = np.flatnonzero(np.diff(r_ids, prepend=-1))
+    live = r_ids[start]
+    length = np.diff(start, append=r_ids.size)
+    bits = _class_bits(length)
+    classes = []
+    for b in np.unique(bits):
+        members = np.flatnonzero(bits == b)
+        lens = length[members]
+        lists = np.full((int(lens.max()), members.size), -1, dtype=np.int32)
+        col = np.repeat(np.arange(members.size), lens)
+        k = np.arange(col.size) - np.repeat(np.cumsum(lens) - lens, lens)
+        lists[k, col] = s_ids[np.repeat(start[members], lens) + k]
+        classes.append((live[members].astype(np.int32), lists))
+    return classes, int(pair.size)
+
+
+def stack_contrib_classes(per_shard, n_tiles: int):
+    """Per-shard ``contrib_classes`` tables stacked on a leading shard axis.
+    Shards share the power-of-two class bounds; each class pads to its
+    widest shard, in tiles with distinct ids ≥ ``n_tiles`` (which the
+    activity scatter drops) and −1 lists, and in width with −1."""
+    k = len(per_shard)
+    by_bits: dict = {}
+    for s, classes in enumerate(per_shard):
+        for ids, lists in classes:
+            by_bits.setdefault(int(_class_bits(lists.shape[0])), {})[s] = \
+                (np.asarray(ids), np.asarray(lists))
+    out = []
+    for bits in sorted(by_bits):
+        got = by_bits[bits]
+        tiles = max(ids.shape[0] for ids, _ in got.values())
+        width = max(lists.shape[0] for _, lists in got.values())
+        ids_k = np.tile(n_tiles + np.arange(tiles, dtype=np.int32), (k, 1))
+        lists_k = np.full((k, width, tiles), -1, dtype=np.int32)
+        for s, (ids, lists) in got.items():
+            ids_k[s, :ids.shape[0]] = ids
+            lists_k[s, :lists.shape[0], :lists.shape[1]] = lists
+        out.append((ids_k, lists_k))
+    return out
+
+
+def resolution_from_slots(n, src, dst, k_in, k_out, w_in, w_out,
+                          block_v, block_e) -> PushResolution:
+    """The push resolution of EXPLICIT per-edge slot assignments and
+    rectangle widths: edge i sits at out-slot ``(src[i], k_out[i])`` and
+    dst-major slot ``(dst[i], k_in[i])``, so ``in2out[dst[i], k_in[i]] =
+    src[i]·w_out + k_out[i]``.  ``to_push_resolution`` passes the canonical
+    fill order; ``mutate`` passes the slots of a patched layout pair.  The
+    rectangles are written per edge; nothing is computed over the padded
+    slots."""
+    n_pad = ((n + block_v - 1) // block_v) * block_v
+    if n_pad * w_out >= 2 ** 31:
+        raise ValueError(
+            f"out rectangle {n_pad}×{w_out} overflows int32 flat indices; "
+            "the dst-sorted resolution layout needs an int64 gather path "
+            "for graphs this hub-heavy")
+    # Per-edge tile coordinates: the edge at dst-major slot (dst, k_in) sits
+    # in resolution tile (dst//block_v, k_in//block_e) and came from
+    # out-tile (src//block_v, k_out//block_e).
+    n_j_in = w_in // block_e
+    n_j_out = w_out // block_e
+    n_tiles = (n_pad // block_v) * n_j_in
+    r_tile = (dst // block_v).astype(np.int64) * n_j_in + k_in // block_e
+    s_tile = (src // block_v).astype(np.int64) * n_j_out + k_out // block_e
+    in2out = np.zeros((n_pad, w_in), dtype=np.int32)
+    valid = np.zeros((n_pad, w_in), dtype=bool)
+    src_tile = np.zeros((n_pad, w_in), dtype=np.int32)
+    in2out[dst, k_in] = src.astype(np.int64) * w_out + k_out
+    valid[dst, k_in] = True
+    src_tile[dst, k_in] = s_tile
+    tile_nnz = np.bincount(r_tile, minlength=n_tiles).astype(np.int32) \
+        .reshape(n_pad // block_v, n_j_in)
+    classes, pairs = contrib_classes(r_tile, s_tile,
+                                     (n_pad // block_v) * n_j_out)
+    pos, src_pos = slot_list(in2out, valid)
+    with obs.span("grafs.layout.upload"):
+        return PushResolution(
+            n=n, n_pad=n_pad, width=w_in, out_width=w_out,
+            block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
+            src_tile=src_tile,
+            tile_nnz=jnp.asarray(tile_nnz),
+            contrib=tuple((jnp.asarray(ids), jnp.asarray(lists))
+                          for ids, lists in classes),
+            slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos),
+            contrib_entries=sum(lists.size for _, lists in classes),
+            contrib_pairs=pairs)
 
 
 @obs.span("grafs.layout.resolution")
@@ -657,9 +776,7 @@ def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
 
     Slot assignment replays ``_fill_order_slots`` / ``_padded_width`` — the
     exact rules ``to_blocked_ell`` builds both directions with — so the
-    correspondence is exact by construction: edge i sits at out-slot
-    ``(src[i], k_out)`` and dst-major slot ``(dst[i], k_in)``, and
-    ``in2out[dst[i], k_in] = src[i]·width_out + k_out``.
+    correspondence is exact by construction (``resolution_from_slots``).
 
     ``min_width`` / ``min_out_width`` (multiples of ``block_e``) floor the
     padded rectangle widths: the sharded stack widens every shard's
@@ -672,54 +789,9 @@ def to_push_resolution(g: Graph, block_v: int = 8, block_e: int = 128,
                int(min_width))
     w_out = max(_padded_width(np.bincount(src, minlength=n), block_e),
                 int(min_out_width))
-    n_pad = ((n + block_v - 1) // block_v) * block_v
-    if n_pad * w_out >= 2 ** 31:
-        raise ValueError(
-            f"out rectangle {n_pad}×{w_out} overflows int32 flat indices; "
-            "the dst-sorted resolution layout needs an int64 gather path "
-            "for graphs this hub-heavy")
-    k_out = _fill_order_slots(src, n)
-    k_in = _fill_order_slots(dst, n)
-    # Per-edge tile coordinates: the edge at dst-major slot (dst, k_in) sits
-    # in resolution tile (dst//block_v, k_in//block_e) and came from
-    # out-tile (src//block_v, k_out//block_e).  The rectangles are written
-    # per edge; nothing is computed over the padded slots.
-    n_j_in = w_in // block_e
-    n_j_out = w_out // block_e
-    n_tiles = (n_pad // block_v) * n_j_in
-    n_out_tiles = (n_pad // block_v) * n_j_out
-    r_tile = (dst // block_v).astype(np.int64) * n_j_in + k_in // block_e
-    s_tile = (src // block_v).astype(np.int64) * n_j_out + k_out // block_e
-    in2out = np.zeros((n_pad, w_in), dtype=np.int32)
-    valid = np.zeros((n_pad, w_in), dtype=bool)
-    src_tile = np.zeros((n_pad, w_in), dtype=np.int32)
-    in2out[dst, k_in] = src.astype(np.int64) * w_out + k_out
-    valid[dst, k_in] = True
-    src_tile[dst, k_in] = s_tile
-    tile_nnz = np.bincount(r_tile, minlength=n_tiles).astype(np.int32) \
-        .reshape(n_pad // block_v, n_j_in)
-    # Contributing out-tile lists: for each resolution tile, the unique
-    # out-layout tiles whose real slots land in it (one host pass over the
-    # edges).
-    pair = np.unique(r_tile * n_out_tiles + s_tile)
-    r_ids = pair // n_out_tiles
-    s_ids = pair % n_out_tiles
-    counts = np.bincount(r_ids, minlength=n_tiles)
-    c_max = int(max(1, counts.max() if counts.size else 1))
-    contrib = np.full((n_tiles, c_max), -1, dtype=np.int32)
-    # np.unique returns pairs sorted, so r_ids is sorted: rank-within-group
-    # via searchsorted, exactly like _fill_order_slots
-    slot = np.arange(r_ids.size) - np.searchsorted(r_ids, r_ids)
-    contrib[r_ids, slot] = s_ids
-    pos, src_pos = slot_list(in2out, valid)
-    with obs.span("grafs.layout.upload"):
-        return PushResolution(
-            n=n, n_pad=n_pad, width=w_in, out_width=w_out,
-            block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
-            src_tile=src_tile,
-            tile_nnz=jnp.asarray(tile_nnz),
-            contrib=jnp.asarray(contrib),
-            slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos))
+    return resolution_from_slots(n, src, dst, _fill_order_slots(dst, n),
+                                 _fill_order_slots(src, n), w_in, w_out,
+                                 block_v, block_e)
 
 
 _RES_CACHE: dict = {}
@@ -759,7 +831,11 @@ class ShardedPushResolution:
     resolve over its slice is therefore bit-identical to a single-device
     sorted resolve over that shard's edge subset, and the cross-shard
     monoid/lex combine contract is unchanged (DESIGN.md §11).  ``contrib``
-    slices are −1-padded to the widest shard's list width."""
+    is the shards' compact class tables stacked by
+    ``stack_contrib_classes``: ``(tile_ids [k, t], lists [k, w, t])``
+    per power-of-two length class, each padded to its widest shard with
+    dropped tile ids and −1 lists; ``contrib_entries`` counts the stack's
+    entries (all shards) and ``contrib_pairs`` the shards' real pairs."""
     k: int
     n: int
     n_pad: int
@@ -772,9 +848,11 @@ class ShardedPushResolution:
     valid: np.ndarray       # [k, n_pad, width] bool
     src_tile: np.ndarray    # [k, n_pad, width] int32
     tile_nnz: jnp.ndarray   # [k, n_pad/block_v, width/block_e] int32
-    contrib: jnp.ndarray    # [k, n_tiles, c_max] int32, −1 pad
+    contrib: tuple          # ((tile_ids [k, t], lists [k, w, t]), ...)
     slot_pos: jnp.ndarray   # [k, E_max] int32 (stack_slot_lists)
     slot_src: jnp.ndarray   # [k, E_max] int32
+    contrib_entries: int    # Σ k·t·w over the classes
+    contrib_pairs: int      # Σ over shards of the real pairs
 
 
 def to_sharded_push_resolution(g: Graph, k: int, strategy: str = "contiguous",
@@ -799,13 +877,10 @@ def to_sharded_push_resolution(g: Graph, k: int, strategy: str = "contiguous",
     rs = [to_push_resolution(sub, block_v=block_v, block_e=block_e,
                              min_width=w_in, min_out_width=w_out)
           for sub in subs]
-    c_max = max(r.contrib.shape[1] for r in rs)
-
-    def widen_contrib(c):
-        out = np.full((c.shape[0], c_max), -1, dtype=np.int32)
-        out[:, :c.shape[1]] = np.asarray(c)
-        return out
-
+    contrib = stack_contrib_classes(
+        [[(np.asarray(ids), np.asarray(lists)) for ids, lists in r.contrib]
+         for r in rs],
+        rs[0].tile_nnz.size)
     slot_pos, slot_src = stack_slot_lists(
         [(np.asarray(r.slot_pos), np.asarray(r.slot_src)) for r in rs],
         rs[0].n_pad * w_in)
@@ -817,9 +892,11 @@ def to_sharded_push_resolution(g: Graph, k: int, strategy: str = "contiguous",
         src_tile=np.stack([r.src_tile for r in rs]),
         tile_nnz=_put(np.stack([np.asarray(r.tile_nnz) for r in rs]),
                       sharding),
-        contrib=_put(np.stack([widen_contrib(r.contrib) for r in rs]),
-                     sharding),
-        slot_pos=_put(slot_pos, sharding), slot_src=_put(slot_src, sharding))
+        contrib=tuple((_put(ids, sharding), _put(lists, sharding))
+                      for ids, lists in contrib),
+        slot_pos=_put(slot_pos, sharding), slot_src=_put(slot_src, sharding),
+        contrib_entries=sum(lists.size for _, lists in contrib),
+        contrib_pairs=sum(r.contrib_pairs for r in rs))
 
 
 _SHARDED_RES_CACHE: dict = {}

@@ -576,20 +576,31 @@ def resolution_tile_activity(res_contrib, push_tile_act, res_tile_nnz):
     candidate is non-identity only if its OUT tile ran (``push_tile_act``
     from ``tile_activity_push``), so a resolution tile whose real slots all
     map into skipped out-tiles contains only identities and can skip too.
-    ``res_contrib`` is the precomputed per-resolution-tile contributing
-    out-tile list (structure.PushResolution.contrib, −1 padded): the test
-    is a tile-granular gather + OR over those lists — O(tiles·c_max), not
-    the O(n_pad·width) dense gather over the slot→tile map the first
-    version paid every iteration.  Σ res_tile_nnz over the tiles this
-    bitmap keeps IS the resolution edge work fusion_bench gates as
-    frontier-proportional."""
+    ``res_contrib`` is the compact contributing-out-tile table of the live
+    resolution tiles (``structure.PushResolution.contrib``): per length
+    class, a gather of the out-tile bitmap through the ``[width, tiles]``
+    lists (one column per tile, −1 padded), an OR down each column, and a
+    scatter of the column results into a zero bitmap at the class's tile
+    ids (ids past the end, a sharded stack's padding columns, drop).  The
+    reads are the table's entries — O(live pairs), padded by under 2× —
+    where a dense ``[n_tiles, c_max]`` table read every tile at the hub
+    tiles' width.  The lists run down columns because the TPU compiler
+    took minutes over the fixpoint with GAP urand-20's classes stored as
+    ``[tiles, width]`` rows and seconds with them as columns.
+    Σ res_tile_nnz over the tiles this bitmap keeps IS the resolution edge
+    work fusion_bench gates as frontier-proportional."""
     n_i, n_j = res_tile_nnz.shape
     with jax.named_scope("grafs.res_activity"):
         flat_act = push_tile_act.reshape(-1)
-        hit = (res_contrib >= 0) & \
-            (flat_act[jnp.clip(res_contrib, 0, flat_act.shape[0] - 1)] != 0)
-        any_act = hit.any(axis=1).reshape(n_i, n_j)
-        return ((res_tile_nnz > 0) & any_act).astype(jnp.int32)
+        top = flat_act.shape[0] - 1
+        any_act = jnp.zeros(n_i * n_j, bool)
+        for tile_ids, lists in res_contrib:
+            hit = (lists >= 0) & (flat_act[jnp.clip(lists, 0, top)] != 0)
+            any_act = any_act.at[tile_ids].set(
+                hit.any(axis=0), mode="drop", unique_indices=True,
+                indices_are_sorted=True)
+        return ((res_tile_nnz > 0) & any_act.reshape(n_i, n_j)) \
+            .astype(jnp.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -237,8 +237,11 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
     """Trace + jit the whole fixpoint once.  The returned function takes the
     blocked-ELL arrays (``_ELL_ARGS`` per direction in ``use``, pull first),
     out-degrees (plain + weighted), the dst-sorted resolution arrays (when
-    the push direction resolves ``"sorted"``), AND the per-component query
-    sources as arguments (NOT closure constants): ``run(*arrays, srcs)``
+    the push direction resolves ``"sorted"``: slot_pos, slot_src, the
+    ``contrib`` class table as one tuple argument, tile_nnz — the table's
+    class count and shapes are the graph's, and jit retraces on them like
+    on any other shape), AND the per-component query sources as arguments
+    (NOT closure constants): ``run(*arrays, srcs)``
     with ``srcs`` an [n_comps] int32 vector, so one compiled executor serves
     every graph with the same padded shapes and EVERY query source without
     retracing.  It returns the full exit diagnostics
@@ -539,9 +542,10 @@ def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
                      interpret, use, dense_threshold, switch_k,
                      push_resolution, batch=False, sentinel=True,
                      chunked=False, warm=False):
-    """Cache lookup / build of the compiled fixpoint, plus the shared
-    argument prefix (ELL arrays + degree vectors + dst-sorted resolution
-    arrays) it runs on."""
+    """Cache lookup / build of the compiled fixpoint, the shared argument
+    prefix (ELL arrays + degree vectors + dst-sorted resolution arrays) it
+    runs on, and the entries of the contributing-tile table one push round
+    reads (0 without the sorted resolution)."""
     ells = {"pull": blocked_ell_cached(g, block_v=block_v, block_e=block_e,
                                        direction="in") if "pull" in use else None,
             "push": blocked_ell_cached(g, block_v=block_v, block_e=block_e,
@@ -573,9 +577,10 @@ def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
                  e.slot_pos, e.slot_nbr]
     args.append(g.out_deg)
     args.append(w_out_deg(g))
-    if res is not None:
-        args += [res.slot_pos, res.slot_src, res.contrib, res.tile_nnz]
-    return run, args
+    if res is None:
+        return run, args, 0
+    args += [res.slot_pos, res.slot_src, res.contrib, res.tile_nnz]
+    return run, args, res.contrib_entries
 
 
 def _fixpoint_fingerprint(g, comps, plans, use, max_iter, tol, block_v,
@@ -733,10 +738,10 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
     chunk_mode = (checkpoint_every is not None or init_state is not None
                   or resume or fault_hook is not None)
     if not chunk_mode:
-        run, args = _pallas_executor(g, comps, plans, max_iter, tol, block_v,
-                                     block_e, interpret, use, dense_threshold,
-                                     switch_k, push_resolution,
-                                     sentinel=divergence_sentinel)
+        run, args, entries = _pallas_executor(
+            g, comps, plans, max_iter, tol, block_v, block_e, interpret, use,
+            dense_threshold, switch_k, push_resolution,
+            sentinel=divergence_sentinel)
         with obs.span("grafs.dispatch"):
             out = run(*args, srcs)
         if not isinstance(out[1], jax.core.Tracer):   # not under a jit
@@ -745,12 +750,10 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         (state, k, work, pushes, res_work, gather_work, div, resid,
          act_n) = out
     else:
-        pair, args = _pallas_executor(g, comps, plans, max_iter, tol, block_v,
-                                      block_e, interpret, use,
-                                      dense_threshold, switch_k,
-                                      push_resolution,
-                                      sentinel=divergence_sentinel,
-                                      chunked=True)
+        pair, args, entries = _pallas_executor(
+            g, comps, plans, max_iter, tol, block_v, block_e, interpret, use,
+            dense_threshold, switch_k, push_resolution,
+            sentinel=divergence_sentinel, chunked=True)
         init_f, step_f = pair
         ckpt = None
         if ckpt_dir is not None:
@@ -806,6 +809,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         res.pull_iters = k_i - p_i        # valid for ints and tracers alike
         res.resolve_work = iterate._host(res_work, float)
         res.gather_work = iterate._host(gather_work, float)
+        res.activity_reads = entries * p_i
     return res
 
 
@@ -869,10 +873,10 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
                 raise ValueError(
                     f"init_state for component {cr.idx} has shape "
                     f"{a.shape}, expected ({B}, {n})")
-    run, args = _pallas_executor(g, comps, plans, max_iter, tol, block_v,
-                                 block_e, interpret, use, dense_threshold,
-                                 switch_k, push_resolution, batch=True,
-                                 warm=init_state is not None)
+    run, args, entries = _pallas_executor(
+        g, comps, plans, max_iter, tol, block_v, block_e, interpret, use,
+        dense_threshold, switch_k, push_resolution, batch=True,
+        warm=init_state is not None)
     if init_state is not None:
         (state, k, work, pushes, res_work, gather_work, div, resid,
          act_n) = run(*args, srcs, *init_state)
@@ -891,6 +895,9 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
     res.pull_iters = k - pushes
     res.resolve_work = res_work           # [B] per-query resolution work
     res.gather_work = gather_work         # [B] per-query gather work
+    # [B] contrib-table entries read (int64 on the host: a large table times
+    # the push rounds passes int32)
+    res.activity_reads = entries * np.asarray(pushes, np.int64)
     return res
 
 
@@ -938,8 +945,9 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
     direction in ``use`` (nbrs, weight, capacity, mask, tile_nnz, slot_pos,
     slot_nbr, row_deg — split on the shard axis by ``shard_map``), then
     (when the push direction resolves ``"sorted"``) the 4 stacked per-shard
-    resolution arrays of ``structure.ShardedPushResolution`` (slot_pos,
-    slot_src, contrib, tile_nnz — also shard-split), the replicated degree
+    resolution arguments of ``structure.ShardedPushResolution`` (slot_pos,
+    slot_src, the contrib class table, tile_nnz — also shard-split), the
+    replicated degree
     vectors, and the
     traced per-component query sources: ``run(*arrays, srcs)``.
 
@@ -983,7 +991,7 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             idx += _ELL_ARGS + 1
         if sorted_res:
             res_pos, res_src, res_contrib, res_nnz = \
-                tuple(a[0] for a in arrays[idx:idx + 4])
+                jax.tree.map(lambda a: a[0], tuple(arrays[idx:idx + 4]))
             idx += 4
         out_deg = arrays[idx]
         wdeg = arrays[idx + 1]
@@ -1174,8 +1182,10 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
 def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
                       block_v, block_e, interpret, use, dense_threshold,
                       switch_k, push_resolution):
-    """Cache lookup / build of the compiled sharded fixpoint, plus the
-    stacked argument prefix it runs on."""
+    """Cache lookup / build of the compiled sharded fixpoint, the stacked
+    argument prefix it runs on, the shard count, and the entries of the
+    stacked contributing-tile table one push round reads (0 without the
+    sorted resolution)."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     ax = _axes_tuple(axes)
@@ -1205,14 +1215,16 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
         e = ells[d]
         args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz,
                  e.slot_pos, e.slot_nbr, e.row_deg]
+    entries = 0
     if push_resolution == "sorted":
         sres = sharded_push_resolution_cached(
             g, k_shards, strategy=strategy, block_v=block_v, block_e=block_e,
             sharding=split)
         args += [sres.slot_pos, sres.slot_src, sres.contrib, sres.tile_nnz]
+        entries = sres.contrib_entries
     args.append(g.out_deg)
     args.append(w_out_deg(g))
-    return run, args, k_shards
+    return run, args, k_shards, entries
 
 
 def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
@@ -1270,7 +1282,7 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
             PUSH_RESOLUTION if push_resolution is None else push_resolution)
         if strategy not in ("contiguous", "dst_hash"):
             raise ValueError(f"unknown shard strategy {strategy!r}")
-    run, args, k_shards = _sharded_executor(
+    run, args, k_shards, entries = _sharded_executor(
         g, comps, plans, mesh, axes, strategy, max_iter, tol, block_v,
         block_e, interpret, use, dense_threshold, switch_k, push_resolution)
     state, k, work, pushes, res_work, gather_work, div, resid, act_n = run(
@@ -1302,6 +1314,7 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     res.pull_iters = k_i - p_i
     res.resolve_work = float(np.asarray(res_work).sum())
     res.gather_work = float(np.asarray(gather_work).sum())
+    res.activity_reads = entries * p_i
     res.shards = k_shards
     res.shard_work = tuple(float(w) for w in work_host)
     res.shard_launches = len(use)        # traced sweeps per shard per round

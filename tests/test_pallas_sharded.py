@@ -96,10 +96,17 @@ def test_sharded_push_resolution_roundtrips_per_shard(strategy):
     # the union over shards is the graph
     src_g, dst_g, _, _ = g.host_edges()
     assert sorted(got) == sorted(zip(src_g.tolist(), dst_g.tolist()))
-    # contrib lists cover every resolution tile that holds real slots
-    contrib = np.asarray(sres.contrib)
+    # the contrib classes hold one non-empty list for each resolution tile
+    # with real slots and none for any other (padding columns' ids are past
+    # the last tile)
     nnz = np.asarray(sres.tile_nnz).reshape(k, -1)
-    assert ((contrib >= 0).any(axis=2).reshape(k, -1) == (nnz > 0)).all()
+    for s in range(k):
+        ids = np.concatenate([np.asarray(i[s]) for i, _ in sres.contrib])
+        lists = [np.asarray(li[s]) for _, li in sres.contrib]
+        real = ids < nnz.shape[1]
+        assert sorted(ids[real].tolist()) == np.flatnonzero(nnz[s]).tolist()
+        filled = np.concatenate([(li >= 0).any(axis=0) for li in lists])
+        assert (filled == real).all()
 
 
 def test_sharded_empty_shards_are_all_padding():

@@ -6,6 +6,10 @@ Covers the acceptance contract of the frontier-proportional resolution path:
   the out-layout slot of the SAME edge (weights/destinations round-trip),
 * `fused_ell_push_sweep(resolution="sorted")` ≡ `"scatter"` bit-for-bit at
   the kernel level across random graphs and frontier densities,
+* the resolution activity test over the compact class table of the live
+  resolution tiles is bitwise the dense OR over the real (resolution tile,
+  out tile) pairs — single-device, sharded and mutation-patched layouts —
+  and the table holds under 2× the real pairs,
 * resolution work is frontier-proportional: Σ tile_nnz of the resolution
   tiles actually processed, strictly under the scatter's full rectangle on
   sparse frontiers, and 0 when nothing is active,
@@ -26,10 +30,11 @@ import jax.numpy as jnp
 from conftest import norm_inf
 from repro.core import engine, fusion
 from repro.core import usecases as U
-from repro.graph import segment
-from repro.graph.structure import (push_resolution_cached, rmat_graph,
+from repro.graph import mutate, segment
+from repro.graph.structure import (blocked_ell_cached,
+                                   push_resolution_cached, rmat_graph,
                                    to_blocked_ell, to_push_resolution,
-                                   uniform_graph)
+                                   to_sharded_push_resolution, uniform_graph)
 from repro.kernels import edge_reduce as er
 from repro.kernels import ops as kops
 
@@ -168,6 +173,91 @@ def test_sorted_resolution_work_frontier_proportional():
     none_act = er.resolution_tile_activity(
         res.contrib, jnp.zeros_like(tile_act), res.tile_nnz)
     assert float(jnp.sum(none_act)) == 0.0
+
+
+# ---------------------------------------------------------------------------
+# the compact contributing-tile table: bitwise the dense OR over real pairs
+# ---------------------------------------------------------------------------
+
+def _activity_layouts(g, layout):
+    """``(contrib, tile_nnz, valid, src_tile, n_out_tiles)`` per shard of
+    one resolution layout: the single-device layout, a k-shard stack, or
+    the layout ``mutate_edges`` patches in for an edited graph."""
+    if layout.startswith("sharded"):
+        k = int(layout[len("sharded"):])
+        sres = to_sharded_push_resolution(g, k)
+        n_tiles = sres.tile_nnz[0].size
+        # the shards' real rows differ in some class, so the stack pads
+        real = [(np.asarray(ids) < n_tiles).sum(axis=1)
+                for ids, _ in sres.contrib]
+        assert any(len(set(r.tolist())) > 1 for r in real)
+        n_out = (sres.n_pad // sres.block_v) * (sres.out_width // sres.block_e)
+        return [(tuple((ids[s], lists[s]) for ids, lists in sres.contrib),
+                 sres.tile_nnz[s], sres.valid[s], sres.src_tile[s], n_out)
+                for s in range(k)]
+    if layout == "mutated":
+        blocked_ell_cached(g, direction="in")
+        blocked_ell_cached(g, direction="out")
+        push_resolution_cached(g)
+        src, dst, _w, _c = g.host_edges()
+        g, md = mutate.mutate_edges(g, insert=([1, 2, 3], [4, 5, 6]),
+                                    delete=(src[:3], dst[:3]))
+        assert md.patched_layouts >= 1 and md.rebuilt_layouts == 0
+    res = push_resolution_cached(g)
+    n_out = (res.n_pad // res.block_v) * (res.out_width // res.block_e)
+    return [(res.contrib, res.tile_nnz, res.valid, res.src_tile, n_out)]
+
+
+@pytest.mark.parametrize("layout", ["single", "sharded2", "sharded4",
+                                    "mutated"])
+@pytest.mark.parametrize("act", ["random", "zeros", "ones"])
+@pytest.mark.parametrize("graph", ["rmat", "uniform"])
+def test_compact_activity_matches_dense_pairs(graph, act, layout):
+    """``resolution_tile_activity`` over the compact class table is bitwise
+    the dense numpy OR of the out-tile bitmap over every real (resolution
+    tile, out tile) pair of the layout's slots."""
+    g = rmat_graph(256, 2048, seed=5) if graph == "rmat" \
+        else uniform_graph(256, 2048, seed=5)
+    rng = np.random.default_rng(3)
+    for contrib, tile_nnz, valid, src_tile, n_out in _activity_layouts(
+            g, layout):
+        push_act = {"random": (rng.random(n_out) < 0.2).astype(np.int32),
+                    "zeros": np.zeros(n_out, np.int32),
+                    "ones": np.ones(n_out, np.int32)}[act]
+        n_i, n_j = tile_nnz.shape
+        block_v, block_e = valid.shape[0] // n_i, valid.shape[1] // n_j
+        rows, cols = np.nonzero(np.asarray(valid))
+        r_tile = (rows // block_v) * n_j + cols // block_e
+        want = np.zeros(n_i * n_j, bool)
+        np.logical_or.at(want, r_tile,
+                         push_act[np.asarray(src_tile)[rows, cols]] != 0)
+        got = er.resolution_tile_activity(contrib, jnp.asarray(push_act),
+                                          tile_nnz)
+        np.testing.assert_array_equal(
+            np.asarray(got), want.reshape(n_i, n_j).astype(np.int32))
+
+
+def test_contrib_table_counts_and_activity_reads():
+    """On a skewed graph the class table stays within 2× of the real pairs
+    and far below the dense ``n_tiles × c_max`` rectangle, and a BFS
+    query's ``activity_reads`` is the table's entries per push round."""
+    g = rmat_graph(1024, 8192, seed=1)
+    res = push_resolution_cached(g)
+    c_max = max(lists.shape[0] for _, lists in res.contrib)
+    n_tiles = res.tile_nnz.size
+    assert res.contrib_pairs < res.contrib_entries <= 2 * res.contrib_pairs
+    assert 4 * res.contrib_entries < n_tiles * c_max
+    ids = np.concatenate([np.asarray(i) for i, _ in res.contrib])
+    live = np.flatnonzero(np.asarray(res.tile_nnz).reshape(-1))
+    assert sorted(ids.tolist()) == live.tolist()
+    assert live.size < n_tiles // 2
+    # one column of out-tile ids per tile
+    assert all(lists.shape[1] == i.shape[0] for i, lists in res.contrib)
+    out = engine.run_program(g, fusion.fuse(U.ALL_SPECS["BFS"]()),
+                             engine="pallas", push_resolution="sorted")
+    assert out.stats.push_iters >= 1
+    assert out.stats.activity_reads == \
+        res.contrib_entries * out.stats.push_iters
 
 
 # ---------------------------------------------------------------------------

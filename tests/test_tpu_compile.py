@@ -145,3 +145,48 @@ def test_vmapped_batch_sweep_compiles(spec):
              spec((batch, n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32),
              _states(spec, n, (batch,)), spec((batch, n), jnp.int32),
              _slots(spec, e))
+
+
+
+# The benchmark's GAP graphs: (vertices, padded width of both layouts, arcs,
+# (width, tiles) of each contrib class as structure.contrib_classes builds
+# them).
+GAP = {"gap-kron-15": (2 ** 15, 47 * 128, 882_416,
+                       [(1, 3), (2, 2), (4, 6), (8, 44), (16, 220),
+                        (32, 812), (64, 1364), (128, 3186), (256, 1736),
+                        (461, 309)]),
+       "gap-urand-20": (2 ** 20, 128, 33_553_908,
+                        [(256, 68_665), (333, 62_407)])}
+
+
+@pytest.mark.parametrize("graph", sorted(GAP))
+def test_bfs_fixpoint_compiles(spec, graph):
+    """The whole BFS fixpoint — both sweeps, the sorted resolution and the
+    contributing-tile activity over the compact class table — at the
+    benchmark graphs' shapes, with its three kernels, within the chip's
+    HBM.  (With each class's lists stored as rows rather than columns the
+    urand-20 fixpoint took minutes to compile.)"""
+    from repro.core import engine, fusion, synthesis, usecases
+    from repro.kernels import ops
+    n, width, arcs, classes = GAP[graph]
+    round_ = fusion.fuse(usecases.bfs(0)).rounds[0][1]
+    comps, plans = engine._round_runtime(round_,
+                                         synthesis.synthesize_round(round_))
+    use, dense, switch_k, resolution = ops._apply_plan(
+        None, "auto", ops.DENSE_FRONTIER, "auto", "sorted", True)
+    run = ops._build_pallas_executor(
+        comps, plans, n, 2 * n + 4, 0.0, er.BLOCK_V, er.BLOCK_E, False, use,
+        dense, switch_k, resolution)
+    tiles = spec((n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32)
+    layout = [*_layout(spec, n, width)[:4], tiles, *_slots(spec, arcs)]
+    contrib = tuple((spec((r,), jnp.int32), spec((w, r), jnp.int32))
+                    for w, r in classes)
+    compiled = run.lower(
+        *layout, *layout, spec((n,), jnp.int32), spec((n,), jnp.float32),
+        *_slots(spec, arcs), contrib, tiles,
+        spec((len(comps),), jnp.int32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
