@@ -132,6 +132,16 @@ class ExecStats:
                                     # resolution activity test read
                                     # (contrib_entries × push iterations
                                     # under "sorted"; pallas engines)
+    tiles_swept: int = 0            # live (block_v × block_e) tiles the
+                                    # pull/push sweep kernels ran, summed
+                                    # over iterations (pallas engines;
+                                    # summed over shards when sharded)
+    tile_shape: tuple = ()          # (block_v, block_e) of those tiles
+    exit_change: float = 0.0        # the last iteration's change in the
+                                    # stopping norm of the last round: the
+                                    # L1 change Σ|Δ| of a component under
+                                    # an L1 rule, else max |Δ| (float
+                                    # components; 0 without the sentinel)
     shards: int = 0                 # shard count of the sharded engines
                                     # (distributed / pallas_sharded)
     shard_launches: int = 0         # traced pallas launches PER SHARD
@@ -454,6 +464,13 @@ def _accumulate(stats: ExecStats, res) -> None:
         stats.gather_work += float(gw)
     if isinstance(ar, int):
         stats.activity_reads += ar
+    ts = getattr(res, "tiles_swept", 0)
+    if isinstance(ts, int):
+        stats.tiles_swept += ts
+    stats.tile_shape = getattr(res, "tile_shape", stats.tile_shape)
+    rs = getattr(res, "residual", 0.0)
+    if isinstance(rs, (int, float)):
+        stats.exit_change = float(rs)
     stats.shards = max(stats.shards, getattr(res, "shards", 0))
     stats.shard_launches += getattr(res, "shard_launches", 0)
     stats.cross_combines += getattr(res, "cross_combines", 0)
@@ -748,6 +765,8 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
             res_ws = np.asarray(res.resolve_work)
             gat_ws = np.asarray(res.gather_work)
             acts = np.asarray(res.activity_reads)
+            tiles = np.asarray(res.tiles_swept)
+            resids = np.asarray(res.residual)
             convs = np.asarray(res.converged)
             for b in range(B):
                 st = stats[b]
@@ -759,6 +778,9 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
                 st.resolve_work += float(res_ws[b])
                 st.gather_work += float(gat_ws[b])
                 st.activity_reads += int(acts[b])
+                st.tiles_swept += int(tiles[b])
+                st.tile_shape = res.tile_shape
+                st.exit_change = float(resids[b])
                 st.converged = st.converged and bool(convs[b])
                 for leaf in round_.leaves:
                     envs[b][leaf.name] = res.state[plan_output(leaf.plan)][b]
@@ -946,6 +968,8 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
             res_ws = np.asarray(res.resolve_work)
             gat_ws = np.asarray(res.gather_work)
             acts = np.asarray(res.activity_reads)
+            tiles = np.asarray(res.tiles_swept)
+            resids = np.asarray(res.residual)
             outs = [ExecResult(
                 value=res.state[0][b], named={},
                 stats=ExecStats(rounds=1, iterations=int(iters[b]),
@@ -955,6 +979,9 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
                                 resolve_work=float(res_ws[b]),
                                 gather_work=float(gat_ws[b]),
                                 activity_reads=int(acts[b]),
+                                tiles_swept=int(tiles[b]),
+                                tile_shape=res.tile_shape,
+                                exit_change=float(resids[b]),
                                 engine_used="pallas", plan=plan))
                 for b in range(len(iters))]
             for o in outs:

@@ -38,6 +38,7 @@ class Component:
     idx: int
     f: L.PathFn
     source: Optional[int]          # None ⇒ Paths(v) (all sources)
+    tol: float = 0.0               # L1 stopping tolerance (PathReduce.tol)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -75,9 +76,10 @@ def plan_output(plan) -> int:
 def plan_key(plan, comps) -> str:
     if isinstance(plan, Prim):
         c = comps[plan.comp]
-        return f"{plan.op}:{c.f.kind}@{c.source}"
+        tol = f"~{c.tol}" if c.tol else ""
+        return f"{plan.op}:{c.f}@{c.source}{tol}"
     c = comps[plan.comp]
-    return f"lex[{plan.op}:{c.f.kind}@{c.source}]->{plan_key(plan.secondary, comps)}"
+    return f"lex[{plan.op}:{c.f}@{c.source}]->{plan_key(plan.secondary, comps)}"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,13 +144,14 @@ class _RoundBuilder:
         self._fresh += 1
         return f"{prefix}{self._fresh}"
 
-    def component(self, f: L.PathFn, source) -> int:
+    def component(self, f: L.PathFn, source, tol: float = 0.0) -> int:
         # NOTE: components are per plan-position, NOT deduped on (f, source) —
         # two leaves reducing the same F with different monoids (e.g. NWR's
         # min-capacity and max-capacity) need distinct iteration state.
         # Common-operation elimination happens at leaf granularity below.
         idx = len(self.components)
-        self.components.append(Component(idx=idx, f=f, source=source))
+        self.components.append(Component(idx=idx, f=f, source=source,
+                                         tol=tol))
         self._pending_comps += 1
         return idx
 
@@ -170,9 +173,11 @@ class _RoundBuilder:
         return name
 
     # ----- path-set flattening (FPNEST) ------------------------------------
-    def flatten_paths(self, pathset, final_op: str, final_f: L.PathFn):
+    def flatten_paths(self, pathset, final_op: str, final_f: L.PathFn,
+                      tol: float = 0.0):
         """Build the lexicographic plan for (possibly nested) restricted
-        paths; returns (plan, source)."""
+        paths; returns (plan, source).  ``tol`` is the final reduction's
+        L1 stopping tolerance."""
         restricts = []
         ps = pathset
         while isinstance(ps, L.ArgsRestrict):
@@ -180,7 +185,7 @@ class _RoundBuilder:
             ps = ps.inner
         assert isinstance(ps, L.AllPaths)
         source = ps.source
-        plan = Prim(final_op, self.component(final_f, source))
+        plan = Prim(final_op, self.component(final_f, source, tol))
         # FPNEST flattens innermost-first: for
         # ArgsRestrict(r2,f2, ArgsRestrict(r1,f1, All)) the primary key is f1
         # (innermost restrict), then f2, then the final F.  `restricts` is
@@ -194,7 +199,7 @@ class _RoundBuilder:
     # ----- m-terms → per-vertex Expr ----------------------------------------
     def lower_m(self, t) -> Expr:
         if isinstance(t, L.PathReduce):
-            plan, _ = self.flatten_paths(t.paths, t.r, t.f)
+            plan, _ = self.flatten_paths(t.paths, t.r, t.f, t.tol)
             return Var(self.leaf(plan), "float")
         if isinstance(t, L.PathSel):
             self.stats.fmred += 1

@@ -57,6 +57,10 @@ class CompRuntime:
                                      # I; legacy single-arg closures accepted)
     source: Optional[int]
     e_fn: Optional[Callable] = None  # epilogue (PageRank); None = identity
+    l1_tol: float = 0.0              # > 0: the component stays live while
+                                     # its L1 change Σ|Δ| ≥ l1_tol (GAP's
+                                     # PageRank rule), replacing the
+                                     # per-vertex |Δ| > tol test
 
     @property
     def ident(self):
@@ -83,7 +87,8 @@ def comp_runtimes(round_: FusedRound, synth: dict) -> list:
         e_fn = entry[2] if len(entry) > 2 else None
         out.append(CompRuntime(
             idx=comp.idx, op=ops[comp.idx], dtype=DTYPES[comp.f.dtype],
-            p_fn=p_fn, init_fn=init_fn, source=comp.source, e_fn=e_fn))
+            p_fn=p_fn, init_fn=init_fn, source=comp.source, e_fn=e_fn,
+            l1_tol=comp.tol))
     return out
 
 
@@ -220,14 +225,25 @@ def _divergence(comps, new):
     return bad
 
 
-def _residual(comps, new, old):
-    """Max |new − old| over float components — the last iteration's residual,
-    reported in NonConvergence diagnostics.  Non-finite diffs (a vertex
-    leaving ⊥) are masked: 'changed from unreachable' is active_count's
-    story, not a numeric residual."""
+def _l1_change(new, old, n):
+    """Σ_v |new(v) − old(v)| over the first ``n`` (real) vertices: the
+    vertex reduction of GAP's PageRank stopping rule."""
+    with jax.named_scope("grafs.converge"):
+        return jnp.sum(jnp.abs(new[:n] - old[:n]).astype(jnp.float32))
+
+
+def _residual(comps, new, old, n):
+    """The last iteration's change in each component's stopping norm —
+    Σ|Δ| under an L1 rule, else max |Δ| — maxed over float components and
+    reported in NonConvergence diagnostics and ``ExecStats.exit_change``.
+    Non-finite diffs (a vertex leaving ⊥) are masked: 'changed from
+    unreachable' is active_count's story, not a numeric residual."""
     r = jnp.float32(0)
     for i, cr in enumerate(comps):
         if not jnp.issubdtype(cr.dtype, jnp.floating):
+            continue
+        if cr.l1_tol:
+            r = jnp.maximum(r, _l1_change(new[i], old[i], n))
             continue
         d = jnp.abs(new[i] - old[i])
         r = jnp.maximum(r, jnp.max(jnp.where(jnp.isfinite(d), d,
@@ -268,7 +284,8 @@ def check_shard_replication(counts, what: str, engine: str) -> None:
 
 
 def _init_arity(init_fn) -> int:
-    """Positional arity of an init kernel: 2 for the source-generic form
+    """Positional arity of an init kernel: 3 for the synthesized form
+    ``init_fn(v, src, nv)``, 2 for the source-generic form
     ``init_fn(v, src)``, 1 for legacy closures that bake the source in."""
     try:
         params = inspect.signature(init_fn).parameters.values()
@@ -276,7 +293,7 @@ def _init_arity(init_fn) -> int:
         return 1
     n_pos = sum(p.kind in (p.POSITIONAL_ONLY, p.POSITIONAL_OR_KEYWORD)
                 for p in params)
-    return 2 if n_pos >= 2 else 1
+    return min(n_pos, 3) if n_pos >= 2 else 1
 
 
 def _init_state(comps, n: int, sources: Optional[dict] = None):
@@ -295,7 +312,10 @@ def _init_state(comps, n: int, sources: Optional[dict] = None):
         src = cr.source
         if sources is not None and cr.source is not None:
             src = sources.get(cr.idx, cr.source)
-        if _init_arity(cr.init_fn) >= 2:
+        arity = _init_arity(cr.init_fn)
+        if arity == 3:
+            vals = cr.init_fn(v, src, float(n))
+        elif arity == 2:
             vals = cr.init_fn(v, src)
         else:
             vals = cr.init_fn(v)
@@ -328,21 +348,32 @@ def _propagate(comps, state, src, env):
     return evals
 
 
-def _changed(comps, new, old, tol):
+def _changed(comps, new, old, tol, n):
+    """The next iteration's frontier: per vertex, whether a component moved
+    (by more than ``tol`` for float components).  A component under an L1
+    rule keeps every vertex live while Σ_v |Δ(v)| over the ``n`` real
+    vertices is ≥ its ``l1_tol``, and none once it drops below."""
     ch = jnp.zeros(new[0].shape, dtype=bool)
     for i, cr in enumerate(comps):
-        if tol > 0 and jnp.issubdtype(cr.dtype, jnp.floating):
+        if cr.l1_tol:
+            ch = ch | (_l1_change(new[i], old[i], n) >= cr.l1_tol)
+        elif tol > 0 and jnp.issubdtype(cr.dtype, jnp.floating):
             ch = ch | (jnp.abs(new[i] - old[i]) > tol)
         else:
             ch = ch | (new[i] != old[i])
     return ch
 
 
-def _apply_epilogue(comps, red: dict) -> dict:
+def _apply_epilogue(comps, red: dict, nv: int) -> dict:
+    """E on each reduction that has one; E's env holds the reduction
+    ``n`` and |V| as ``nv``."""
     out = dict(red)
-    for cr in comps:
-        if cr.e_fn is not None:
-            out[cr.idx] = jnp.asarray(cr.e_fn({"n": red[cr.idx]}), dtype=cr.dtype)
+    with jax.named_scope("grafs.epilogue"):
+        for cr in comps:
+            if cr.e_fn is not None:
+                out[cr.idx] = jnp.asarray(
+                    cr.e_fn({"n": red[cr.idx], "nv": jnp.float32(nv)}),
+                    dtype=cr.dtype)
     return out
 
 
@@ -412,13 +443,13 @@ def iterate_graph(g: Graph, comps, plans, model: str = "pull+",
                 for p in plans:
                     red.update(plan_scatter_reduce(
                         p, ident, evals, dst, valid_e, keep, comps_by_idx))
-            red = _apply_epilogue(comps, red)
+            red = _apply_epilogue(comps, red, n)
             has_pred = _has_pred(comps, state, src, dst, valid_e, n)
             new_d = _recompute_merge(plans, comps_by_idx, state_d, red, has_pred)
         new = tuple(new_d[cr.idx] for cr in comps)
-        ch = _changed(comps, new, state, tol)
+        ch = _changed(comps, new, state, tol, n)
         div = div | _divergence(comps, new)
-        resid = _residual(comps, new, state)
+        resid = _residual(comps, new, state, n)
         ch = ch & ~div                     # divergence drains the frontier:
         return new, ch, k + 1, work, div, resid   # the loop exits next cond
 
@@ -495,9 +526,9 @@ def iterate_adaptive(g: Graph, comps, plans, max_iter: Optional[int] = None,
                            (state, active))
         work = work + jnp.sum(active.astype(jnp.float32)
                               * g.out_deg.astype(jnp.float32))
-        ch = _changed(comps, new, state, tol)
+        ch = _changed(comps, new, state, tol, n)
         div = div | _divergence(comps, new)
-        resid = _residual(comps, new, state)
+        resid = _residual(comps, new, state, n)
         ch = ch & ~div
         return (new, ch, k + 1, work, pulls + use_pull.astype(jnp.int32),
                 div, resid)
@@ -575,7 +606,7 @@ def iterate_dense(g: Graph, comps, plans, model: str = "pull+",
         red = {}
         for pl in plans:
             red.update(dense_reduce(pl, mats))
-        red = _apply_epilogue(comps, red)
+        red = _apply_epilogue(comps, red, n)
         if idempotent:
             new_d = {}
             for pl in plans:
@@ -585,9 +616,9 @@ def iterate_dense(g: Graph, comps, plans, model: str = "pull+",
                                         axis=0) for cr in comps}
             new_d = _recompute_merge(plans, comps_by_idx, state_d, red, has_pred)
         new = tuple(new_d[cr.idx] for cr in comps)
-        ch = _changed(comps, new, state, tol)
+        ch = _changed(comps, new, state, tol, n)
         div = div | _divergence(comps, new)
-        resid = _residual(comps, new, state)
+        resid = _residual(comps, new, state, n)
         ch = ch & ~div
         return new, ch, k + 1, work, div, resid
 
@@ -676,7 +707,7 @@ def iterate_distributed(g: Graph, comps, plans, mesh, axes=("data",),
                 for p in plans:
                     new_d.update(plan_merge(p, state_d, red, comps_by_idx))
             else:
-                red = _apply_epilogue(comps, red)
+                red = _apply_epilogue(comps, red, n)
                 nonbot = {cr.idx: segment.segment_reduce(
                     "or", (state_d[cr.idx][src] != cr.ident) & mask, dst, n)
                     for cr in comps}
@@ -684,11 +715,11 @@ def iterate_distributed(g: Graph, comps, plans, mesh, axes=("data",),
                             for i in nonbot}
                 new_d = _recompute_merge(plans, comps_by_idx, state_d, red, has_pred)
             new = tuple(new_d[cr.idx] for cr in comps)
-            ch = _changed(comps, new, state, tol)
+            ch = _changed(comps, new, state, tol, n)
             # sentinel on the replicated post-combine state: every shard
             # computes the identical flag, so the drain stays collective-safe
             div = div | _divergence(comps, new)
-            resid = _residual(comps, new, state)
+            resid = _residual(comps, new, state, n)
             ch = ch & ~div
             return new, ch, k + 1, work, div, resid
 

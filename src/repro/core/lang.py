@@ -39,26 +39,31 @@ CAP_INF = 1e30
 
 @dataclasses.dataclass(frozen=True)
 class PathFn:
-    kind: str          # length|weight|capacity|head|penultimate|one
+    kind: str          # length|weight|capacity|head|penultimate|one|rank
     dtype: str         # "int"|"float"|"vert"
+    damping: float = 0.0   # d of the "rank" walk weight; no other kind reads it
 
-    def trivial(self, v):
-        """F(⟨v,v⟩): value on the zero-length path at v."""
+    def trivial(self, v, nv=None):
+        """F(⟨v,v⟩): value on the zero-length path at v (``nv`` = |V|)."""
+        if self.kind == "rank":
+            return (1.0 - self.damping) / nv
         return {"length": 0, "weight": 0.0, "capacity": CAP_INF, "head": v,
                 "penultimate": v, "one": 1}[self.kind]
 
     def extend(self, n, edge):
-        """F(p·e) given F(p)=n and e=(src,dst,w,c) — the extension law."""
-        src, dst, w, c = edge
+        """F(p·e) given F(p)=n and e=(src,dst,w,c[,outdeg(src)]) — the
+        extension law."""
+        src, dst, w, c = edge[:4]
         return {"length": lambda: n + 1,
                 "weight": lambda: n + w,
                 "capacity": lambda: min(n, c),
                 "head": lambda: n,
                 "penultimate": lambda: src,
-                "one": lambda: n}[self.kind]()
+                "one": lambda: n,
+                "rank": lambda: n * self.damping / edge[4]}[self.kind]()
 
     def __str__(self):
-        return self.kind
+        return self.kind if self.kind != "rank" else f"rank({self.damping})"
 
 
 LENGTH = PathFn("length", "int")
@@ -70,6 +75,16 @@ ONE = PathFn("one", "int")
 
 PATH_FNS = {f.kind: f for f in
             (LENGTH, WEIGHT, CAPACITY, HEAD, PENULTIMATE, ONE)}
+
+
+def rank(damping: float) -> PathFn:
+    """The damped random-walk weight of a path (PageRank): (1−d)/|V| on the
+    zero-length path, and each edge multiplies by d/outdeg(src(e)).  Its
+    sum over every walk ending at v is v's PageRank; the sum over the walks
+    of length ≤ k is the k-th Jacobi iterate from (1−d)/|V|."""
+    if not 0.0 < damping < 1.0:
+        raise ValueError(f"damping must lie in (0, 1), got {damping}")
+    return PathFn("rank", "float", float(damping))
 
 # Reduction functions R (commutative + associative; C7, C8).
 IDEMPOTENT = {"min": True, "max": True, "or": True, "and": True,
@@ -118,10 +133,14 @@ class ArgsRestrict:
 
 @dataclasses.dataclass(frozen=True)
 class PathReduce(Term):
-    """R_{p ∈ paths} F(p)."""
+    """R_{p ∈ paths} F(p).  ``tol`` > 0 states how a non-idempotent
+    reduction over infinitely many walks is cut off: the fixpoint stops at
+    the first iteration whose L1 change Σ_v |Δ(v)| is under ``tol`` (GAP's
+    PageRank rule); 0 means the exact fixpoint."""
     r: str
     f: PathFn
     paths: "AllPaths | ArgsRestrict" = AllPaths()
+    tol: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -198,10 +217,12 @@ def _enum_paths(g: Graph, max_len: int):
     """All paths of length ≤ max_len, as lists of edge tuples, grouped by
     destination.  Exponential — test-sized graphs only."""
     src, dst, w, c = g.host_edges()
+    outdeg = np.bincount(src, minlength=g.n)
     out_edges = [[] for _ in range(g.n)]
     for i in range(src.shape[0]):
         out_edges[int(src[i])].append((int(src[i]), int(dst[i]),
-                                       float(w[i]), float(c[i])))
+                                       float(w[i]), float(c[i]),
+                                       float(outdeg[src[i]])))
     by_dst = [[] for _ in range(g.n)]
     for v in range(g.n):
         by_dst[v].append((v, []))           # trivial path ⟨v,v⟩ (head=v)
@@ -217,8 +238,8 @@ def _enum_paths(g: Graph, max_len: int):
     return by_dst
 
 
-def _path_value(f: PathFn, head: int, path):
-    n = f.trivial(head)
+def _path_value(f: PathFn, head: int, path, nv=None):
+    n = f.trivial(head, nv)
     for e in path:
         n = f.extend(n, e)
     return n
@@ -246,7 +267,9 @@ def paths_semantics(term: Term, g: Graph, max_len: Optional[int] = None,
                     scalars: Optional[dict] = None):
     """⟦term⟧ by explicit enumeration of paths with length ≤ max_len
     (Def. 6; with max_len ≥ longest simple path this equals Def. 5 whenever
-    the termination condition C10 holds)."""
+    the termination condition C10 holds).  Paths here are walks: a sum over
+    ``rank`` walks with max_len = k is the truncated walk sum, the k-th
+    Jacobi iterate; a reduction's ``tol`` is not read."""
     if max_len is None:
         max_len = g.n
     scalars = scalars or {}
@@ -259,7 +282,7 @@ def paths_semantics(term: Term, g: Graph, max_len: Optional[int] = None,
             for v in range(g.n):
                 acc = reduce_identity(t.r)
                 for (h, p) in _paths_for(t.paths, by_dst[v], v):
-                    acc = reduce_op(t.r, acc, _path_value(t.f, h, p))
+                    acc = reduce_op(t.r, acc, _path_value(t.f, h, p, g.n))
                 out[v] = acc
             return out
         if isinstance(t, PathSel):

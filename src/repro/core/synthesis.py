@@ -24,8 +24,9 @@ import numpy as np
 from repro import obs
 from repro.core import conditions as C
 from repro.core import lang as L
-from repro.core.kernel_lang import (Enumerator, Expr, Lit, Var, FLT, INT, VERT,
-                                    compile_expr, default_terminals, expr_size)
+from repro.core.kernel_lang import (Bin, Enumerator, Expr, Lit, Var, FLT, INT,
+                                    VERT, compile_expr, default_terminals,
+                                    expr_size)
 
 
 @dataclasses.dataclass
@@ -35,30 +36,40 @@ class SynthesizedKernels:
     p_expr: Expr
     i_expr: Expr                  # on-source branch (C2's ⊥ branch is structural)
     idempotent: bool
-    terminating: bool             # strengthened C10 verified
+    terminating: bool             # strengthened C10 verified (idempotent R),
+                                  # or an L1 contraction (sum)
     candidates_tried: int
     wall_ms: float
+    e_expr: Optional[Expr] = None  # epilogue over {n, nv}; None = identity
 
     def p_fn(self):
         return compile_expr(self.p_expr)
 
+    def e_fn(self):
+        """The epilogue ``E(env)`` over ``{"n": reduction, "nv": |V|}``, or
+        None."""
+        return None if self.e_expr is None else compile_expr(self.e_expr)
+
     def init_fn(self):
-        """Source-GENERIC init kernel ``init_fn(v, s=None)`` (DESIGN.md §8).
+        """Source-GENERIC init kernel ``init_fn(v, s=None, nv=None)``
+        (DESIGN.md §8).
 
         The on-source branch is only ever read where ``v == s`` (the engine
         masks everything else to ⊥ per C2), so the source enters as a plain
         value — a traced scalar works as well as a Python int, which is what
         lets one compiled executor serve every query source.  ``s=None``
         (sourceless components, Paths(v)) evaluates the trivial path at each
-        vertex, i.e. ``s := v``."""
+        vertex, i.e. ``s := v``.  ``nv`` is |V|, which the engines pass
+        (PageRank's base score reads it)."""
         fn = compile_expr(self.i_expr)
-        return lambda v, s=None: fn({"v": v, "s": v if s is None else s})
+        return lambda v, s=None, nv=None: fn(
+            {"v": v, "s": v if s is None else s, "nv": nv})
 
     def describe(self) -> str:
         return (f"I := λv. if (v = s) {self.i_expr} else ⊥\n"
                 f"P := λn, e. {self.p_expr}\n"
                 f"R := {self.rop}  (idempotent={self.idempotent})\n"
-                f"E := λn. n")
+                f"E := λn. {self.e_expr if self.e_expr is not None else 'n'}")
 
 
 _VALUE_TY = {"int": INT, "float": FLT, "vert": VERT}
@@ -70,8 +81,14 @@ class SynthesisError(Exception):
 
 
 def synthesize_component(f: L.PathFn, rop: str,
-                         require_idempotent: bool = False) -> SynthesizedKernels:
-    key = (f.kind, rop, require_idempotent)
+                         require_idempotent: bool = False,
+                         sourced: bool = True) -> SynthesizedKernels:
+    """Kernels for one component ``R F``.  A path function's parameters
+    (the damping of ``rank``) join the grammar's literals.  A sourceless
+    ``sum`` whose P contracts in L1 also gets the epilogue E := λn. n + I:
+    every vertex is the head of its own zero-length path, so each full
+    recompute merges that path back in — x(v) = I(v) + Σ_{(u,v)} P(x(u))."""
+    key = (f, rop, require_idempotent, sourced)
     if key in _CACHE:
         return _CACHE[key]
     t0 = time.perf_counter()
@@ -85,7 +102,8 @@ def synthesize_component(f: L.PathFn, rop: str,
     # --- P: C5 then C4, smallest first ------------------------------------
     tried = 0
     p_expr = None
-    enum = Enumerator(default_terminals(ty))
+    params = [Lit(f.damping, FLT)] if f.kind == "rank" else []
+    enum = Enumerator(default_terminals(ty) + params)
     for cand in enum.upto(ty, 5):
         tried += 1
         if C.check_C5(cand, f, rng) and C.check_C4(cand, f, rop, rng):
@@ -97,9 +115,11 @@ def synthesize_component(f: L.PathFn, rop: str,
     # --- I: the on-source branch must match F(⟨v,v⟩) (C1) ------------------
     init_terms = [Lit(0, INT), Lit(1, INT), Lit(L.CAP_INF, FLT),
                   Var("v", VERT), Var("s", VERT)]
+    if params:
+        init_terms += [Var("nv", FLT)] + params
     i_expr = None
     ienum = Enumerator(init_terms)
-    for cand in ienum.upto(ty, 3):
+    for cand in ienum.upto(ty, 5 if params else 3):
         tried += 1
         if C.check_I(cand, f, rng):
             i_expr = cand
@@ -107,11 +127,18 @@ def synthesize_component(f: L.PathFn, rop: str,
     if i_expr is None:
         raise SynthesisError(f"no initialization function found for {f}")
 
-    terminating = C.check_C10(f, rop, rng)
+    e_expr = None
+    if rop == "sum":
+        terminating = C.contraction_factor(p_expr) < 1.0
+        if terminating and not sourced:
+            e_expr = Bin("+", Var("n", ty), i_expr)
+    else:
+        terminating = C.check_C10(f, rop, rng)
     out = SynthesizedKernels(
         f=f, rop=rop, p_expr=p_expr, i_expr=i_expr,
         idempotent=L.IDEMPOTENT[rop], terminating=terminating,
-        candidates_tried=tried, wall_ms=(time.perf_counter() - t0) * 1e3)
+        candidates_tried=tried, wall_ms=(time.perf_counter() - t0) * 1e3,
+        e_expr=e_expr)
     _CACHE[key] = out
     return out
 
@@ -149,7 +176,7 @@ def round_structure_key(round_) -> tuple:
     whether a component has a source at all (Paths(s,·) vs Paths(v)) is
     structural: it decides the ⊥-masking shape of the initial state."""
     ops = _plan_position_ops(round_)
-    return tuple((comp.idx, comp.f.kind, comp.source is not None,
+    return tuple((comp.idx, comp.f, comp.source is not None,
                   ops[comp.idx])
                  for comp in round_.components)
 
@@ -171,8 +198,9 @@ def synthesize_round(round_) -> dict:
     ops = _plan_position_ops(round_)
     out = {}
     for comp in round_.components:
-        sk = synthesize_component(comp.f, ops[comp.idx])
-        out[comp.idx] = (sk.p_fn(), sk.init_fn())
+        sk = synthesize_component(comp.f, ops[comp.idx],
+                                  sourced=comp.source is not None)
+        out[comp.idx] = (sk.p_fn(), sk.init_fn(), sk.e_fn())
         out[("kernels", comp.idx)] = sk
     _ROUND_CACHE[key] = out
     return out

@@ -15,7 +15,7 @@ from __future__ import annotations
 from repro.core.lang import (AllPaths, ArgsRestrict, CAPACITY, Cardinality,
                              HEAD, LENGTH, LetRound, MBin, MConst, ONE,
                              PathReduce, PathSel, PENULTIMATE, RBin, RConst,
-                             ScalarRef, Term, VertexReduce, WEIGHT)
+                             ScalarRef, Term, VertexReduce, WEIGHT, rank)
 
 
 # --- single path-based reductions ------------------------------------------
@@ -52,6 +52,14 @@ def reach(s: int) -> Term:
     aggregate available via DS-style constraints; the direct boolean
     path-reduction uses ONE with the `or` monoid."""
     return PathReduce("or", ONE, AllPaths(s))
+
+
+def pagerank(damping: float = 0.85, tol: float = 1e-4) -> Term:
+    """PR(v) = Σ_{p∈Paths(v)} (1−d)/|V| · Π_{e∈p} d/outdeg(src(e)): the
+    damped walk weight summed over every walk ending at v.  A non-idempotent
+    sum over infinitely many walks, cut off by GAP's rule: the fixpoint
+    stops at the first iteration whose L1 change is under ``tol``."""
+    return PathReduce("sum", rank(damping), AllPaths(None), tol=tol)
 
 
 def n_reachable(s: int) -> Term:

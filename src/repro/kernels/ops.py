@@ -167,7 +167,8 @@ def _comps_key(comps):
     the initial state) is structural."""
     return tuple((cr.idx, cr.op, str(cr.dtype), cr.source is not None,
                   id(cr.p_fn), id(cr.init_fn),
-                  None if cr.e_fn is None else id(cr.e_fn)) for cr in comps)
+                  None if cr.e_fn is None else id(cr.e_fn), cr.l1_tol)
+                 for cr in comps)
 
 
 # Knob semantics live in the planner (core.plan, DESIGN.md §14) — ops
@@ -245,8 +246,8 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
     with ``srcs`` an [n_comps] int32 vector, so one compiled executor serves
     every graph with the same padded shapes and EVERY query source without
     retracing.  It returns the full exit diagnostics
-    ``(state, k, work, pushes, res_work, gather_work, div, resid,
-    active_n)``.
+    ``(state, k, work, pushes, res_work, gather_work, tiles, div, resid,
+    active_n)``, ``tiles`` the live tiles the main sweeps ran.
 
     ``use`` = ("pull",) | ("push",) | ("pull", "push"); with both, each
     iteration picks its sweep via ``lax.cond`` — both branches trace (two
@@ -379,12 +380,13 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 red, _, res_w, gat_w = sweep(d, state_d, active_i32, tile_act,
                                              False)
                 w_inc = jnp.sum((tile_nnz * tile_act)).astype(jnp.float32)
-                return tuple(red[c] for c in comps_order), w_inc, res_w, gat_w
+                return (tuple(red[c] for c in comps_order), w_inc, res_w,
+                        gat_w, jnp.sum(tile_act))
             return branch
 
         def body(carry):
-            (state, active, k, work, pushes, res_work, gather_work, div,
-             resid) = carry
+            (state, active, k, work, pushes, res_work, gather_work, tiles,
+             div, resid) = carry
             state_d = {cr.idx: state[i] for i, cr in enumerate(comps)}
             if idempotent:
                 active_i32 = active.astype(jnp.int32)
@@ -407,18 +409,19 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                             # dilute).
                             frac = jnp.sum(active.astype(jnp.float32)) / n
                             use_push = frac <= dense_threshold
-                    red_t, w_inc, res_w, gat_w = jax.lax.cond(
+                    red_t, w_inc, res_w, gat_w, t_inc = jax.lax.cond(
                         use_push, masked_branch("push"), masked_branch("pull"),
                         (state_d, active_i32))
                     pushes = pushes + use_push.astype(jnp.int32)
                 else:
-                    red_t, w_inc, res_w, gat_w = masked_branch(use[0])(
+                    red_t, w_inc, res_w, gat_w, t_inc = masked_branch(use[0])(
                         (state_d, active_i32))
                     pushes = pushes + (1 if use[0] == "push" else 0)
                 red = {c: red_t[i] for i, c in enumerate(comps_order)}
                 work = work + w_inc
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
+                tiles = tiles + t_inc
                 new_d = {}
                 with jax.named_scope("grafs.merge"):
                     for p in plans:
@@ -434,27 +437,28 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                                               tiles_static, True)
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
-                red = iterate._apply_epilogue(comps, red)
+                tiles = tiles + jnp.sum(tiles_static)
+                red = iterate._apply_epilogue(comps, red, n)
                 with jax.named_scope("grafs.merge"):
                     new_d = iterate._recompute_merge(plans, comps_by_idx,
                                                      state_d, red, hp)
                 pushes = pushes + (1 if d == "push" else 0)
             new = tuple(new_d[cr.idx] for cr in comps)
             with jax.named_scope("grafs.merge"):
-                ch = iterate._changed(comps, new, state, tol)
+                ch = iterate._changed(comps, new, state, tol, n)
                 if sentinel:
                     # fold divergence + residual into the existing carry:
                     # pure elementwise reductions, no extra kernel launches.
                     # A fired sentinel drains the frontier so the loop exits
                     # on its own condition.
                     div = div | iterate._divergence(comps, new)
-                    resid = iterate._residual(comps, new, state)
+                    resid = iterate._residual(comps, new, state, n)
                     ch = ch & ~div
             return (new, ch, k + 1, work, pushes, res_work, gather_work,
-                    div, resid)
+                    tiles, div, resid)
 
         def cond(carry):
-            _, active, k, _, _, _, _, _, _ = carry
+            active, k = carry[1], carry[2]
             return jnp.any(active) & (k < k_stop)
 
         return jax.lax.while_loop(cond, body, carry0)
@@ -467,15 +471,17 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
         state0 = _padded_init_state(comps, n, n_pad, srcs)
         return (state0, jnp.ones(n_pad, bool), jnp.int32(0),
                 jnp.float32(0), jnp.int32(0), jnp.float32(0),
-                jnp.float32(0), jnp.asarray(False), jnp.float32(0))
+                jnp.float32(0), jnp.int32(0), jnp.asarray(False),
+                jnp.float32(0))
+
+    def _exit(carry):
+        """The executor's outputs from the final carry: the frontier
+        becomes its live count over the real vertices."""
+        state, active, *counters = carry
+        return (state, *counters, jnp.sum(active[:n].astype(jnp.int32)))
 
     def run(*arrays):
-        carry = _fixpoint(arrays, _init(arrays), max_iter)
-        (state, active, k, work, pushes, res_work, gather_work, div,
-         resid) = carry
-        active_n = jnp.sum(active[:n].astype(jnp.int32))
-        return (state, k, work, pushes, res_work, gather_work, div, resid,
-                active_n)
+        return _exit(_fixpoint(arrays, _init(arrays), max_iter))
 
     if warm and not batch:
         raise ValueError("warm start rows are a batched-executor feature; "
@@ -501,18 +507,10 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             def run_warm(*all_args):
                 arrays = all_args[:n_shared + 1]      # shared + this row's srcs
                 state0 = all_args[n_shared + 1:]      # per-component [n] rows
-                (st, active, k, work, pushes, res_work, gather_work, div,
-                 resid) = _init(arrays)
+                st, *rest = _init(arrays)
                 st = tuple(ref.at[:n].set(s.astype(ref.dtype))
                            for ref, s in zip(st, state0))
-                carry = _fixpoint(
-                    arrays, (st, active, k, work, pushes, res_work,
-                             gather_work, div, resid), max_iter)
-                (state, active, k, work, pushes, res_work, gather_work, div,
-                 resid) = carry
-                active_n = jnp.sum(active[:n].astype(jnp.int32))
-                return (state, k, work, pushes, res_work, gather_work, div,
-                        resid, active_n)
+                return _exit(_fixpoint(arrays, (st, *rest), max_iter))
 
             return jax.jit(jax.vmap(
                 run_warm,
@@ -593,7 +591,8 @@ def _fixpoint_fingerprint(g, comps, plans, use, max_iter, tol, block_v,
         "n": int(g.n), "num_edges": int(g.num_edges),
         "plans": repr(tuple(tuple(_plan_levels(p)) for p in plans)),
         "comps": repr(tuple((cr.idx, cr.op, str(np.dtype(cr.dtype)),
-                             cr.e_fn is not None) for cr in comps)),
+                             cr.e_fn is not None, cr.l1_tol)
+                            for cr in comps)),
         "use": list(use), "max_iter": int(max_iter), "tol": float(tol),
         "block_v": int(block_v), "block_e": int(block_e),
         "push_resolution": str(push_resolution),
@@ -607,8 +606,7 @@ def _warm_start_carry(carry, comps, init_state, n):
     [n] arrays (the warm-start primitive): padding rows keep the reduction
     identity, the frontier resets to all-ones so the first sweep re-derives
     the true active set from the supplied state."""
-    (state0, active, k, work, pushes, res_work, gather_work, div,
-     resid) = carry
+    state0, *rest = carry
     init_state = tuple(init_state)
     if len(init_state) != len(comps):
         raise ValueError(
@@ -622,8 +620,7 @@ def _warm_start_carry(carry, comps, init_state, n):
                 f"init_state for component {cr.idx} has shape {a.shape}, "
                 f"expected ({n},)")
         new_state.append(ref.at[:n].set(a))
-    return (tuple(new_state), active, k, work, pushes, res_work, gather_work,
-            div, resid)
+    return (tuple(new_state), *rest)
 
 
 def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
@@ -747,7 +744,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         if not isinstance(out[1], jax.core.Tracer):   # not under a jit
             with obs.span("grafs.device_wait"):
                 jax.block_until_ready(out)
-        (state, k, work, pushes, res_work, gather_work, div, resid,
+        (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
          act_n) = out
     else:
         pair, args, entries = _pallas_executor(
@@ -791,7 +788,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
                 ckpt.save(carry, k_done)
             if fault_hook is not None:
                 fault_hook(k_done)
-        (state, active, k, work, pushes, res_work, gather_work, div,
+        (state, active, k, work, pushes, res_work, gather_work, tiles, div,
          resid) = carry
         act_n = jnp.sum(active[:n].astype(jnp.int32))
     with obs.span("grafs.stats_to_host"):
@@ -810,6 +807,8 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         res.resolve_work = iterate._host(res_work, float)
         res.gather_work = iterate._host(gather_work, float)
         res.activity_reads = entries * p_i
+        res.tiles_swept = iterate._host(tiles, int)
+        res.tile_shape = (block_v, block_e)
     return res
 
 
@@ -878,10 +877,10 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
         dense_threshold, switch_k, push_resolution, batch=True,
         warm=init_state is not None)
     if init_state is not None:
-        (state, k, work, pushes, res_work, gather_work, div, resid,
+        (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
          act_n) = run(*args, srcs, *init_state)
     else:
-        (state, k, work, pushes, res_work, gather_work, div, resid,
+        (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
          act_n) = run(*args, srcs)
     res = iterate.IterationResult(
         state=tuple(s[:, :n] for s in state),
@@ -895,6 +894,8 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
     res.pull_iters = k - pushes
     res.resolve_work = res_work           # [B] per-query resolution work
     res.gather_work = gather_work         # [B] per-query gather work
+    res.tiles_swept = tiles               # [B] per-query live tiles swept
+    res.tile_shape = (block_v, block_e)
     # [B] contrib-table entries read (int64 on the host: a large table times
     # the push rounds passes int32)
     res.activity_reads = entries * np.asarray(pushes, np.int64)
@@ -1076,12 +1077,13 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 red, _, res_w, gat_w = sweep(d, state_d, active_i32, tile_act,
                                              False)
                 w_inc = jnp.sum((tile_nnz * tile_act)).astype(jnp.float32)
-                return tuple(red[c] for c in comps_order), w_inc, res_w, gat_w
+                return (tuple(red[c] for c in comps_order), w_inc, res_w,
+                        gat_w, jnp.sum(tile_act))
             return branch
 
         def body(carry):
-            (state, active, k, work, pushes, res_work, gather_work, div,
-             resid) = carry
+            (state, active, k, work, pushes, res_work, gather_work, tiles,
+             div, resid) = carry
             state_d = {cr.idx: state[i] for i, cr in enumerate(comps)}
             if idempotent:
                 active_i32 = active.astype(jnp.int32)
@@ -1101,12 +1103,12 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                         # replicated, so this is shard-invariant by itself.
                         frac = jnp.sum(active.astype(jnp.float32)) / n
                         use_push = frac <= dense_threshold
-                    red_t, w_inc, res_w, gat_w = jax.lax.cond(
+                    red_t, w_inc, res_w, gat_w, t_inc = jax.lax.cond(
                         use_push, masked_branch("push"), masked_branch("pull"),
                         (state_d, active_i32))
                     pushes = pushes + use_push.astype(jnp.int32)
                 else:
-                    red_t, w_inc, res_w, gat_w = masked_branch(use[0])(
+                    red_t, w_inc, res_w, gat_w, t_inc = masked_branch(use[0])(
                         (state_d, active_i32))
                     pushes = pushes + (1 if use[0] == "push" else 0)
                 red = cross_shard({c: red_t[i]
@@ -1114,6 +1116,7 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 work = work + w_inc
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
+                tiles = tiles + t_inc
                 new_d = {}
                 for p in plans:
                     new_d.update(iterate.plan_merge(p, state_d, red,
@@ -1129,48 +1132,50 @@ def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                                               tiles_static, True)
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
+                tiles = tiles + jnp.sum(tiles_static)
                 red = cross_shard(red)
                 hp = {c: segment.psum_like(
                     "or", hp[c].astype(jnp.int32), ax).astype(bool)
                     for c in hp}
-                red = iterate._apply_epilogue(comps, red)
+                red = iterate._apply_epilogue(comps, red, n)
                 new_d = iterate._recompute_merge(plans, comps_by_idx,
                                                  state_d, red, hp)
                 pushes = pushes + (1 if d == "push" else 0)
             new = tuple(new_d[cr.idx] for cr in comps)
-            ch = iterate._changed(comps, new, state, tol)
+            ch = iterate._changed(comps, new, state, tol, n)
             # divergence sentinel on the REPLICATED post-combine state: every
             # shard computes the identical flag, so draining the frontier
             # through it stays collective-safe.
             div = div | iterate._divergence(comps, new)
-            resid = iterate._residual(comps, new, state)
+            resid = iterate._residual(comps, new, state, n)
             ch = ch & ~div
             return (new, ch, k + 1, work, pushes, res_work, gather_work,
-                    div, resid)
+                    tiles, div, resid)
 
         def cond(carry):
-            _, active, k, _, _, _, _, _, _ = carry
+            active, k = carry[1], carry[2]
             return jnp.any(active) & (k < max_iter)
 
         state0 = _padded_init_state(comps, n, n_pad, srcs)
-        (state, active, k, work, pushes, res_work, gather_work, div,
+        (state, active, k, work, pushes, res_work, gather_work, tiles, div,
          resid) = jax.lax.while_loop(
             cond, body, (state0, jnp.ones(n_pad, bool), jnp.int32(0),
                          jnp.float32(0), jnp.int32(0), jnp.float32(0),
-                         jnp.float32(0), jnp.asarray(False),
+                         jnp.float32(0), jnp.int32(0), jnp.asarray(False),
                          jnp.float32(0)))
         # k/pushes/div/resid/active_n are replicated (k and pushes asserted
-        # host-side); work/res_work/gather_work are per-shard.
+        # host-side); work/res_work/gather_work/tiles are per-shard.
         active_n = jnp.sum(active[:n].astype(jnp.int32))
         return (state, k[None], work[None], pushes[None], res_work[None],
-                gather_work[None], div[None], resid[None], active_n[None])
+                gather_work[None], tiles[None], div[None], resid[None],
+                active_n[None])
 
     pspec = P(ax)
     in_specs = tuple([pspec] * ((_ELL_ARGS + 1) * len(use))
                      + ([pspec] * 4 if sorted_res else [])
                      + [P(), P(), P()])
     out_specs = (tuple(P() for _ in comps), P(ax), P(ax), P(ax), P(ax),
-                 P(ax), P(ax), P(ax), P(ax))
+                 P(ax), P(ax), P(ax), P(ax), P(ax))
     # check_vma off: the checker cannot see through pallas_call —
     # replication of state/k/pushes is an engine-level contract asserted on
     # the host instead.
@@ -1285,8 +1290,8 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     run, args, k_shards, entries = _sharded_executor(
         g, comps, plans, mesh, axes, strategy, max_iter, tol, block_v,
         block_e, interpret, use, dense_threshold, switch_k, push_resolution)
-    state, k, work, pushes, res_work, gather_work, div, resid, act_n = run(
-        *args, _srcs_vector(comps, sources))
+    (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
+     act_n) = run(*args, _srcs_vector(comps, sources))
     k_host = np.asarray(k)
     work_host = np.asarray(work)
     push_host = np.asarray(pushes)
@@ -1315,6 +1320,8 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     res.resolve_work = float(np.asarray(res_work).sum())
     res.gather_work = float(np.asarray(gather_work).sum())
     res.activity_reads = entries * p_i
+    res.tiles_swept = int(np.asarray(tiles).sum())
+    res.tile_shape = (block_v, block_e)
     res.shards = k_shards
     res.shard_work = tuple(float(w) for w in work_host)
     res.shard_launches = len(use)        # traced sweeps per shard per round

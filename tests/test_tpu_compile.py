@@ -190,3 +190,33 @@ def test_bfs_fixpoint_compiles(spec, graph):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
+
+
+def test_pr_fixpoint_compiles(spec):
+    """The whole PageRank fixpoint at the GAP kron-15 shapes: the pull−
+    recompute of every live tile (the float ``sum`` sweep with the has-pred
+    probe), the epilogue and the L1 stopping rule in the loop condition —
+    one pull layout, one kernel, within the chip's HBM."""
+    from repro.core import engine, fusion, synthesis, usecases
+    from repro.kernels import ops
+    n, width, arcs, _classes = GAP["gap-kron-15"]
+    round_ = fusion.fuse(usecases.pagerank()).rounds[0][1]
+    comps, plans = engine._round_runtime(round_,
+                                         synthesis.synthesize_round(round_))
+    use, dense, switch_k, resolution = ops._apply_plan(
+        None, "auto", ops.DENSE_FRONTIER, "auto", "sorted", False)
+    assert use == ("pull",)
+    run = ops._build_pallas_executor(
+        comps, plans, n, 2 * n + 4, 0.0, er.BLOCK_V, er.BLOCK_E, False, use,
+        dense, switch_k, resolution)
+    tiles = spec((n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32)
+    layout = [*_layout(spec, n, width)[:4], tiles, *_slots(spec, arcs)]
+    compiled = run.lower(*layout, spec((n,), jnp.int32),
+                         spec((n,), jnp.float32),
+                         spec((len(comps),), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    ma = compiled.memory_analysis()
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
