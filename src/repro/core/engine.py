@@ -136,6 +136,11 @@ class ExecStats:
                                     # pull/push sweep kernels ran, summed
                                     # over iterations (pallas engines;
                                     # summed over shards when sharded)
+    grid_steps: int = 0             # grid steps the pull, push and resolve
+                                    # kernels launched, summed over
+                                    # launches: the live-tile list's length
+                                    # or the full grid's (pallas engines;
+                                    # summed over shards when sharded)
     tile_shape: tuple = ()          # (block_v, block_e) of those tiles
     exit_change: float = 0.0        # the last iteration's change in the
                                     # stopping norm of the last round: the
@@ -467,6 +472,9 @@ def _accumulate(stats: ExecStats, res) -> None:
     ts = getattr(res, "tiles_swept", 0)
     if isinstance(ts, int):
         stats.tiles_swept += ts
+    gs = getattr(res, "grid_steps", 0)
+    if isinstance(gs, int):
+        stats.grid_steps += gs
     stats.tile_shape = getattr(res, "tile_shape", stats.tile_shape)
     rs = getattr(res, "residual", 0.0)
     if isinstance(rs, (int, float)):
@@ -766,6 +774,7 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
             gat_ws = np.asarray(res.gather_work)
             acts = np.asarray(res.activity_reads)
             tiles = np.asarray(res.tiles_swept)
+            steps = np.asarray(res.grid_steps)
             resids = np.asarray(res.residual)
             convs = np.asarray(res.converged)
             for b in range(B):
@@ -779,6 +788,7 @@ def run_program_batch(g, prog: FusedProgram, sources: Sequence,
                 st.gather_work += float(gat_ws[b])
                 st.activity_reads += int(acts[b])
                 st.tiles_swept += int(tiles[b])
+                st.grid_steps += int(steps[b])
                 st.tile_shape = res.tile_shape
                 st.exit_change = float(resids[b])
                 st.converged = st.converged and bool(convs[b])
@@ -969,6 +979,7 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
             gat_ws = np.asarray(res.gather_work)
             acts = np.asarray(res.activity_reads)
             tiles = np.asarray(res.tiles_swept)
+            steps = np.asarray(res.grid_steps)
             resids = np.asarray(res.residual)
             outs = [ExecResult(
                 value=res.state[0][b], named={},
@@ -980,6 +991,7 @@ def run_direct(g, dk: DirectKernels, engine: Optional[str] = None,
                                 gather_work=float(gat_ws[b]),
                                 activity_reads=int(acts[b]),
                                 tiles_swept=int(tiles[b]),
+                                grid_steps=int(steps[b]),
                                 tile_shape=res.tile_shape,
                                 exit_change=float(resids[b]),
                                 engine_used="pallas", plan=plan))

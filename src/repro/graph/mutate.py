@@ -47,7 +47,7 @@ from repro.core.guard import GraphValidationError
 from repro.graph import structure
 from repro.graph.structure import (
     BlockedELL, Graph, _check_edge_arrays, _fill_order_slots, _padded_width,
-    from_edges, slot_list)
+    from_edges, live_tiles, slot_list)
 
 # Global patch/rebuild accounting (bench + tests; reset like SWEEP_STATS).
 MUTATION_STATS = {
@@ -149,7 +149,9 @@ def _patch_ell(ell: BlockedELL, row_old, k_old, keep,
         nbrs=jnp.asarray(nbrs), weight=jnp.asarray(ws),
         capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
         tile_nnz=jnp.asarray(tile_nnz), slot_pos=jnp.asarray(pos),
-        slot_nbr=jnp.asarray(nbr), direction=ell.direction)
+        slot_nbr=jnp.asarray(nbr),
+        live_tiles=jnp.asarray(live_tiles(tile_nnz, bv)),
+        direction=ell.direction)
     return patched, k_ins
 
 

@@ -330,7 +330,9 @@ class BlockedELL:
     ``tile_nnz[i, j]`` counts the real slots inside grid tile (i, j) for the
     layout's own (block_v, block_e); power-law degree distributions leave most
     tail column-tiles fully padded, and the fused sweeps skip those tiles
-    before doing any work (DESIGN.md §2).
+    before doing any work (DESIGN.md §2).  ``live_tiles`` lists the tiles
+    with real slots (``live_tiles``): where that list is shorter than the
+    grid, the sweep kernels walk only it.
     """
     n: int                  # logical vertex count
     n_pad: int
@@ -344,6 +346,7 @@ class BlockedELL:
     tile_nnz: jnp.ndarray   # [n_pad/block_v, width/block_e] int32
     slot_pos: jnp.ndarray   # [E] int32 flat positions of the real slots
     slot_nbr: jnp.ndarray   # [E] int32 their neighbour ids (slot_list)
+    live_tiles: jnp.ndarray  # [L] int32 flat ids of the tiles a sweep walks
     direction: str = "in"   # "in" (rows = dst, pull) | "out" (rows = src, push)
 
     @property
@@ -394,6 +397,23 @@ def slot_list(idx, mask):
     pos = np.flatnonzero(np.asarray(mask))
     return (pos.astype(np.int32),
             np.asarray(idx).reshape(-1)[pos].astype(np.int32))
+
+
+def live_tiles(tile_nnz, block_v: int):
+    """The tiles a sweep kernel walks over a layout with ``tile_nnz``
+    [n_i, n_j] (DESIGN.md §2): the flat ids ``i·n_j + j`` of every tile with
+    real slots, ascending, plus the first tile of every 128-row output
+    group (``128 // block_v`` row tiles) that has none.  Each lane-dense
+    output block of the kernels is then visited, and identity-filled, by
+    one contiguous run of steps; a placeholder tile has no real slots, so
+    its activity bit is 0 in every bitmap the engines build."""
+    nnz = np.asarray(tile_nnz)
+    n_i, n_j = nnz.shape
+    group = (128 // block_v) * n_j
+    live = np.flatnonzero(nnz.reshape(-1) > 0)
+    empty = np.setdiff1d(np.arange(-(-n_i // (128 // block_v))),
+                         live // group)
+    return np.union1d(live, empty * group).astype(np.int32)
 
 
 def stack_slot_lists(lists, size: int):
@@ -452,7 +472,10 @@ def to_blocked_ell(g: Graph, block_v: int = 8, block_e: int = 128,
                           capacity=jnp.asarray(cs), mask=jnp.asarray(mask),
                           tile_nnz=jnp.asarray(tile_nnz),
                           slot_pos=jnp.asarray(pos),
-                          slot_nbr=jnp.asarray(nbr), direction=direction)
+                          slot_nbr=jnp.asarray(nbr),
+                          live_tiles=jnp.asarray(live_tiles(tile_nnz,
+                                                            block_v)),
+                          direction=direction)
 
 
 _ELL_CACHE: dict = {}
@@ -648,6 +671,7 @@ class PushResolution:
     valid: np.ndarray       # [n_pad, width] bool
     src_tile: np.ndarray    # [n_pad, width] int32 flat out-tile id
     tile_nnz: jnp.ndarray   # [n_pad/block_v, width/block_e] int32
+    live_tiles: jnp.ndarray  # [L] int32 the tiles the resolve kernel walks
     contrib: tuple          # ((tile_ids [t], lists [w, t]) int32, ...)
     slot_pos: jnp.ndarray   # [E] int32 flat positions of the valid slots
     slot_src: jnp.ndarray   # [E] int32 their in2out (slot_list)
@@ -761,6 +785,7 @@ def resolution_from_slots(n, src, dst, k_in, k_out, w_in, w_out,
             block_v=block_v, block_e=block_e, in2out=in2out, valid=valid,
             src_tile=src_tile,
             tile_nnz=jnp.asarray(tile_nnz),
+            live_tiles=jnp.asarray(live_tiles(tile_nnz, block_v)),
             contrib=tuple((jnp.asarray(ids), jnp.asarray(lists))
                           for ids, lists in classes),
             slot_pos=jnp.asarray(pos), slot_src=jnp.asarray(src_pos),

@@ -23,6 +23,9 @@ slots)`` tile in VMEM:
 
 The per-tile activity bitmap rides in SMEM as the scalar-prefetch operand
 of a ``PrefetchScalarGridSpec``; a tile whose bit is 0 skips its body.
+Where a layout's live-tile list is shorter than the full grid, it rides
+beside the bitmap and the grid walks only the tiles it names
+(``live_grid``, ``_tile_call``).
 Per-row vectors the push sweep reads (its own row's state, the frontier,
 the degrees) stream as ``(1, 1, 128)`` lane rows (``_lane_rows``).
 
@@ -66,7 +69,7 @@ layout, and the slot axis a multiple of 128.  ``block_v`` must divide 128
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence
+from typing import Callable, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -274,9 +277,11 @@ def _lane_rows(v):
     return jnp.pad(v, (0, n_g * LANES - n)).reshape(n_g, 1, LANES)
 
 
-def _lane_row_spec(block_v):
+def _lane_row_block(block_v):
+    """The ``(1, 1, 128)`` lane-row block of row tile i (``_tile_call``'s
+    (block shape, tile → block index) form)."""
     g = LANES // block_v
-    return pl.BlockSpec((1, 1, LANES), lambda i, j, *_: (i // g, 0, 0))
+    return (1, 1, LANES), lambda i, j: (i // g, 0, 0)
 
 
 def _rows_of_step(ref, i, block_v):
@@ -288,15 +293,17 @@ def _rows_of_step(ref, i, block_v):
     return _unbits(col, ref.dtype)
 
 
-def _lane_dense_spec(n_j, block_v):
+def _lane_dense_block(n_j, block_v):
+    """The resident ``(1, n_j, 128)`` lane-dense output block of row tile
+    i's 128-row group."""
     g = LANES // block_v
-    return pl.BlockSpec((1, n_j, LANES), lambda i, j, *_: (i // g, 0, 0))
+    return (1, n_j, LANES), lambda i, j: (i // g, 0, 0)
 
 
-def _fill_on_first_visit(out_refs, fills, i, j, block_v):
+def _fill_on_first_visit(out_refs, fills, step):
     """Identity-fill each lane-dense output block when the grid enters its
     128-row group: row tiles that skip keep ⊥ (= the identity, C6)."""
-    @pl.when((j == 0) & (i % (LANES // block_v) == 0))
+    @pl.when(step.first())
     def _fill():
         for ref, fill in zip(out_refs, fills):
             ref[...] = jnp.full(ref.shape, fill, ref.dtype)
@@ -334,24 +341,98 @@ def _pack_tile_bits(tile_act):
     return jax.lax.bitcast_convert_type(words, jnp.int32)
 
 
-def _tile_active(bits_ref, i, j, n_j):
-    """Scalar test of tile (i, j)'s bit in the SMEM bitmap."""
-    t = i * n_j + j
+def _tile_active(bits_ref, t):
+    """Scalar test of flat tile t's bit in the SMEM bitmap."""
     word = bits_ref[t // 32]
     return jax.lax.shift_right_logical(word, t % 32) & 1 != 0
 
 
-def _tile_call(kern, tile_act, args, in_specs, out_shapes, out_specs, *,
-               grid, interpret, name):
-    """``pallas_call`` named ``name`` over a (row tile, slot tile) grid with
-    the packed activity bitmap (``_pack_tile_bits``) as the SMEM
-    scalar-prefetch operand."""
+# Live-tile lists longer than this (int32 entries, 256 KiB) keep the full
+# grid: the list rides in SMEM (1 MiB on a v5e) beside the packed bitmap.
+LIVE_LIST_MAX = 1 << 16
+
+
+def live_grid(live, n_tiles: int):
+    """The grid a sweep over a layout of ``n_tiles`` tiles walks: its
+    live-tile list (``structure.live_tiles``) when that is shorter than the
+    full grid and fits ``LIVE_LIST_MAX``, else None — the full
+    (row tile, slot tile) grid."""
+    if live is None or live.shape[0] >= n_tiles \
+            or live.shape[0] > LIVE_LIST_MAX:
+        return None
+    return live
+
+
+def grid_steps(live, n_tiles: int) -> int:
+    """Grid steps of one sweep launch over ``live_grid(live, n_tiles)``."""
+    grid = live_grid(live, n_tiles)
+    return n_tiles if grid is None else int(grid.shape[0])
+
+
+class _Step(NamedTuple):
+    """The layout tile a grid step sweeps: row tile ``i``, slot tile ``j``,
+    and two scalars the kernel traces where it needs them: ``first()``,
+    the step enters its 128-row output group, and ``active()``, the tile's
+    bit in the SMEM bitmap."""
+    i: object
+    j: object
+    first: Callable
+    active: Callable
+
+
+def _tile_call(kern, tile_act, live, args, in_blocks, out_shapes, out_blocks,
+               *, block_v, interpret, name):
+    """``pallas_call`` named ``name`` of ``kern(step, *refs)`` (``_Step``)
+    over the tiles of an [n_i, n_j] layout, with the packed activity bitmap
+    (``_pack_tile_bits``) as the SMEM scalar-prefetch operand.  Blocks are
+    (block shape, (i, j) → block index) pairs.
+
+    ``live`` None walks the full (row tile, slot tile) grid.  A live-tile
+    list (``live_grid``) is a second scalar-prefetch operand and the grid
+    is 1-D over it: step s sweeps tile ``live[s] = i·n_j + j``.  The list
+    is ascending, so each 128-row group's steps run contiguously and its
+    lane-dense output block is filled on the group's first step, stays
+    resident and is written back once."""
+    n_i, n_j = tile_act.shape
+    g = LANES // block_v
+    prefetch = [_pack_tile_bits(tile_act)]
+    if live is None:
+        grid = (n_i, n_j)
+
+        def spec(block):
+            shape, at = block
+            return pl.BlockSpec(shape, lambda i, j, *_: at(i, j))
+
+        def body(bits_ref, *refs):
+            i = pl.program_id(0)
+            j = pl.program_id(1)
+            kern(_Step(i, j, lambda: (j == 0) & (i % g == 0),
+                       lambda: _tile_active(bits_ref, i * n_j + j)), *refs)
+    else:
+        grid = (live.shape[0],)
+        prefetch.append(live)
+
+        def spec(block):
+            shape, at = block
+            return pl.BlockSpec(
+                shape, lambda s, _bits, lv: at(lv[s] // n_j, lv[s] % n_j))
+
+        def body(bits_ref, live_ref, *refs):
+            s = pl.program_id(0)
+            t = live_ref[s]
+
+            def first():
+                prev = live_ref[jnp.maximum(s - 1, 0)]
+                return (s == 0) | (t // (g * n_j) != prev // (g * n_j))
+            kern(_Step(t // n_j, t % n_j, first,
+                       lambda: _tile_active(bits_ref, t)), *refs)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=grid, in_specs=in_specs,
-        out_specs=out_specs)
-    outs = pl.pallas_call(kern, grid_spec=grid_spec, out_shape=out_shapes,
-                          interpret=interpret, name=name)(
-        _pack_tile_bits(tile_act), *args)
+        num_scalar_prefetch=len(prefetch), grid=grid,
+        in_specs=[spec(b) for b in in_blocks],
+        out_specs=[spec(b) for b in out_blocks])
+    outs = pl.pallas_call(body, grid_spec=grid_spec, out_shape=out_shapes,
+                          interpret=interpret, name=name)(*prefetch, *args)
     return list(outs) if isinstance(outs, (tuple, list)) else [outs]
 
 
@@ -379,9 +460,8 @@ def _tile_lex_chain(props, plan_specs, idents, tie):
 # ---------------------------------------------------------------------------
 
 
-def _reduce_kernel(tile_act_ref, *refs, n_comps, env_names, has_act,
-                   plan_specs, hp_positions, p_fns, idents, nv, block_v,
-                   n_j):
+def _reduce_kernel(step, *refs, n_comps, env_names, has_act, plan_specs,
+                   hp_positions, p_fns, idents, nv, block_v):
     """One (BLOCK_V, BLOCK_E) tile of the pull sweep or of the sorted push
     resolution.
 
@@ -396,8 +476,7 @@ def _reduce_kernel(tile_act_ref, *refs, n_comps, env_names, has_act,
     Every (row, slot tile) candidate is written by exactly one grid step —
     no cross-step accumulation — so cross-tile lexicographic resolution can
     run outside the kernel on the [n_pad, n_tiles] candidates."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i, j = step.i, step.j
     n_env = len(env_names)
     val_refs = refs[:n_comps]
     env_refs = refs[n_comps:n_comps + n_env]
@@ -405,9 +484,9 @@ def _reduce_kernel(tile_act_ref, *refs, n_comps, env_names, has_act,
     out_refs = refs[n_comps + n_env + int(has_act):]
     fills = [idents[pos] for spec in plan_specs for pos, _op in spec] \
         + [0] * len(hp_positions)
-    _fill_on_first_visit(out_refs, fills, i, j, block_v)
+    _fill_on_first_visit(out_refs, fills, step)
 
-    @pl.when(_tile_active(tile_act_ref, i, j, n_j))
+    @pl.when(step.active())
     def _tile_body():
         vals = [r[...] for r in val_refs]
         if p_fns is None:
@@ -431,12 +510,18 @@ def _reduce_kernel(tile_act_ref, *refs, n_comps, env_names, has_act,
                               block_v)
 
 
-def _reduce_sweep(vals, env, act, tile_act, *, plan_specs, hp_positions,
-                  p_fns, idents, nv, block_v, block_e, interpret, name):
+def _tile_block(block_v, block_e):
+    """The (block_v, block_e) operand tile of layout tile (i, j)."""
+    return (block_v, block_e), lambda i, j: (i, j)
+
+
+def _reduce_sweep(vals, env, act, tile_act, live, *, plan_specs,
+                  hp_positions, p_fns, idents, nv, block_v, block_e,
+                  interpret, name):
     """Launch ``_reduce_kernel`` (as the kernel ``name``) over [n_pad, width]
     value operands ``vals`` (plus the named env operands and an optional
-    frontier mask) and return the per-level (then has-pred) [n_pad, n_tiles]
-    candidate arrays."""
+    frontier mask), on the grid ``live`` names (``_tile_call``), and return
+    the per-level (then has-pred) [n_pad, n_tiles] candidate arrays."""
     _check_block_v(block_v)
     n_pad, width = vals[0].shape
     n_j = width // block_e
@@ -444,7 +529,6 @@ def _reduce_sweep(vals, env, act, tile_act, *, plan_specs, hp_positions,
     args = list(vals) + [env[k] for k in env_names]
     if act is not None:
         args.append(act.astype(jnp.int32))
-    tile = pl.BlockSpec((block_v, block_e), lambda i, j, *_: (i, j))
     out_dtypes = [vals[pos].dtype for spec in plan_specs
                   for pos, _op in spec] + [jnp.int32] * len(hp_positions)
     n_g = -(-n_pad // LANES)
@@ -452,25 +536,28 @@ def _reduce_sweep(vals, env, act, tile_act, *, plan_specs, hp_positions,
         _reduce_kernel, n_comps=len(vals), env_names=env_names,
         has_act=act is not None, plan_specs=plan_specs,
         hp_positions=hp_positions, p_fns=p_fns, idents=idents, nv=nv,
-        block_v=block_v, n_j=n_j)
+        block_v=block_v)
     outs = _tile_call(
-        kern, tile_act, args, [tile] * len(args),
+        kern, tile_act, live, args, [_tile_block(block_v, block_e)] * len(args),
         [jax.ShapeDtypeStruct((n_g, n_j, LANES), dt) for dt in out_dtypes],
-        [_lane_dense_spec(n_j, block_v)] * len(out_dtypes),
-        grid=(n_pad // block_v, n_j), interpret=interpret, name=name)
+        [_lane_dense_block(n_j, block_v)] * len(out_dtypes),
+        block_v=block_v, interpret=interpret, name=name)
     return [_lane_dense_rows(o, n_pad) for o in outs]
 
 
 def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
                     outdeg, *, plans, idents, p_fns, nv,
                     need_haspred: bool = False, wdeg=None, slots=None,
-                    block_v: int = BLOCK_V, block_e: int = BLOCK_E,
+                    live=None, block_v: int = BLOCK_V, block_e: int = BLOCK_E,
                     interpret: Optional[bool] = None,
                     return_candidates: bool = False):
     """Single-launch fused edge sweep over every plan of a fused round.
 
     srcs/weight/capacity/mask   [n_pad, width] blocked-ELL arrays
     tile_act  [n_pad/block_v, width/block_e] int32 — 0 short-circuits a tile
+    live      the layout's live-tile grid (``live_grid``): the kernel walks
+              only those tiles; None walks the full grid.  Either way every
+              candidate comes out the same, bit for bit
     states    {comp: [n_pad] value vector}
     active    [n_pad] int32 frontier (1 = source eligible)
     outdeg    [n_pad] float32 (gathered per edge into the P environment)
@@ -520,7 +607,7 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
                 "wdeg": lambda: gather(wdeg, 0)}
     env = {k: get() for k, get in per_slot.items() if k in keys}
     outs = _reduce_sweep(
-        vals, env, act if need_haspred else None, tile_act,
+        vals, env, act if need_haspred else None, tile_act, live,
         plan_specs=plan_specs, hp_positions=hp_positions, p_fns=fns,
         idents=ident_scalars, nv=float(nv), block_v=block_v,
         block_e=block_e, interpret=interpret, name="grafs_pull_sweep")
@@ -609,8 +696,8 @@ def resolution_tile_activity(res_contrib, push_tile_act, res_tile_nnz):
 # ---------------------------------------------------------------------------
 
 
-def _push_kernel(tile_act_ref, mask_ref, *refs, n_comps, edge_names,
-                 row_names, p_fns, idents, nv, block_v, n_j):
+def _push_kernel(step, mask_ref, *refs, n_comps, edge_names, row_names,
+                 p_fns, idents, nv, block_v):
     """One (BLOCK_V sources × BLOCK_E successor slots) tile of the push sweep.
 
     ``refs`` = the edge tiles named by ``edge_names``, then lane-row blocks
@@ -624,8 +711,7 @@ def _push_kernel(tile_act_ref, mask_ref, *refs, n_comps, edge_names,
     frontier-inactive sources and padding slots to the reduction identity
     (C6) so the dst-keyed scatter outside absorbs them as no-ops.  Inactive
     tiles short-circuit via ``pl.when`` and emit identities bit-for-bit."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = step.i
     n_e, n_r = len(edge_names), len(row_names)
     edge_refs = refs[:n_e]
     active_ref = refs[n_e]
@@ -637,7 +723,7 @@ def _push_kernel(tile_act_ref, mask_ref, *refs, n_comps, edge_names,
         out_refs[k][...] = jnp.full(out_refs[k].shape, idents[k],
                                     out_refs[k].dtype)
 
-    @pl.when(_tile_active(tile_act_ref, i, j, n_j))
+    @pl.when(step.active())
     def _tile_body():
         shape = out_refs[0].shape
         mask = (mask_ref[...] != 0) & \
@@ -663,7 +749,7 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
                          active, outdeg, *, plans, idents, p_fns, nv,
                          need_haspred: bool = False, wdeg=None,
                          resolution: str = "scatter", res=None,
-                         res_slots=None,
+                         res_slots=None, live=None, res_live=None,
                          block_v: int = BLOCK_V, block_e: int = BLOCK_E,
                          interpret: Optional[bool] = None,
                          return_candidates: bool = False):
@@ -672,6 +758,8 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     dsts/weight/capacity/mask  [n_pad, width] out-edge blocked-ELL arrays
                                (``to_blocked_ell(..., direction="out")``:
                                rows are sources, slots hold destinations)
+    live / res_live  the live-tile grids (``live_grid``) of the out-layout
+              and of the resolution layout; None walks the full grid
     tile_act  [n_pad/block_v, width/block_e] int32 — 0 short-circuits a tile
     states    {comp: [n_pad] value vector}
     active    [n_pad] int32 frontier (1 = source eligible; push+ masks
@@ -704,6 +792,12 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     ``iterate.plan_merge`` resolves against the old state, so push and pull
     rounds share one merge contract bit-for-bit.
 
+    Over a live-tile grid the kernel never writes the candidate blocks of
+    dead tiles.  The sorted resolution reads candidates only at real slots,
+    which lie in live tiles; the scatter reference and
+    ``return_candidates`` read the whole rectangle, so for them the
+    padding slots are set to the identity after the launch.
+
     Returns ``(red, hp)`` like ``fused_ell_sweep``: ``red[comp]`` is the
     [n_pad] dst-keyed reduction of that level over the candidates, ``hp``
     the has-predecessor vectors of the push− models (from the non-⊥ source
@@ -725,7 +819,6 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     keys = env_keys_read(fns, [states[c].dtype for c in comps_order])
 
     n_pad, width = dsts.shape
-    n_j = width // block_e
 
     if wdeg is None:
         wdeg = jnp.ones_like(outdeg)
@@ -737,21 +830,24 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
         + [_lane_rows(jnp.asarray(active, jnp.int32))] \
         + [_lane_rows(states[c]) for c in comps_order] \
         + [_lane_rows(a) for a in rows.values()]
-    tile = pl.BlockSpec((block_v, block_e), lambda i, j, *_: (i, j))
-    in_specs = [tile] * (1 + len(edge)) \
-        + [_lane_row_spec(block_v)] * (1 + len(comps_order) + len(rows))
+    tile = _tile_block(block_v, block_e)
+    in_blocks = [tile] * (1 + len(edge)) \
+        + [_lane_row_block(block_v)] * (1 + len(comps_order) + len(rows))
     kern = functools.partial(
         _push_kernel, n_comps=len(comps_order), edge_names=tuple(edge),
         row_names=tuple(rows), p_fns=fns, idents=ident_scalars,
-        nv=float(nv), block_v=block_v, n_j=n_j)
+        nv=float(nv), block_v=block_v)
     outs = _tile_call(
-        kern, tile_act, args, in_specs,
+        kern, tile_act, live, args, in_blocks,
         [jax.ShapeDtypeStruct((n_pad, width), states[c].dtype)
          for c in comps_order],
-        [tile] * len(comps_order), grid=(n_pad // block_v, n_j),
-        interpret=interpret, name="grafs_push_sweep")
+        [tile] * len(comps_order), block_v=block_v, interpret=interpret,
+        name="grafs_push_sweep")
     SWEEP_STATS["launches"] += 1
     SWEEP_STATS["push_launches"] += 1
+    if live is not None and (resolution == "scatter" or return_candidates):
+        outs = [jnp.where(mask != 0, o, jnp.asarray(ident_scalars[k], o.dtype))
+                for k, o in enumerate(outs)]
 
     if resolution == "sorted":
         in2out, valid, res_tile_act = res
@@ -761,7 +857,7 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
                                        slots=res_slots, idx=in2out,
                                        mask=valid)
         red = _resolve_push_sorted(
-            outs, res_gather, res_tile_act, plans=plans,
+            outs, res_gather, res_tile_act, res_live, plans=plans,
             comps_order=comps_order, ident_scalars=ident_scalars,
             block_v=block_v, block_e=block_e, interpret=interpret)
     else:
@@ -807,8 +903,8 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     return red, hp
 
 
-def _resolve_push_sorted(cand_outs, res_gather, res_tile_act, *, plans,
-                         comps_order, ident_scalars, block_v, block_e,
+def _resolve_push_sorted(cand_outs, res_gather, res_tile_act, res_live, *,
+                         plans, comps_order, ident_scalars, block_v, block_e,
                          interpret):
     """Dst-sorted segment-reduction resolution (DESIGN.md §10).
 
@@ -826,9 +922,10 @@ def _resolve_push_sorted(cand_outs, res_gather, res_tile_act, *, plans,
     vals = [res_gather(c.reshape(-1), ident_scalars[k])
             for k, c in enumerate(cand_outs)]
     outs = _reduce_sweep(
-        vals, {}, None, res_tile_act, plan_specs=plan_specs, hp_positions=(),
-        p_fns=None, idents=ident_scalars, nv=0.0, block_v=block_v,
-        block_e=block_e, interpret=interpret, name="grafs_resolve")
+        vals, {}, None, res_tile_act, res_live, plan_specs=plan_specs,
+        hp_positions=(), p_fns=None, idents=ident_scalars, nv=0.0,
+        block_v=block_v, block_e=block_e, interpret=interpret,
+        name="grafs_resolve")
     SWEEP_STATS["resolve_launches"] += 1
     red, _ = _fold_tile_candidates(plans, plan_specs, ident_scalars, outs)
     return red

@@ -227,7 +227,9 @@ def _padded_init_state(comps, n, n_pad, srcs):
 
 
 # executor arguments per blocked-ELL direction: nbrs, weight, capacity, mask,
-# tile_nnz, slot_pos, slot_nbr (the sharded executor appends row_deg)
+# tile_nnz, slot_pos, slot_nbr, then one more: the single-device executor's
+# live-tile grid (``edge_reduce.live_grid``, None for the full grid), the
+# sharded executor's row_deg
 _ELL_ARGS = 7
 
 
@@ -236,12 +238,13 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                            push_resolution, batch=False, sentinel=True,
                            chunked=False, warm=False):
     """Trace + jit the whole fixpoint once.  The returned function takes the
-    blocked-ELL arrays (``_ELL_ARGS`` per direction in ``use``, pull first),
-    out-degrees (plain + weighted), the dst-sorted resolution arrays (when
-    the push direction resolves ``"sorted"``: slot_pos, slot_src, the
-    ``contrib`` class table as one tuple argument, tile_nnz — the table's
-    class count and shapes are the graph's, and jit retraces on them like
-    on any other shape), AND the per-component query sources as arguments
+    blocked-ELL arrays (``_ELL_ARGS`` + the live-tile grid per direction in
+    ``use``, pull first), out-degrees (plain + weighted), the dst-sorted
+    resolution arrays (when the push direction resolves ``"sorted"``:
+    slot_pos, slot_src, the ``contrib`` class table as one tuple argument,
+    tile_nnz, the live-tile grid — the table's class count and shapes and
+    the grid's length are the graph's, and jit retraces on them like on any
+    other shape), AND the per-component query sources as arguments
     (NOT closure constants): ``run(*arrays, srcs)``
     with ``srcs`` an [n_comps] int32 vector, so one compiled executor serves
     every graph with the same padded shapes and EVERY query source without
@@ -296,16 +299,16 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
 
     def _split(arrays):
         """(ELL dict, out_deg, wdeg, resolution arrays|None, rest)."""
-        ell = {d: arrays[_ELL_ARGS * i:_ELL_ARGS * (i + 1)]
-               for i, d in enumerate(use)}
-        idx = _ELL_ARGS * len(use)
+        per = _ELL_ARGS + 1
+        ell = {d: arrays[per * i:per * (i + 1)] for i, d in enumerate(use)}
+        idx = per * len(use)
         out_deg = arrays[idx]
         wdeg = arrays[idx + 1]
         idx += 2
         res = None
         if sorted_res:
-            res = arrays[idx:idx + 4]
-            idx += 4
+            res = arrays[idx:idx + 5]
+            idx += 5
         return ell, out_deg, wdeg, res, arrays[idx:]
 
     def _fixpoint(arrays, carry0, k_stop):
@@ -315,7 +318,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
         share."""
         ell, out_deg, wdeg, res_arrays, _ = _split(arrays)
         if sorted_res:
-            res_pos, res_src, res_contrib, res_nnz = res_arrays
+            res_pos, res_src, res_contrib, res_nnz, res_live = res_arrays
         n_pad = ell[use[0]][0].shape[0]
         out_deg_pad = jnp.zeros(n_pad, jnp.float32).at[:n].set(
             jnp.maximum(out_deg, 1).astype(jnp.float32))
@@ -337,11 +340,11 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             rectangle for sorted push (XLA gathers every slot of it before
             the resolution kernel), and rectangle/0 for the reference
             scatter (full-rectangle work, no permutation gather)."""
-            nbrs, weight, capacity, mask, _nnz, s_pos, s_nbr = ell[d]
+            nbrs, weight, capacity, mask, _nnz, s_pos, s_nbr, live = ell[d]
             states = {c: state_d[c] for c in comps_order}
             common = dict(plans=plan_levels, idents=idents, p_fns=p_fns,
                           nv=float(n), need_haspred=need_hp, wdeg=wdeg_pad,
-                          block_v=block_v, block_e=block_e,
+                          live=live, block_v=block_v, block_e=block_e,
                           interpret=interpret)
             if d == "pull":
                 red, hp = _er.fused_ell_sweep(
@@ -355,7 +358,8 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                     nbrs, weight, capacity, mask, tile_act, states,
                     active_i32, out_deg_pad, resolution="sorted",
                     res=(None, None, res_tile_act),
-                    res_slots=(res_pos, res_src), **common)
+                    res_slots=(res_pos, res_src), res_live=res_live,
+                    **common)
                 res_w = jnp.sum(res_nnz * res_tile_act).astype(jnp.float32)
                 return red, hp, res_w, jnp.sum(res_nnz).astype(jnp.float32)
             red, hp = _er.fused_ell_push_sweep(
@@ -369,7 +373,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
             work is the real slots inside the tiles actually processed."""
             def branch(args):
                 state_d, active_i32 = args
-                nbrs, _w, _c, mask, tile_nnz, s_pos, s_nbr = ell[d]
+                nbrs, _w, _c, mask, tile_nnz, s_pos, s_nbr, _live = ell[d]
                 if d == "pull":
                     tile_act = _er.tile_activity(nbrs, mask, tile_nnz,
                                                  active_i32, block_v, block_e,
@@ -502,7 +506,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
     if batch:
         # everything but srcs (ELL tuples, degrees, resolution arrays) is
         # shared across the batch
-        n_shared = _ELL_ARGS * len(use) + 2 + (4 if sorted_res else 0)
+        n_shared = (_ELL_ARGS + 1) * len(use) + 2 + (5 if sorted_res else 0)
         if warm:
             def run_warm(*all_args):
                 arrays = all_args[:n_shared + 1]      # shared + this row's srcs
@@ -542,8 +546,9 @@ def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
                      chunked=False, warm=False):
     """Cache lookup / build of the compiled fixpoint, the shared argument
     prefix (ELL arrays + degree vectors + dst-sorted resolution arrays) it
-    runs on, and the entries of the contributing-tile table one push round
-    reads (0 without the sorted resolution)."""
+    runs on, the entries of the contributing-tile table one push round
+    reads (0 without the sorted resolution), and the grid steps the sweep
+    kernels of one iteration launch, per direction in ``use``."""
     ells = {"pull": blocked_ell_cached(g, block_v=block_v, block_e=block_e,
                                        direction="in") if "pull" in use else None,
             "push": blocked_ell_cached(g, block_v=block_v, block_e=block_e,
@@ -568,17 +573,29 @@ def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
             comps, plans, g.n, max_iter, tol, block_v, block_e, interpret,
             use, dense_threshold, switch_k, push_resolution, batch=batch,
             sentinel=sentinel, chunked=chunked, warm=warm), comps)
-    args = []
+    args, steps = [], {}
     for d in use:
         e = ells[d]
         args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz,
-                 e.slot_pos, e.slot_nbr]
+                 e.slot_pos, e.slot_nbr,
+                 _er.live_grid(e.live_tiles, e.tile_nnz.size)]
+        steps[d] = _er.grid_steps(e.live_tiles, e.tile_nnz.size)
     args.append(g.out_deg)
     args.append(w_out_deg(g))
     if res is None:
-        return run, args, 0
-    args += [res.slot_pos, res.slot_src, res.contrib, res.tile_nnz]
-    return run, args, res.contrib_entries
+        return run, args, 0, steps
+    args += [res.slot_pos, res.slot_src, res.contrib, res.tile_nnz,
+             _er.live_grid(res.live_tiles, res.tile_nnz.size)]
+    steps["push"] += _er.grid_steps(res.live_tiles, res.tile_nnz.size)
+    return run, args, res.contrib_entries, steps
+
+
+def _grid_steps(steps, iters, pushes):
+    """Grid steps the sweep kernels of a fixpoint launched: ``steps`` per
+    pull and per push iteration (``_pallas_executor``) times how many of
+    each ran."""
+    return (steps.get("pull", 0) * (iters - pushes)
+            + steps.get("push", 0) * pushes)
 
 
 def _fixpoint_fingerprint(g, comps, plans, use, max_iter, tol, block_v,
@@ -735,7 +752,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
     chunk_mode = (checkpoint_every is not None or init_state is not None
                   or resume or fault_hook is not None)
     if not chunk_mode:
-        run, args, entries = _pallas_executor(
+        run, args, entries, steps = _pallas_executor(
             g, comps, plans, max_iter, tol, block_v, block_e, interpret, use,
             dense_threshold, switch_k, push_resolution,
             sentinel=divergence_sentinel)
@@ -747,7 +764,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
          act_n) = out
     else:
-        pair, args, entries = _pallas_executor(
+        pair, args, entries, steps = _pallas_executor(
             g, comps, plans, max_iter, tol, block_v, block_e, interpret, use,
             dense_threshold, switch_k, push_resolution,
             sentinel=divergence_sentinel, chunked=True)
@@ -808,6 +825,7 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
         res.gather_work = iterate._host(gather_work, float)
         res.activity_reads = entries * p_i
         res.tiles_swept = iterate._host(tiles, int)
+        res.grid_steps = _grid_steps(steps, k_i, p_i)
         res.tile_shape = (block_v, block_e)
     return res
 
@@ -872,7 +890,7 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
                 raise ValueError(
                     f"init_state for component {cr.idx} has shape "
                     f"{a.shape}, expected ({B}, {n})")
-    run, args, entries = _pallas_executor(
+    run, args, entries, steps = _pallas_executor(
         g, comps, plans, max_iter, tol, block_v, block_e, interpret, use,
         dense_threshold, switch_k, push_resolution, batch=True,
         warm=init_state is not None)
@@ -895,6 +913,8 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
     res.resolve_work = res_work           # [B] per-query resolution work
     res.gather_work = gather_work         # [B] per-query gather work
     res.tiles_swept = tiles               # [B] per-query live tiles swept
+    res.grid_steps = _grid_steps(         # [B] per-query kernel grid steps
+        steps, np.asarray(k, np.int64), np.asarray(pushes, np.int64))
     res.tile_shape = (block_v, block_e)
     # [B] contrib-table entries read (int64 on the host: a large table times
     # the push rounds passes int32)
@@ -1188,9 +1208,11 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
                       block_v, block_e, interpret, use, dense_threshold,
                       switch_k, push_resolution):
     """Cache lookup / build of the compiled sharded fixpoint, the stacked
-    argument prefix it runs on, the shard count, and the entries of the
+    argument prefix it runs on, the shard count, the entries of the
     stacked contributing-tile table one push round reads (0 without the
-    sorted resolution)."""
+    sorted resolution), and the grid steps the sweep kernels of one
+    iteration launch, per direction, summed over shards.  Every shard
+    walks its full grid: the shards' live-tile lists differ in length."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     ax = _axes_tuple(axes)
@@ -1215,11 +1237,12 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
             comps, plans, g.n, max_iter, tol, block_v, block_e, interpret,
             use, dense_threshold, switch_k, push_resolution, mesh, ax),
             comps)
-    args = []
+    args, steps = [], {}
     for d in use:
         e = ells[d]
         args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz,
                  e.slot_pos, e.slot_nbr, e.row_deg]
+        steps[d] = e.tile_nnz.size
     entries = 0
     if push_resolution == "sorted":
         sres = sharded_push_resolution_cached(
@@ -1227,9 +1250,10 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
             sharding=split)
         args += [sres.slot_pos, sres.slot_src, sres.contrib, sres.tile_nnz]
         entries = sres.contrib_entries
+        steps["push"] += sres.tile_nnz.size
     args.append(g.out_deg)
     args.append(w_out_deg(g))
-    return run, args, k_shards, entries
+    return run, args, k_shards, entries, steps
 
 
 def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
@@ -1287,7 +1311,7 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
             PUSH_RESOLUTION if push_resolution is None else push_resolution)
         if strategy not in ("contiguous", "dst_hash"):
             raise ValueError(f"unknown shard strategy {strategy!r}")
-    run, args, k_shards, entries = _sharded_executor(
+    run, args, k_shards, entries, steps = _sharded_executor(
         g, comps, plans, mesh, axes, strategy, max_iter, tol, block_v,
         block_e, interpret, use, dense_threshold, switch_k, push_resolution)
     (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
@@ -1321,6 +1345,7 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     res.gather_work = float(np.asarray(gather_work).sum())
     res.activity_reads = entries * p_i
     res.tiles_swept = int(np.asarray(tiles).sum())
+    res.grid_steps = _grid_steps(steps, k_i, p_i)
     res.tile_shape = (block_v, block_e)
     res.shards = k_shards
     res.shard_work = tuple(float(w) for w in work_host)

@@ -65,3 +65,22 @@ def small_graphs():
         "rmat": rmat_graph(16, 48, seed=5),
         "line": line_graph(8, weighted=True, seed=2),
     }
+
+
+def skewed_graph(seed: int = 3):
+    """A power-law graph on which most layout tiles are dead: R-MAT edges on
+    256 vertices, placed at ids 0-127 and 256-511 so that rows 128-255 (one
+    whole 128-row output group) hold no edge, plus vertex 0 joined both ways
+    to 200 others, so its rows span several 128-slot tiles."""
+    from repro.graph.structure import from_edges, rmat_graph
+    src, dst, w, c = rmat_graph(256, 1500, seed=seed).host_edges()
+    spread = lambda v: np.where(v < 128, v, v + 128)
+    rng = np.random.default_rng(seed)
+    leaves = spread(rng.choice(np.arange(1, 256), 200, replace=False))
+    hub = np.zeros_like(leaves)
+    ones = np.ones(2 * leaves.size, np.float32)
+    return from_edges(512,
+                      np.concatenate([spread(src), hub, leaves]).astype(np.int32),
+                      np.concatenate([spread(dst), leaves, hub]).astype(np.int32),
+                      np.concatenate([w, 1 + 7 * ones]),
+                      np.concatenate([c, ones]))

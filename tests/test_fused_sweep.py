@@ -12,7 +12,10 @@ Covers the acceptance contract of the fused execution layer:
 * frontier-skipped tiles (no active source) return identities bit-for-bit,
 * cross-tile lexicographic resolution on graphs whose padded width spans
   several slot tiles,
-* the compiled-executor cache reuses traced fixpoints across repeats.
+* the compiled-executor cache reuses traced fixpoints across repeats,
+* the live-tile grid: a sweep over a layout's live-tile list gives the
+  full grid's candidates bit for bit, the executor takes it only where it
+  is shorter than the grid, and ``ExecStats.grid_steps`` counts its steps.
 """
 import numpy as np
 import pytest
@@ -242,6 +245,177 @@ def test_slot_list_gather_matches_per_slot_gather(direction, need_haspred):
         for s in (None, slots)]
     for a, b in zip(jax.tree.leaves(outs[0]), jax.tree.leaves(outs[1])):
         assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+# ---------------------------------------------------------------------------
+# the live-tile grid: the kernels walk only the tiles with real slots
+# ---------------------------------------------------------------------------
+
+# kind → (plans, identity, P, has-pred); "pagerank" is the executor's
+# full-recompute round: a float sum of n / outdeg with the has-pred probe
+PULL_KINDS = {
+    "value": ((((0, "min"),),), float("inf"),
+              lambda env: env["n"] + env["w"], False),
+    "haspred": ((((0, "min"),),), float("inf"),
+                lambda env: env["n"] + env["w"], True),
+    "pagerank": ((((0, "sum"),),), 0.0,
+                 lambda env: env["n"] / env["outdeg"], True),
+}
+
+
+def _pull_sweep(ell, kind, bitmap, live, seed=4):
+    plans, ident, p_fn, need_hp = PULL_KINDS[kind]
+    rng = np.random.default_rng(seed)
+    state = jnp.asarray(rng.uniform(1, 9, ell.n_pad).astype(np.float32))
+    outdeg = jnp.asarray(rng.integers(1, 5, ell.n_pad).astype(np.float32))
+    active = jnp.asarray(
+        np.ones(ell.n_pad, np.int32) if kind == "pagerank" else
+        (rng.random(ell.n_pad) < (0 if bitmap == "none" else 0.3))
+        .astype(np.int32))
+    tile_act = {"all": lambda: jnp.ones_like(ell.tile_nnz),
+                "none": lambda: jnp.zeros_like(ell.tile_nnz)}.get(
+        bitmap, lambda: er.tile_activity(ell.nbrs, ell.mask, ell.tile_nnz,
+                                         active, ell.block_v, ell.block_e))()
+    return er.fused_ell_sweep(
+        ell.nbrs, ell.weight, ell.capacity, ell.mask, tile_act, {0: state},
+        active, outdeg, plans=plans, idents={0: ident}, p_fns={0: p_fn},
+        nv=ell.n, need_haspred=need_hp, slots=(ell.slot_pos, ell.slot_nbr),
+        live=live, return_candidates=True)
+
+
+@pytest.mark.parametrize("bitmap", ["frontier", "none", "all"])
+@pytest.mark.parametrize("kind", sorted(PULL_KINDS))
+def test_live_grid_pull_sweep_matches_full_grid(kind, bitmap):
+    """On a layout whose tiles are mostly dead, with a 128-row group that
+    has no live tile, the pull sweep over the live-tile list gives the full
+    grid's reductions, has-pred and per-tile candidates bit for bit:
+    dead tiles and empty groups keep the identities the first-visit fill
+    writes.  With an all-ones bitmap the empty groups' placeholder tiles run
+    on ⊥ operands; with no active source every candidate is the identity."""
+    from conftest import skewed_graph
+    ell = to_blocked_ell(skewed_graph())
+    live = er.live_grid(ell.live_tiles, ell.tile_nnz.size)
+    assert live is not None
+    got = _pull_sweep(ell, kind, bitmap, live)
+    want = _pull_sweep(ell, kind, bitmap, None)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    if bitmap == "none":
+        ident = np.float32(PULL_KINDS[kind][1])
+        assert np.all(np.asarray(got[2][0]) == ident)
+
+
+def _pallas_grids(fn, *args):
+    """The grid of every ``pallas_call`` in ``fn``'s jaxpr, nested ones
+    (the while_loop body, both direction branches) included."""
+    grids = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                grids.append(tuple(eqn.params["grid_mapping"].grid))
+                continue
+            for p in eqn.params.values():
+                for sub in p if isinstance(p, (tuple, list)) else (p,):
+                    if hasattr(sub, "eqns"):
+                        walk(sub)
+                    elif hasattr(getattr(sub, "jaxpr", None), "eqns"):
+                        walk(sub.jaxpr)
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return grids
+
+
+def _bfs_executor(g):
+    from repro.core import synthesis
+    from repro.kernels import ops
+    round_ = fusion.fuse(U.bfs(0)).rounds[0][1]
+    comps, plans = engine._round_runtime(round_,
+                                         synthesis.synthesize_round(round_))
+    use, dense, switch_k, resolution = ops._apply_plan(
+        None, "auto", ops.DENSE_FRONTIER, "auto", "sorted", True)
+    run, args, _entries, steps = ops._pallas_executor(
+        g, comps, plans, 2 * g.n + 4, 0.0, er.BLOCK_V, er.BLOCK_E, True, use,
+        dense, switch_k, resolution)
+    return run.fn, (*args, jnp.zeros((len(comps),), jnp.int32)), steps
+
+
+@pytest.mark.parametrize("graph", ["every-tile-live", "skewed"])
+def test_live_grid_selection_rule(graph):
+    """Where every tile is live (the ER shape) the executor passes no list
+    and its kernels keep the 2-D (row tile, slot tile) grid; on the skewed
+    layout each kernel's grid is 1-D over the list: the live tiles plus one
+    placeholder per 128-row group without any."""
+    from conftest import skewed_graph
+    from repro.graph.structure import to_push_resolution
+    g = uniform_graph(64, 640, seed=1) if graph == "every-tile-live" \
+        else skewed_graph()
+    ell = to_blocked_ell(g)
+    nnz = np.asarray(ell.tile_nnz)
+    n_i, n_j = nnz.shape
+    groups = -(-n_i // (er.LANES // ell.block_v))
+    has_live = np.unique(np.flatnonzero(nnz.reshape(-1))
+                         // (n_j * er.LANES // ell.block_v))
+    assert ell.live_tiles.shape[0] == \
+        int((nnz > 0).sum()) + groups - has_live.size
+    assert np.all(np.diff(np.asarray(ell.live_tiles)) > 0)
+    live = er.live_grid(ell.live_tiles, nnz.size)
+    fn, args, steps = _bfs_executor(g)
+    grids = _pallas_grids(fn, *args)
+    assert len(grids) == 3                      # pull, push, resolve
+    if graph == "every-tile-live":
+        assert (nnz > 0).all() and live is None
+        assert set(grids) == {(n_i, n_j)}
+        assert steps == {"pull": nnz.size, "push": 2 * nnz.size}
+    else:
+        assert live is not None and live.shape[0] == 36 < nnz.size
+        res = to_push_resolution(g)
+        assert set(grids) == {(36,), (res.live_tiles.shape[0],)}
+        assert steps == {"pull": 36, "push": 36 + res.live_tiles.shape[0]}
+
+
+@pytest.mark.parametrize("model", [None, "pull", "push"])
+@pytest.mark.parametrize("graph", ["every-tile-live", "skewed"])
+def test_grid_steps_counter(graph, model):
+    """``ExecStats.grid_steps`` is the launches times the grid length: the
+    live-tile list's on the skewed graph, n_i·n_j on the all-live one, with
+    the push sweep's resolve kernel counted beside it."""
+    from conftest import skewed_graph
+    from repro.graph.structure import to_push_resolution
+    g = uniform_graph(64, 640, seed=1) if graph == "every-tile-live" \
+        else skewed_graph()
+    res = engine.run_program(g, fusion.fuse(U.bfs(0)), engine="pallas",
+                             model=model, fallback=False)
+    st = res.stats
+    steps = [er.grid_steps(lay.live_tiles, lay.tile_nnz.size)
+             for lay in (to_blocked_ell(g), to_blocked_ell(g, direction="out"),
+                         to_push_resolution(g))]
+    grid = {"pull": steps[0], "push": steps[1] + steps[2]}
+    assert st.grid_steps == (st.pull_iters * grid["pull"]
+                             + st.push_iters * grid["push"]) > 0
+    n_tiles = to_blocked_ell(g).tile_nnz.size
+    if graph == "every-tile-live":
+        assert grid == {"pull": n_tiles, "push": 2 * n_tiles}
+    else:
+        assert grid["pull"] / n_tiles < 0.2
+
+
+def test_batched_executor_live_grid(monkeypatch):
+    """The vmapped batch executor over the live-tile lists answers like the
+    sequential runs and like the batch over the full grids, bit for bit,
+    with the same grid steps per query as the sequential runs."""
+    from conftest import skewed_graph
+    g = skewed_graph()
+    prog = fusion.fuse(U.sssp(0))
+    sources = [0, 5, 300]
+    got = engine.run_program_batch(g, prog, sources, engine="pallas")
+    seq = [engine.run_program(g, fusion.fuse(U.sssp(s)), engine="pallas",
+                              fallback=False) for s in sources]
+    monkeypatch.setattr(er, "live_grid", lambda live, n_tiles: None)
+    full = engine.run_program_batch(g, prog, sources, engine="pallas")
+    for a, s, f in zip(got, seq, full):
+        assert np.asarray(a.value).tobytes() == np.asarray(s.value).tobytes()
+        assert np.asarray(a.value).tobytes() == np.asarray(f.value).tobytes()
+        assert a.stats.grid_steps == s.stats.grid_steps < f.stats.grid_steps
 
 
 def test_tile_nnz_marks_padding_tiles():

@@ -163,6 +163,33 @@ def test_push_sweep_skipped_row_tiles_emit_identities():
     assert np.all(np.asarray(red[0]) == np.float32(ident))
 
 
+@pytest.mark.parametrize("name,model,resolution", [
+    ("BFS", None, "sorted"), ("SSSP", None, "sorted"),
+    ("SSSP", "push", "scatter"), ("NSP", "push", "sorted"),
+    ("PR", None, "sorted")])
+def test_live_grid_fixpoint_matches_full_grid(name, model, resolution,
+                                              monkeypatch):
+    """Whole fixpoints through ``run_program`` on a layout whose tiles are
+    mostly dead, with 128-row groups that have no live tile: over the
+    layouts' live-tile lists and over the full grids they answer bit for
+    bit alike, in as many iterations, in each direction the planner picks
+    (and the forced push− full recompute of NSP)."""
+    from conftest import skewed_graph
+    g = skewed_graph()
+    prog = fusion.fuse(U.pagerank() if name == "PR" else U.ALL_SPECS[name]())
+    kw = dict(engine="pallas", model=model, push_resolution=resolution,
+              fallback=False)
+    got = engine.run_program(g, prog, **kw)
+    engine.clear_program_caches()
+    monkeypatch.setattr(er, "live_grid", lambda live, n_tiles: None)
+    want = engine.run_program(g, prog, **kw)
+    assert np.asarray(got.value).tobytes() == \
+        np.asarray(want.value).tobytes()
+    assert got.stats.iterations == want.stats.iterations
+    assert got.stats.push_iters == want.stats.push_iters
+    assert 0 < got.stats.grid_steps < want.stats.grid_steps
+
+
 def test_direction_optimized_does_less_work_on_sparse_frontier():
     """The tentpole claim at engine level: on a power-law BFS the adaptive
     pallas engine's total edge work is ≤ the pull-only engine's, with at

@@ -25,6 +25,7 @@ Covers the acceptance contract of the frontier-proportional resolution path:
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from conftest import norm_inf
@@ -146,6 +147,46 @@ def test_sorted_resolution_matches_scatter_kernel_level(n, density, seed,
     want, w_scatter = _push_sweep(g, frontier, seed, "scatter")
     np.testing.assert_array_equal(got, want)
     assert w_sorted <= w_scatter
+
+
+def _push_sweep_grids(ell, res, frontier, resolution, live, res_live):
+    rng = np.random.default_rng(6)
+    state = jnp.asarray(rng.integers(1, 9, ell.n_pad).astype(np.float32))
+    ident = float(segment.identity("min", jnp.float32))
+    active = jnp.asarray((rng.random(ell.n_pad) < frontier).astype(np.int32))
+    tile_act = er.tile_activity_push(ell.tile_nnz, active, ell.block_v)
+    kw = dict(plans=(((0, "min"),),), idents={0: ident},
+              p_fns={0: lambda env: env["n"] + env["w"]}, nv=ell.n,
+              need_haspred=True, live=live, return_candidates=True)
+    if resolution == "sorted":
+        kw.update(res=(None, None, er.resolution_tile_activity(
+            res.contrib, tile_act, res.tile_nnz)),
+            res_slots=(res.slot_pos, res.slot_src), res_live=res_live)
+    return er.fused_ell_push_sweep(
+        ell.nbrs, ell.weight, ell.capacity, ell.mask, tile_act, {0: state},
+        active, jnp.ones(ell.n_pad, jnp.float32), resolution=resolution, **kw)
+
+
+@pytest.mark.parametrize("frontier", [0.0, 0.3, 1.0])
+@pytest.mark.parametrize("resolution", ["sorted", "scatter"])
+def test_live_grid_push_sweep_matches_full_grid(resolution, frontier):
+    """On a layout whose tiles are mostly dead, with 128-row groups that
+    have no live tile, the push sweep and the sorted resolution over their
+    live-tile lists give the full grids' reductions, has-pred and per-edge
+    candidates bit for bit: the candidates of dead tiles, which the list
+    never visits, read as identities wherever a consumer reads the whole
+    rectangle."""
+    from conftest import skewed_graph
+    g = skewed_graph()
+    ell = to_blocked_ell(g, direction="out")
+    res = to_push_resolution(g)
+    live = er.live_grid(ell.live_tiles, ell.tile_nnz.size)
+    res_live = er.live_grid(res.live_tiles, res.tile_nnz.size)
+    assert live is not None and res_live is not None
+    got = _push_sweep_grids(ell, res, frontier, resolution, live, res_live)
+    want = _push_sweep_grids(ell, res, frontier, resolution, None, None)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
 
 
 def test_sorted_resolution_work_frontier_proportional():

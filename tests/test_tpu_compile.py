@@ -6,7 +6,9 @@ vector gathers, SMEM or VMEM overflow — so every test here lowers a sweep
 with ``interpret=False`` for a *described* v5e and compiles it with the
 installed TPU compiler: ER-20 (2^20 rows, width 128 → one slot tile per row)
 and RMAT-15 (2^15 rows, width 31·128 → 31 slot tiles per row), plus the
-vmapped batch sweep of the serving path.  Each compiled program must hold
+vmapped batch sweep of the serving path.  At RMAT-15 the sweeps also
+compile over a live-tile list of the longest length ``live_grid`` passes,
+which must fit SMEM beside the bitmap.  Each compiled program must hold
 its Mosaic kernels (``tpu_custom_call``) and fit the chip's HBM.
 
 The topology is described inside a module fixture — never at import — so
@@ -48,9 +50,17 @@ def spec(topo):
                                                      sharding=one_chip)
 
 
-def _compile(fn, *args, kernels=1):
+def _compile(fn, *args, kernels=1, operand=None):
+    """Compile for the described v5e; the program holds ``kernels`` Mosaic
+    kernels, each of them taking ``operand`` (an HLO shape) when given, and
+    fits HBM."""
     compiled = jax.jit(fn).lower(*args).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= kernels
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) >= kernels
+    if operand is not None:
+        assert all(operand in line for line in calls), operand
     ma = compiled.memory_analysis()
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
@@ -147,16 +157,68 @@ def test_vmapped_batch_sweep_compiles(spec):
              _slots(spec, e))
 
 
+@pytest.mark.parametrize("sweep", ["pull", "pull-haspred", "push",
+                                   "push-sorted"])
+def test_live_list_sweeps_compile(spec, sweep):
+    """The pull, push and resolve kernels over a live-tile list at RMAT-15:
+    a 1-D grid over the longest list ``live_grid`` passes, which rides in
+    SMEM beside the packed bitmap as a second scalar-prefetch operand."""
+    n, width, e = WIDTHS["RMAT-15"]
+    n_tiles = (n // er.BLOCK_V) * (width // er.BLOCK_E)
+    assert er.LIVE_LIST_MAX < n_tiles
+    live = spec((er.LIVE_LIST_MAX,), jnp.int32)
+    assert er.live_grid(live, n_tiles) is live
+    tiles = spec((n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32)
+    kw = dict(plans=PLANS, idents=IDENTS, p_fns=P_FNS, nv=n, interpret=False)
+
+    def pull(nbrs, w, c, mask, tile_act, states, active, outdeg, slots,
+             live):
+        return er.fused_ell_sweep(
+            nbrs, w, c, mask, tile_act, states, active, outdeg,
+            need_haspred=sweep == "pull-haspred", slots=slots, live=live,
+            **kw)
+
+    def push(dsts, w, c, mask, tile_act, states, active, outdeg, slots,
+             live):
+        if sweep == "push":
+            return er.fused_ell_push_sweep(
+                dsts, w, c, mask, tile_act, states, active, outdeg,
+                resolution="scatter", live=live, **kw)[0]
+        return er.fused_ell_push_sweep(
+            dsts, w, c, mask, tile_act, states, active, outdeg,
+            resolution="sorted", res=(None, None, tile_act), res_slots=slots,
+            live=live, res_live=live, **kw)[0]
+
+    _compile(pull if sweep.startswith("pull") else push,
+             *_layout(spec, n, width)[:4], tiles, _states(spec, n),
+             spec((n,), jnp.int32), spec((n,), jnp.float32), _slots(spec, e),
+             live, kernels=2 if sweep == "push-sorted" else 1,
+             operand=f"s32[{er.LIVE_LIST_MAX}]")
+
+
 
 # The benchmark's GAP graphs: (vertices, padded width of both layouts, arcs,
 # (width, tiles) of each contrib class as structure.contrib_classes builds
-# them).
+# them, length of each layout's live-tile list as structure.live_tiles
+# builds it).
 GAP = {"gap-kron-15": (2 ** 15, 47 * 128, 882_416,
                        [(1, 3), (2, 2), (4, 6), (8, 44), (16, 220),
                         (32, 812), (64, 1364), (128, 3186), (256, 1736),
-                        (461, 309)]),
+                        (461, 309)], 7_682),
        "gap-urand-20": (2 ** 20, 128, 33_553_908,
-                        [(256, 68_665), (333, 62_407)])}
+                        [(256, 68_665), (333, 62_407)], 131_072)}
+
+
+def _gap_layout(spec, graph):
+    """(tile_nnz, the executor arguments of one layout, the live-tile grid
+    ``live_grid`` passes) at a GAP graph's shapes: a list at kron-15, the
+    full grid (None) at urand-20, where every tile is live."""
+    n, width, arcs, _classes, n_live = GAP[graph]
+    tiles = spec((n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32)
+    live = er.live_grid(spec((n_live,), jnp.int32),
+                        (n // er.BLOCK_V) * (width // er.BLOCK_E))
+    return tiles, [*_layout(spec, n, width)[:4], tiles, *_slots(spec, arcs),
+                   live], live
 
 
 @pytest.mark.parametrize("graph", sorted(GAP))
@@ -164,11 +226,12 @@ def test_bfs_fixpoint_compiles(spec, graph):
     """The whole BFS fixpoint — both sweeps, the sorted resolution and the
     contributing-tile activity over the compact class table — at the
     benchmark graphs' shapes, with its three kernels, within the chip's
-    HBM.  (With each class's lists stored as rows rather than columns the
-    urand-20 fixpoint took minutes to compile.)"""
+    HBM: over the live-tile lists at kron-15 and the full grids at
+    urand-20.  (With each class's lists stored as rows rather than columns
+    the urand-20 fixpoint took minutes to compile.)"""
     from repro.core import engine, fusion, synthesis, usecases
     from repro.kernels import ops
-    n, width, arcs, classes = GAP[graph]
+    n, width, arcs, classes, n_live = GAP[graph]
     round_ = fusion.fuse(usecases.bfs(0)).rounds[0][1]
     comps, plans = engine._round_runtime(round_,
                                          synthesis.synthesize_round(round_))
@@ -177,19 +240,14 @@ def test_bfs_fixpoint_compiles(spec, graph):
     run = ops._build_pallas_executor(
         comps, plans, n, 2 * n + 4, 0.0, er.BLOCK_V, er.BLOCK_E, False, use,
         dense, switch_k, resolution)
-    tiles = spec((n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32)
-    layout = [*_layout(spec, n, width)[:4], tiles, *_slots(spec, arcs)]
+    tiles, layout, live = _gap_layout(spec, graph)
+    assert (live is None) == (graph == "gap-urand-20")
     contrib = tuple((spec((r,), jnp.int32), spec((w, r), jnp.int32))
                     for w, r in classes)
-    compiled = run.lower(
-        *layout, *layout, spec((n,), jnp.int32), spec((n,), jnp.float32),
-        *_slots(spec, arcs), contrib, tiles,
-        spec((len(comps),), jnp.int32)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
-    ma = compiled.memory_analysis()
-    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
+    _compile(run, *layout, *layout, spec((n,), jnp.int32),
+             spec((n,), jnp.float32), *_slots(spec, arcs), contrib, tiles,
+             live, spec((len(comps),), jnp.int32), kernels=3,
+             operand=None if live is None else f"s32[{n_live}]")
 
 
 def test_pr_fixpoint_compiles(spec):
@@ -199,7 +257,7 @@ def test_pr_fixpoint_compiles(spec):
     one pull layout, one kernel, within the chip's HBM."""
     from repro.core import engine, fusion, synthesis, usecases
     from repro.kernels import ops
-    n, width, arcs, _classes = GAP["gap-kron-15"]
+    n, _width, _arcs, _classes, n_live = GAP["gap-kron-15"]
     round_ = fusion.fuse(usecases.pagerank()).rounds[0][1]
     comps, plans = engine._round_runtime(round_,
                                          synthesis.synthesize_round(round_))
@@ -209,14 +267,7 @@ def test_pr_fixpoint_compiles(spec):
     run = ops._build_pallas_executor(
         comps, plans, n, 2 * n + 4, 0.0, er.BLOCK_V, er.BLOCK_E, False, use,
         dense, switch_k, resolution)
-    tiles = spec((n // er.BLOCK_V, width // er.BLOCK_E), jnp.int32)
-    layout = [*_layout(spec, n, width)[:4], tiles, *_slots(spec, arcs)]
-    compiled = run.lower(*layout, spec((n,), jnp.int32),
-                         spec((n,), jnp.float32),
-                         spec((len(comps),), jnp.int32)).compile()
-    text = compiled.as_text()
-    assert text.count("tpu_custom_call") >= 1
-    ma = compiled.memory_analysis()
-    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
-            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
-    assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
+    _tiles, layout, live = _gap_layout(spec, "gap-kron-15")
+    assert live is not None
+    _compile(run, *layout, spec((n,), jnp.int32), spec((n,), jnp.float32),
+             spec((len(comps),), jnp.int32), operand=f"s32[{n_live}]")
