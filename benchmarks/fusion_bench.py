@@ -121,7 +121,7 @@ from benchmarks.common import BENCH_GRAPHS, emit, timed
 from repro.core import engine, fusion
 from repro.core import usecases as U
 from repro.core.iterate import plan_idempotent
-from repro.kernels.ops import _plan_levels
+from repro.core.plan import _plan_levels
 
 SIMPLE = ["WSP", "NWR", "RADIUS"]
 MULTI = ["DRR", "Trust", "RDS"]

@@ -31,13 +31,12 @@ convergence via the existing active mask (DESIGN.md §9).
 
 ``iterate_pallas_sharded`` composes this engine with the distributed
 vertex-cut model (DESIGN.md §11): every shard holds its own blocked-ELL
-pair (``structure.sharded_ell_cached``), runs the SAME fused sweeps
-shard-locally inside ``shard_map`` — including the dst-sorted push
-resolution over each shard's own ``PushResolution`` stack
-(``structure.sharded_push_resolution_cached``) — and merges per-vertex
-partials with monoid/lex collectives; the direction switch stays global
-via a psum'd frontier edge mass, so the sharded fixpoint walks the exact
-iteration sequence of the single-device one.
+pair (``structure.sharded_ell_cached``) and its own ``PushResolution``
+stack (``structure.sharded_push_resolution_cached``), and runs the SAME
+traced fixpoint body (``_fixpoint_program``) inside ``shard_map``, with
+per-vertex partials merged by monoid/lex collectives (``_Shards``); the
+direction switch reads the replicated out-degrees, so the sharded
+fixpoint walks the exact iteration sequence of the single-device one.
 
 The other wrappers expose the embedding-bag and ELL-softmax kernels behind
 plain jit'd functions that the models call.
@@ -68,16 +67,6 @@ embedding_bag = jax.jit(_eb.embedding_bag,
                                          "interpret"))
 ell_softmax = jax.jit(_ss.ell_softmax,
                       static_argnames=("block_v", "block_e", "interpret"))
-
-
-def _plan_levels(plan):
-    levels = []
-    p = plan
-    while isinstance(p, Lex):
-        levels.append((p.comp, p.op))
-        p = p.secondary
-    levels.append((p.comp, p.op))
-    return levels
 
 
 # ---------------------------------------------------------------------------
@@ -177,7 +166,8 @@ def _comps_key(comps):
 # ExecutionPlan whose fields are asserted (never re-parsed) below.
 from repro.core.plan import (DENSE_FRONTIER,            # noqa: E402
                              PUSH_RESOLUTION, SWITCH_K, _check_resolution,
-                             _normalize_switch_k, assert_normalized)
+                             _normalize_switch_k, _plan_levels,
+                             assert_normalized)
 
 
 def _apply_plan(plan, direction, dense_threshold, switch_k, push_resolution,
@@ -217,8 +207,7 @@ def _directions_used(direction: str, idempotent: bool):
 def _padded_init_state(comps, n, n_pad, srcs):
     """Initial per-component state padded to the layout rectangle, with the
     traced per-component sources applied (the executor-argument contract of
-    DESIGN.md §8).  Shared by the single-device and sharded builders so
-    their fixpoints can never diverge on the C1/C2 initial state."""
+    DESIGN.md §8)."""
     overrides = {cr.idx: srcs[i] for i, cr in enumerate(comps)
                  if cr.source is not None}
     base = iterate._init_state(comps, n, overrides)
@@ -226,69 +215,113 @@ def _padded_init_state(comps, n, n_pad, srcs):
                  for s, cr in zip(base, comps))
 
 
+def _pinned_knobs(use, dense_threshold, switch_k, push_resolution):
+    """Knobs a pinned executor never reads, normalized out of its cache key,
+    so e.g. model="pull" runs with different push_resolution values share
+    one compiled entry instead of retracing per knob."""
+    if len(use) != 2:                # pinned direction: no switch traced
+        dense_threshold = None
+        switch_k = None
+    if "push" not in use:            # no push sweep: no resolution traced
+        push_resolution = "unused"
+    return dense_threshold, switch_k, push_resolution
+
+
+class _OneChip:
+    """The steps of the fixpoint body that a mesh changes, on one chip:
+    each is the identity and emits no op."""
+
+    def total(self, x):
+        """The sum over shards of a shard-local scalar."""
+        return x
+
+    def combine(self, red: dict) -> dict:
+        """The per-vertex partial reductions combined across shards."""
+        return red
+
+    def combine_hp(self, hp: dict) -> dict:
+        """The has-pred flags ORed across shards."""
+        return hp
+
+
+class _Shards(_OneChip):
+    """The same steps across the shards of mesh axes ``ax`` under
+    ``shard_map``, with monoid collectives only: the combined reductions,
+    hence the merged state, are replicated on every shard."""
+
+    def __init__(self, ax, plans, comps_by_idx):
+        self.ax = ax
+        self.plans = plans
+        self.comps_by_idx = comps_by_idx
+
+    def total(self, x):
+        return jax.lax.psum(x, self.ax)
+
+    def combine(self, red: dict) -> dict:
+        out = dict(red)
+        for p in self.plans:
+            out.update(self._cross_plan(p, red))
+        return out
+
+    def _cross_plan(self, plan, red: dict) -> dict:
+        """Cross-shard lexicographic combine: the global primary via
+        psum/pmin/pmax, the local secondaries tie-masked to identity,
+        recursively (k× less traffic than an all_gather)."""
+        best = segment.psum_like(plan.op, red[plan.comp], self.ax)
+        out = {plan.comp: best}
+        if isinstance(plan, Lex):
+            tie = red[plan.comp] == best
+            masked = {j: jnp.where(tie, red[j], self.comps_by_idx[j].ident)
+                      for j in iterate._plan_comps(plan.secondary)}
+            out.update(self._cross_plan(plan.secondary, masked))
+        return out
+
+    def combine_hp(self, hp: dict) -> dict:
+        return {c: segment.psum_like("or", hp[c].astype(jnp.int32),
+                                     self.ax).astype(bool) for c in hp}
+
+
 # executor arguments per blocked-ELL direction: nbrs, weight, capacity, mask,
-# tile_nnz, slot_pos, slot_nbr, then one more: the single-device executor's
-# live-tile grid (``edge_reduce.live_grid``, None for the full grid), the
-# sharded executor's row_deg
+# tile_nnz, slot_pos, slot_nbr, then one more: the live-tile grid
+# (``edge_reduce.live_grid``, None for the full grid — always None under
+# shard_map, whose shards walk their full grids)
 _ELL_ARGS = 7
 
 
-def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
-                           interpret, use, dense_threshold, switch_k,
-                           push_resolution, batch=False, sentinel=True,
-                           chunked=False, warm=False):
-    """Trace + jit the whole fixpoint once.  The returned function takes the
-    blocked-ELL arrays (``_ELL_ARGS`` + the live-tile grid per direction in
-    ``use``, pull first), out-degrees (plain + weighted), the dst-sorted
-    resolution arrays (when the push direction resolves ``"sorted"``:
-    slot_pos, slot_src, the ``contrib`` class table as one tuple argument,
-    tile_nnz, the live-tile grid — the table's class count and shapes and
-    the grid's length are the graph's, and jit retraces on them like on any
-    other shape), AND the per-component query sources as arguments
-    (NOT closure constants): ``run(*arrays, srcs)``
-    with ``srcs`` an [n_comps] int32 vector, so one compiled executor serves
-    every graph with the same padded shapes and EVERY query source without
-    retracing.  It returns the full exit diagnostics
-    ``(state, k, work, pushes, res_work, gather_work, tiles, div, resid,
-    active_n)``, ``tiles`` the live tiles the main sweeps ran.
+def _fixpoint_program(comps, plans, n, tol, block_v, block_e, interpret, use,
+                      dense_threshold, switch_k, push_resolution,
+                      sentinel=True, mesh=_OneChip()):
+    """The traced fixpoint both Pallas executors run, as ``(init, fixpoint,
+    exit)`` over one argument tuple ``arrays``: the blocked-ELL arrays
+    (``_ELL_ARGS`` + the live-tile grid per direction in ``use``, pull
+    first), out-degrees (plain + weighted), the dst-sorted resolution arrays
+    (when the push direction resolves ``"sorted"``: slot_pos, slot_src, the
+    ``contrib`` class table as one tuple argument, tile_nnz, the live-tile
+    grid — the table's class count and shapes and the grid's length are the
+    graph's, and jit retraces on them like on any other shape), then the
+    per-component query sources ``srcs`` ([n_comps] int32, a traced
+    argument, so one compiled executor serves every source).
 
-    ``use`` = ("pull",) | ("push",) | ("pull", "push"); with both, each
-    iteration picks its sweep via ``lax.cond`` — both branches trace (two
-    pallas_calls appear in the HLO) but exactly one executes per iteration
-    at runtime.  The switch is the Gemini rule when ``switch_k`` is a
-    number (push while Σ out_deg over the frontier ≤ |E|/k) and the
-    legacy frontier-fraction threshold when ``switch_k`` is None.
+    ``init(arrays)`` builds the carry, ``fixpoint(arrays, carry, k_stop)``
+    runs the while_loop until quiescence or ``k == k_stop``, and
+    ``exit(carry)`` gives ``(state, k, work, pushes, res_work, gather_work,
+    tiles, div, resid, active_n)``, ``tiles`` the live tiles the main sweeps
+    ran.
 
-    With ``batch=True`` the same fixpoint is ``jax.vmap``ped over a leading
-    source axis (``srcs`` [B, n_comps]; the ELL arrays stay shared): state
-    and frontier grow a batch dimension, the while_loop's batching rule
-    keeps per-query convergence exact (converged queries stop updating via
-    the per-element carry select), and the direction lax.cond lowers to a
-    per-query select — bit-identical to the sequential runs (DESIGN.md §9).
+    With ``use`` = ("pull", "push") each idempotent iteration picks its
+    sweep via ``lax.cond`` — both branches trace, exactly one executes: the
+    Gemini rule when ``switch_k`` is a number (push while Σ out_deg over the
+    frontier ≤ |E|/k), the frontier-fraction threshold when it is None.
+    Non-idempotent rounds run the full recompute with the has-pred probe.
+    ``sentinel`` folds the NaN/Inf divergence sentinel and the
+    last-iteration residual into the carry (no extra launches); off, the
+    carry keeps constant placeholders so both variants share one signature.
 
-    ``sentinel`` folds the NaN/Inf divergence sentinel + last-iteration
-    residual into the loop carry (elementwise reductions, zero extra
-    launches); off, the carry keeps constant placeholders so both variants
-    share one signature.
-
-    With ``warm=True`` (batch only) the vmapped fixpoint additionally takes
-    one per-component ``[B, n]`` state block after ``srcs`` —
-    ``run(*arrays, srcs, *state0)`` — and overrides each batch element's
-    initial state rows with its own supplied block (padding rows keep the
-    reduction identity, the frontier resets to all-ones exactly like
-    ``_warm_start_carry``).  This is the continuous-batching join point
-    (DESIGN.md §13): unconverged queries resume from their last chunk's
-    state while fresh joiners ride in with their C1/C2 init rows, all in
-    the same launch.
-
-    With ``chunked=True`` the SAME traced body is exposed as a host-steppable
-    pair ``(init, step)``: ``init(*arrays, srcs)`` builds the initial carry,
-    ``step(*arrays, carry, k_stop)`` advances the while_loop until ``k ==
-    k_stop`` or quiescence.  The loop body is the identical jaxpr in both
-    variants and the carry crosses the host boundary as concrete buffers, so
-    a chunked run visits the exact iteration sequence of the monolithic one
-    and stays bitwise-identical (DESIGN.md §12) — which is what lets long
-    fixpoints snapshot through CheckpointManager and warm-resume."""
+    ``mesh`` holds what a mesh changes (``_OneChip``: nothing; ``_Shards``:
+    the cross-shard combines of the partial reductions and has-pred flags
+    and the global edge count).  The out-degrees are replicated, so every
+    shard computes the same frontier edge mass and takes the same
+    direction; the state is replicated, so the loop condition is too."""
     comps_by_idx = {cr.idx: cr for cr in comps}
     plan_levels = tuple(tuple(_plan_levels(p)) for p in plans)
     idempotent = all(iterate.plan_idempotent(p) for p in plans)
@@ -313,9 +346,8 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
 
     def _fixpoint(arrays, carry0, k_stop):
         """Run the while_loop from ``carry0`` until quiescence or ``k ==
-        k_stop`` — THE single traced body both the monolithic executor
-        (k_stop = max_iter, static) and the chunked stepper (k_stop traced)
-        share."""
+        k_stop`` — THE single traced body every executor (k_stop =
+        max_iter, static) and the chunked stepper (k_stop traced) share."""
         ell, out_deg, wdeg, res_arrays, _ = _split(arrays)
         if sorted_res:
             res_pos, res_src, res_contrib, res_nnz, res_live = res_arrays
@@ -330,6 +362,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
         wdeg_pad = jnp.ones(n_pad, jnp.float32).at[:n].set(
             wdeg.astype(jnp.float32))
         num_edges = jnp.sum(ell[use[0]][3].astype(jnp.float32))
+        all_edges = mesh.total(num_edges)   # the switch compares GLOBAL |E|
         ones_act = jnp.ones(n_pad, jnp.int32)
 
         def sweep(d, state_d, active_i32, tile_act, need_hp):
@@ -405,7 +438,7 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                             # out_deg_raw.
                             e_frontier = jnp.sum(active.astype(jnp.float32)
                                                  * out_deg_raw)
-                            use_push = e_frontier <= num_edges / switch_k
+                            use_push = e_frontier <= all_edges / switch_k
                         else:
                             # documented fallback: frontier VERTEX fraction
                             # over the logical vertex count (padding rows,
@@ -421,7 +454,8 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                     red_t, w_inc, res_w, gat_w, t_inc = masked_branch(use[0])(
                         (state_d, active_i32))
                     pushes = pushes + (1 if use[0] == "push" else 0)
-                red = {c: red_t[i] for i, c in enumerate(comps_order)}
+                red = mesh.combine({c: red_t[i]
+                                    for i, c in enumerate(comps_order)})
                 work = work + w_inc
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
@@ -433,7 +467,8 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                                                         comps_by_idx))
             else:
                 # full recompute (− models): has-pred probe in the same
-                # launch; only all-padding tiles skip.
+                # launch; only all-padding tiles skip.  The epilogue applies
+                # to the combined reduction.
                 d = use[0]
                 work = work + num_edges
                 tiles_static = (ell[d][4] > 0).astype(jnp.int32)
@@ -442,7 +477,8 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
                 tiles = tiles + jnp.sum(tiles_static)
-                red = iterate._apply_epilogue(comps, red, n)
+                red = iterate._apply_epilogue(comps, mesh.combine(red), n)
+                hp = mesh.combine_hp(hp)
                 with jax.named_scope("grafs.merge"):
                     new_d = iterate._recompute_merge(plans, comps_by_idx,
                                                      state_d, red, hp)
@@ -484,8 +520,30 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
         state, active, *counters = carry
         return (state, *counters, jnp.sum(active[:n].astype(jnp.int32)))
 
+    return _init, _fixpoint, _exit
+
+
+def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
+                           interpret, use, dense_threshold, switch_k,
+                           push_resolution, batch=False, sentinel=True,
+                           chunked=False, warm=False):
+    """Jit ``_fixpoint_program`` on one chip: ``run(*arrays, srcs)``.
+
+    ``batch=True`` vmaps it over a leading source axis (``srcs`` [B,
+    n_comps]; everything else shared): per-query convergence stays exact
+    and the direction cond becomes a per-query select — bit-identical to
+    the sequential runs (DESIGN.md §9).  ``warm=True`` (batch only) also
+    takes one per-component ``[B, n]`` state block after ``srcs``, the
+    continuous-batching join point (DESIGN.md §13).  ``chunked=True``
+    returns the host-steppable pair ``(init(*arrays, srcs), step(*arrays,
+    carry, k_stop))`` over the same body, bitwise the monolithic loop
+    (DESIGN.md §12)."""
+    init_carry, fixpoint, exit_ = _fixpoint_program(
+        comps, plans, n, tol, block_v, block_e, interpret, use,
+        dense_threshold, switch_k, push_resolution, sentinel)
+
     def run(*arrays):
-        return _exit(_fixpoint(arrays, _init(arrays), max_iter))
+        return exit_(fixpoint(arrays, init_carry(arrays), max_iter))
 
     if warm and not batch:
         raise ValueError("warm start rows are a batched-executor feature; "
@@ -497,24 +555,25 @@ def _build_pallas_executor(comps, plans, n, max_iter, tol, block_v, block_e,
 
         def step(*args_carry):
             *arrays, carry, k_stop = args_carry
-            return _fixpoint(tuple(arrays), carry, k_stop)
+            return fixpoint(tuple(arrays), carry, k_stop)
 
         def init(*arrays):
-            return _init(tuple(arrays))
+            return init_carry(tuple(arrays))
 
         return jax.jit(init), jax.jit(step)
     if batch:
         # everything but srcs (ELL tuples, degrees, resolution arrays) is
         # shared across the batch
+        sorted_res = push_resolution == "sorted" and "push" in use
         n_shared = (_ELL_ARGS + 1) * len(use) + 2 + (5 if sorted_res else 0)
         if warm:
             def run_warm(*all_args):
                 arrays = all_args[:n_shared + 1]      # shared + this row's srcs
                 state0 = all_args[n_shared + 1:]      # per-component [n] rows
-                st, *rest = _init(arrays)
+                st, *rest = init_carry(arrays)
                 st = tuple(ref.at[:n].set(s.astype(ref.dtype))
                            for ref, s in zip(st, state0))
-                return _exit(_fixpoint(arrays, (st, *rest), max_iter))
+                return exit_(fixpoint(arrays, (st, *rest), max_iter))
 
             return jax.jit(jax.vmap(
                 run_warm,
@@ -553,14 +612,8 @@ def _pallas_executor(g, comps, plans, max_iter, tol, block_v, block_e,
                                        direction="in") if "pull" in use else None,
             "push": blocked_ell_cached(g, block_v=block_v, block_e=block_e,
                                        direction="out") if "push" in use else None}
-    # Normalize knobs a pinned executor never reads out of its cache key,
-    # so e.g. model="pull" runs with different push_resolution values share
-    # one compiled entry instead of retracing per knob.
-    if len(use) != 2:                # pinned direction: no switch traced
-        dense_threshold = None
-        switch_k = None
-    if "push" not in use:            # no push sweep: no resolution traced
-        push_resolution = "unused"
+    dense_threshold, switch_k, push_resolution = _pinned_knobs(
+        use, dense_threshold, switch_k, push_resolution)
     res = push_resolution_cached(g, block_v=block_v, block_e=block_e) \
         if (push_resolution == "sorted" and "push" in use) else None
     key = (g.n, tuple(tuple(_plan_levels(p)) for p in plans),
@@ -596,6 +649,26 @@ def _grid_steps(steps, iters, pushes):
     each ran."""
     return (steps.get("pull", 0) * (iters - pushes)
             + steps.get("push", 0) * pushes)
+
+
+def _iteration_result(state, k, pushes, work, converged, div, act_n, resid,
+                      res_work, gather_work, tiles, reads, grid, tile_shape):
+    """The ``IterationResult`` of a Pallas executor, with the fields every
+    one of them adds: iterations per direction, resolution and gather work,
+    contributing-tile entries read, live tiles swept, kernel grid steps and
+    the tile shape."""
+    res = iterate.IterationResult(
+        state=state, iterations=k, edge_work=work, converged=converged,
+        diverged=div, active_count=act_n, residual=resid)
+    res.push_iters = pushes
+    res.pull_iters = k - pushes      # ints, tracers and [B] vectors alike
+    res.resolve_work = res_work
+    res.gather_work = gather_work
+    res.activity_reads = reads
+    res.tiles_swept = tiles
+    res.grid_steps = grid
+    res.tile_shape = tile_shape
+    return res
 
 
 def _fixpoint_fingerprint(g, comps, plans, use, max_iter, tol, block_v,
@@ -811,23 +884,13 @@ def iterate_pallas(g: Graph, comps, plans, max_iter: Optional[int] = None,
     with obs.span("grafs.stats_to_host"):
         k_i = iterate._host(k, int)
         p_i = iterate._host(pushes, int)
-        res = iterate.IterationResult(
-            state=tuple(s[:n] for s in state),
-            iterations=k_i,
-            edge_work=iterate._host(work, float),
-            converged=iterate._host(jnp.logical_and(~div, act_n == 0), bool),
-            diverged=iterate._host(div, bool),
-            active_count=iterate._host(act_n, int),
-            residual=iterate._host(resid, float))
-        res.push_iters = p_i
-        res.pull_iters = k_i - p_i        # valid for ints and tracers alike
-        res.resolve_work = iterate._host(res_work, float)
-        res.gather_work = iterate._host(gather_work, float)
-        res.activity_reads = entries * p_i
-        res.tiles_swept = iterate._host(tiles, int)
-        res.grid_steps = _grid_steps(steps, k_i, p_i)
-        res.tile_shape = (block_v, block_e)
-    return res
+        return _iteration_result(
+            tuple(s[:n] for s in state), k_i, p_i, iterate._host(work, float),
+            iterate._host(jnp.logical_and(~div, act_n == 0), bool),
+            iterate._host(div, bool), iterate._host(act_n, int),
+            iterate._host(resid, float), iterate._host(res_work, float),
+            iterate._host(gather_work, float), iterate._host(tiles, int),
+            entries * p_i, _grid_steps(steps, k_i, p_i), (block_v, block_e))
 
 
 def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
@@ -900,26 +963,15 @@ def iterate_pallas_batch(g: Graph, comps, plans, sources: Sequence,
     else:
         (state, k, work, pushes, res_work, gather_work, tiles, div, resid,
          act_n) = run(*args, srcs)
-    res = iterate.IterationResult(
-        state=tuple(s[:, :n] for s in state),
-        iterations=k,                     # [B] per-query iteration counts
-        edge_work=work,                   # [B] per-query edge work
-        converged=jnp.logical_and(~div, act_n == 0),   # [B]
-        diverged=div,                     # [B] per-query sentinel flags
-        active_count=act_n,               # [B]
-        residual=resid)                   # [B]
-    res.push_iters = pushes
-    res.pull_iters = k - pushes
-    res.resolve_work = res_work           # [B] per-query resolution work
-    res.gather_work = gather_work         # [B] per-query gather work
-    res.tiles_swept = tiles               # [B] per-query live tiles swept
-    res.grid_steps = _grid_steps(         # [B] per-query kernel grid steps
-        steps, np.asarray(k, np.int64), np.asarray(pushes, np.int64))
-    res.tile_shape = (block_v, block_e)
-    # [B] contrib-table entries read (int64 on the host: a large table times
-    # the push rounds passes int32)
-    res.activity_reads = entries * np.asarray(pushes, np.int64)
-    return res
+    # every count is a per-query [B] vector; the contrib-table reads and
+    # grid steps are int64 on the host (a large table times the push
+    # rounds passes int32)
+    k_h, p_h = np.asarray(k, np.int64), np.asarray(pushes, np.int64)
+    return _iteration_result(
+        tuple(s[:, :n] for s in state), k, pushes, work,
+        jnp.logical_and(~div, act_n == 0), div, act_n, resid, res_work,
+        gather_work, tiles, entries * p_h, _grid_steps(steps, k_h, p_h),
+        (block_v, block_e))
 
 
 # ---------------------------------------------------------------------------
@@ -945,12 +997,11 @@ def _mesh_cache_key(mesh, axes):
 
 
 def cross_combines_per_iter(plans, comps, idempotent: bool) -> int:
-    """Cross-shard state-combine collectives one ``pallas_sharded`` (or
-    ``distributed``) iteration executes: one monoid psum/pmin/pmax per lex
-    level of every plan, plus one OR-combine per component for the has-pred
-    probe of non-idempotent rounds.  (The direction-switch edge-mass psum
-    and the work accounting are control traffic, not state combines, and are
-    not counted.)"""
+    """Cross-shard state-combine collectives one ``pallas_sharded``
+    iteration executes: one monoid psum/pmin/pmax per lex level of every
+    plan, plus one OR-combine per component for the has-pred probe of
+    non-idempotent rounds.  (The global edge count and the work accounting
+    are control traffic, not state combines, and are not counted.)"""
     c = sum(len(_plan_levels(p)) for p in plans)
     if not idempotent:
         c += len(comps)
@@ -960,242 +1011,44 @@ def cross_combines_per_iter(plans, comps, idempotent: bool) -> int:
 def _build_sharded_executor(comps, plans, n, max_iter, tol, block_v, block_e,
                             interpret, use, dense_threshold, switch_k,
                             push_resolution, mesh, axes):
-    """Trace + jit the sharded fixpoint once per (plan structure, kernel set,
-    graph shape, direction set, resolution, mesh).  The returned function
-    takes one 8-tuple of STACKED ``[k, ...]`` sharded-ELL arrays per
-    direction in ``use`` (nbrs, weight, capacity, mask, tile_nnz, slot_pos,
-    slot_nbr, row_deg — split on the shard axis by ``shard_map``), then
-    (when the push direction resolves ``"sorted"``) the 4 stacked per-shard
-    resolution arguments of ``structure.ShardedPushResolution`` (slot_pos,
-    slot_src, the contrib class table, tile_nnz — also shard-split), the
-    replicated degree
-    vectors, and the
-    traced per-component query sources: ``run(*arrays, srcs)``.
-
-    Inside ``shard_map`` every shard runs the SAME fused Pallas sweeps as
-    the single-device engine over its own blocked-ELL pair — frontier-aware
-    tile skipping included — producing an identity-initialised per-vertex
-    partial reduction; partials merge across shards with the monoid/lex
-    ``cross_plan`` combine (primary via psum/pmin/pmax, tie-masked
-    secondaries, k× less traffic than an all_gather), and the replicated
-    merged state feeds ``plan_merge`` / ``_recompute_merge`` exactly like
-    the single-device fixpoint.  The per-iteration direction switch stays
-    GLOBAL: the frontier's outgoing edge mass is a psum of shard-local
-    out-layout row degrees, so every shard compares the same (integer-exact)
-    mass against |E|/k and picks the same sweep.  State is replicated, so
-    the convergence flag is identical on every shard and the while_loop is
-    collective-safe.  The push sweep resolves its dst-keyed reduction
-    shard-locally with the dst-sorted segment pass by default (each shard's
-    own ``PushResolution`` stack over its widened out-layout — the
-    permutation gather and the frontier-proportional tile skipping work
-    per shard exactly as on one device, and the cross-shard monoid/lex
-    combine contract is unchanged); ``"scatter"`` keeps the per-shard
-    reference scatter as the oracle (DESIGN.md §11)."""
+    """Jit ``_fixpoint_program`` under ``shard_map`` (DESIGN.md §11):
+    ``run(*arrays, srcs)`` takes the one-chip executor's arguments without
+    the live-tile grids, the layouts and resolution STACKED ``[k, ...]`` per
+    shard (``structure.ShardedELL`` / ``ShardedPushResolution``, split on
+    the shard axis), the degree vectors and ``srcs`` replicated.  Each shard
+    runs the fixpoint over its own blocks and full grids with the ``_Shards``
+    combines; the counters come back with a shard axis."""
     from jax.sharding import PartitionSpec as P
 
-
     ax = _axes_tuple(axes)
-    comps_by_idx = {cr.idx: cr for cr in comps}
-    plan_levels = tuple(tuple(_plan_levels(p)) for p in plans)
-    idempotent = all(iterate.plan_idempotent(p) for p in plans)
-    comps_order = _er.comps_in_plan_order(plan_levels)
-    idents = {c: comps_by_idx[c].ident for c in comps_order}
-    p_fns = {c: comps_by_idx[c].p_fn for c in comps_order}
     sorted_res = push_resolution == "sorted" and "push" in use
+    init_carry, fixpoint, exit_ = _fixpoint_program(
+        comps, plans, n, tol, block_v, block_e, interpret, use,
+        dense_threshold, switch_k, push_resolution,
+        mesh=_Shards(ax, plans, {cr.idx: cr for cr in comps}))
 
     def shard_fn(*arrays):
-        ell = {}
-        idx = 0
-        for d in use:
-            # [1, ...] → [...]
-            ell[d] = tuple(a[0] for a in arrays[idx:idx + _ELL_ARGS + 1])
-            idx += _ELL_ARGS + 1
+        def block(a):                                 # [1, ...] → [...]
+            return jax.tree.map(lambda x: x[0], a)
+        local = []
+        for i in range(len(use)):                     # no live-tile grid
+            local += [*map(block, arrays[_ELL_ARGS * i:_ELL_ARGS * (i + 1)]),
+                      None]
+        idx = _ELL_ARGS * len(use)
+        local += arrays[idx:idx + 2]                  # out_deg, wdeg
         if sorted_res:
-            res_pos, res_src, res_contrib, res_nnz = \
-                jax.tree.map(lambda a: a[0], tuple(arrays[idx:idx + 4]))
-            idx += 4
-        out_deg = arrays[idx]
-        wdeg = arrays[idx + 1]
-        srcs = arrays[idx + 2]
-        n_pad = ell[use[0]][0].shape[0]
-        out_deg_pad = jnp.zeros(n_pad, jnp.float32).at[:n].set(
-            jnp.maximum(out_deg, 1).astype(jnp.float32))
-        wdeg_pad = jnp.ones(n_pad, jnp.float32).at[:n].set(
-            wdeg.astype(jnp.float32))
-        # Shard-local real-edge count; the direction switch compares against
-        # the GLOBAL |E| via psum so every shard sees the same threshold.
-        local_edges = jnp.sum(ell[use[0]][3].astype(jnp.float32))
-        num_edges_g = jax.lax.psum(local_edges, ax)
-        ones_act = jnp.ones(n_pad, jnp.int32)
-
-        def cross_plan(plan, red: dict) -> dict:
-            """Cross-shard lexicographic combine with monoid collectives
-            only (the distributed engine's combiner over the pallas sweeps'
-            partials): global primary via psum/pmin/pmax, tie-mask the local
-            secondaries to identity, recurse.  Replicated across shards."""
-            best = segment.psum_like(plan.op, red[plan.comp], ax)
-            out = {plan.comp: best}
-            if isinstance(plan, Lex):
-                tie = red[plan.comp] == best
-                masked = {j: jnp.where(tie, red[j], comps_by_idx[j].ident)
-                          for j in iterate._plan_comps(plan.secondary)}
-                out.update(cross_plan(plan.secondary, masked))
-            return out
-
-        def cross_shard(red: dict) -> dict:
-            out = dict(red)
-            for p in plans:
-                out.update(cross_plan(p, red))
-            return out
-
-        def sweep(d, state_d, active_i32, tile_act, need_hp):
-            """One shard-local fused sweep: the SAME pallas kernels as the
-            single-device engine, over this shard's blocked-ELL slice.
-            Returns (red, hp, resolution edge work, gather work) exactly
-            like the single-device ``sweep`` — the sorted push resolve runs
-            shard-locally over this shard's own ``PushResolution`` slice."""
-            nbrs, weight, capacity, mask, _nnz, s_pos, s_nbr, _rdeg = ell[d]
-            states = {c: state_d[c] for c in comps_order}
-            common = dict(plans=plan_levels, idents=idents, p_fns=p_fns,
-                          nv=float(n), need_haspred=need_hp, wdeg=wdeg_pad,
-                          block_v=block_v, block_e=block_e,
-                          interpret=interpret)
-            if d == "pull":
-                red, hp = _er.fused_ell_sweep(
-                    nbrs, weight, capacity, mask, tile_act, states,
-                    active_i32, out_deg_pad, slots=(s_pos, s_nbr), **common)
-                return red, hp, jnp.float32(0), jnp.float32(0)
-            if sorted_res:
-                res_tile_act = _er.resolution_tile_activity(
-                    res_contrib, tile_act, res_nnz)
-                red, hp = _er.fused_ell_push_sweep(
-                    nbrs, weight, capacity, mask, tile_act, states,
-                    active_i32, out_deg_pad, resolution="sorted",
-                    res=(None, None, res_tile_act),
-                    res_slots=(res_pos, res_src), **common)
-                res_w = jnp.sum(res_nnz * res_tile_act).astype(jnp.float32)
-                return red, hp, res_w, jnp.sum(res_nnz).astype(jnp.float32)
-            red, hp = _er.fused_ell_push_sweep(
-                nbrs, weight, capacity, mask, tile_act, states,
-                active_i32, out_deg_pad, resolution="scatter", **common)
-            return (red, hp, jnp.float32(nbrs.shape[0] * nbrs.shape[1]),
-                    jnp.float32(0))
-
-        def masked_branch(d):
-            """One frontier-masked (+model) shard-local sweep; edge work is
-            the real slots inside the tiles THIS shard processed."""
-            def branch(args):
-                state_d, active_i32 = args
-                nbrs, _w, _c, mask, tile_nnz, s_pos, s_nbr, _rdeg = ell[d]
-                if d == "pull":
-                    tile_act = _er.tile_activity(nbrs, mask, tile_nnz,
-                                                 active_i32, block_v, block_e,
-                                                 slots=(s_pos, s_nbr))
-                else:
-                    tile_act = _er.tile_activity_push(tile_nnz, active_i32,
-                                                      block_v)
-                red, _, res_w, gat_w = sweep(d, state_d, active_i32, tile_act,
-                                             False)
-                w_inc = jnp.sum((tile_nnz * tile_act)).astype(jnp.float32)
-                return (tuple(red[c] for c in comps_order), w_inc, res_w,
-                        gat_w, jnp.sum(tile_act))
-            return branch
-
-        def body(carry):
-            (state, active, k, work, pushes, res_work, gather_work, tiles,
-             div, resid) = carry
-            state_d = {cr.idx: state[i] for i, cr in enumerate(comps)}
-            if idempotent:
-                active_i32 = active.astype(jnp.int32)
-                if len(use) == 2:
-                    if switch_k is not None:
-                        # Gemini rule, computed GLOBALLY: psum the frontier's
-                        # shard-local out-edge mass (out-layout row degrees —
-                        # padding rows carry 0) so every shard compares the
-                        # identical (integer-exact) edge mass and picks the
-                        # same direction as the single-device engine.
-                        local_mass = jnp.sum(active.astype(jnp.float32)
-                                             * ell["push"][-1])
-                        e_frontier = jax.lax.psum(local_mass, ax)
-                        use_push = e_frontier <= num_edges_g / switch_k
-                    else:
-                        # fallback frontier-fraction rule: the frontier is
-                        # replicated, so this is shard-invariant by itself.
-                        frac = jnp.sum(active.astype(jnp.float32)) / n
-                        use_push = frac <= dense_threshold
-                    red_t, w_inc, res_w, gat_w, t_inc = jax.lax.cond(
-                        use_push, masked_branch("push"), masked_branch("pull"),
-                        (state_d, active_i32))
-                    pushes = pushes + use_push.astype(jnp.int32)
-                else:
-                    red_t, w_inc, res_w, gat_w, t_inc = masked_branch(use[0])(
-                        (state_d, active_i32))
-                    pushes = pushes + (1 if use[0] == "push" else 0)
-                red = cross_shard({c: red_t[i]
-                                   for i, c in enumerate(comps_order)})
-                work = work + w_inc
-                res_work = res_work + res_w
-                gather_work = gather_work + gat_w
-                tiles = tiles + t_inc
-                new_d = {}
-                for p in plans:
-                    new_d.update(iterate.plan_merge(p, state_d, red,
-                                                    comps_by_idx))
-            else:
-                # full recompute (− models): every shard sweeps its real
-                # tiles, partial sums/extrema combine across shards, then
-                # the epilogue applies to the GLOBAL reduction.
-                d = use[0]
-                work = work + local_edges
-                tiles_static = (ell[d][4] > 0).astype(jnp.int32)
-                red, hp, res_w, gat_w = sweep(d, state_d, ones_act,
-                                              tiles_static, True)
-                res_work = res_work + res_w
-                gather_work = gather_work + gat_w
-                tiles = tiles + jnp.sum(tiles_static)
-                red = cross_shard(red)
-                hp = {c: segment.psum_like(
-                    "or", hp[c].astype(jnp.int32), ax).astype(bool)
-                    for c in hp}
-                red = iterate._apply_epilogue(comps, red, n)
-                new_d = iterate._recompute_merge(plans, comps_by_idx,
-                                                 state_d, red, hp)
-                pushes = pushes + (1 if d == "push" else 0)
-            new = tuple(new_d[cr.idx] for cr in comps)
-            ch = iterate._changed(comps, new, state, tol, n)
-            # divergence sentinel on the REPLICATED post-combine state: every
-            # shard computes the identical flag, so draining the frontier
-            # through it stays collective-safe.
-            div = div | iterate._divergence(comps, new)
-            resid = iterate._residual(comps, new, state, n)
-            ch = ch & ~div
-            return (new, ch, k + 1, work, pushes, res_work, gather_work,
-                    tiles, div, resid)
-
-        def cond(carry):
-            active, k = carry[1], carry[2]
-            return jnp.any(active) & (k < max_iter)
-
-        state0 = _padded_init_state(comps, n, n_pad, srcs)
-        (state, active, k, work, pushes, res_work, gather_work, tiles, div,
-         resid) = jax.lax.while_loop(
-            cond, body, (state0, jnp.ones(n_pad, bool), jnp.int32(0),
-                         jnp.float32(0), jnp.int32(0), jnp.float32(0),
-                         jnp.float32(0), jnp.int32(0), jnp.asarray(False),
-                         jnp.float32(0)))
+            local += [*map(block, arrays[idx + 2:idx + 6]), None]
+        local.append(arrays[-1])                      # srcs
+        local = tuple(local)
+        state, *counters = exit_(fixpoint(local, init_carry(local), max_iter))
         # k/pushes/div/resid/active_n are replicated (k and pushes asserted
         # host-side); work/res_work/gather_work/tiles are per-shard.
-        active_n = jnp.sum(active[:n].astype(jnp.int32))
-        return (state, k[None], work[None], pushes[None], res_work[None],
-                gather_work[None], tiles[None], div[None], resid[None],
-                active_n[None])
+        return (state, *(c[None] for c in counters))
 
-    pspec = P(ax)
-    in_specs = tuple([pspec] * ((_ELL_ARGS + 1) * len(use))
-                     + ([pspec] * 4 if sorted_res else [])
-                     + [P(), P(), P()])
-    out_specs = (tuple(P() for _ in comps), P(ax), P(ax), P(ax), P(ax),
-                 P(ax), P(ax), P(ax), P(ax), P(ax))
+    split, rep = P(ax), P()
+    in_specs = tuple([split] * (_ELL_ARGS * len(use)) + [rep, rep]
+                     + ([split] * 4 if sorted_res else []) + [rep])
+    out_specs = (tuple(rep for _ in comps),) + (split,) * 9
     # check_vma off: the checker cannot see through pallas_call —
     # replication of state/k/pushes is an engine-level contract asserted on
     # the host instead.
@@ -1222,11 +1075,8 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
         g, k_shards, strategy=strategy, block_v=block_v, block_e=block_e,
         direction={"pull": "in", "push": "out"}[d], sharding=split)
         for d in use}
-    if len(use) != 2:                # pinned direction: no switch traced
-        dense_threshold = None
-        switch_k = None
-    if "push" not in use:            # no push sweep: resolution never traced
-        push_resolution = "unused"
+    dense_threshold, switch_k, push_resolution = _pinned_knobs(
+        use, dense_threshold, switch_k, push_resolution)
     key = ("sharded", g.n, tuple(tuple(_plan_levels(p)) for p in plans),
            _comps_key(comps), max_iter, tol, block_v, block_e, interpret,
            use, dense_threshold, switch_k, push_resolution, strategy,
@@ -1241,8 +1091,10 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
     for d in use:
         e = ells[d]
         args += [e.nbrs, e.weight, e.capacity, e.mask, e.tile_nnz,
-                 e.slot_pos, e.slot_nbr, e.row_deg]
+                 e.slot_pos, e.slot_nbr]
         steps[d] = e.tile_nnz.size
+    args.append(g.out_deg)
+    args.append(w_out_deg(g))
     entries = 0
     if push_resolution == "sorted":
         sres = sharded_push_resolution_cached(
@@ -1251,8 +1103,6 @@ def _sharded_executor(g, comps, plans, mesh, axes, strategy, max_iter, tol,
         args += [sres.slot_pos, sres.slot_src, sres.contrib, sres.tile_nnz]
         entries = sres.contrib_entries
         steps["push"] += sres.tile_nnz.size
-    args.append(g.out_deg)
-    args.append(w_out_deg(g))
     return run, args, k_shards, entries, steps
 
 
@@ -1272,10 +1122,11 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     blocked-ELL pair, runs the existing pull/push sweeps locally (one
     ``pallas_call`` per shard per iteration — frontier-aware tile skipping
     included), and merges per-vertex partials across shards with the
-    monoid/lex ``cross_plan`` combine.  The per-iteration direction switch
-    is GLOBAL (psum'd frontier edge mass), so the sharded engine takes the
-    same push/pull sequence — and produces bitwise-identical states for
-    idempotent rounds — as the single-device ``iterate_pallas``.
+    monoid/lex combine of ``_Shards``.  The per-iteration direction switch
+    is GLOBAL (the frontier's edge mass over the replicated out-degrees), so
+    the sharded engine takes the same push/pull sequence — and produces
+    bitwise-identical states for idempotent rounds — as the single-device
+    ``iterate_pallas``.
 
     ``strategy`` picks the edge partitioning (``partition.partition_edges``:
     "contiguous" | "dst_hash").  ``push_resolution`` selects the shard-local
@@ -1297,20 +1148,13 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     interpret = _er.resolve_interpret(interpret)
     max_iter = max_iter if max_iter is not None else 2 * n + 4
     idempotent = all(iterate.plan_idempotent(p) for p in plans)
+    use, dense_threshold, switch_k, push_resolution = _apply_plan(
+        plan, direction, dense_threshold, switch_k, push_resolution,
+        idempotent)
     if plan is not None:
-        assert_normalized(plan)
-        push_resolution = plan.push_resolution
-        use = _directions_used(plan.direction, idempotent)
-        dense_threshold, switch_k = plan.dense_threshold, plan.switch_k
         strategy = plan.shard_strategy
-    else:
-        use = _directions_used(direction, idempotent)
-        switch_k = _normalize_switch_k(
-            switch_k, dense_threshold if len(use) == 2 else DENSE_FRONTIER)
-        push_resolution = _check_resolution(
-            PUSH_RESOLUTION if push_resolution is None else push_resolution)
-        if strategy not in ("contiguous", "dst_hash"):
-            raise ValueError(f"unknown shard strategy {strategy!r}")
+    elif strategy not in ("contiguous", "dst_hash"):
+        raise ValueError(f"unknown shard strategy {strategy!r}")
     run, args, k_shards, entries, steps = _sharded_executor(
         g, comps, plans, mesh, axes, strategy, max_iter, tol, block_v,
         block_e, interpret, use, dense_threshold, switch_k, push_resolution)
@@ -1331,22 +1175,12 @@ def iterate_pallas_sharded(g: Graph, comps, plans, mesh, axes=("data",),
     p_i = int(push_host[0])
     div_h = bool(np.asarray(div)[0])
     act_h = int(np.asarray(act_n)[0])
-    res = iterate.IterationResult(
-        state=tuple(s[:n] for s in state),
-        iterations=k_i,
-        edge_work=float(work_host.sum()),
-        converged=(not div_h) and act_h == 0,
-        diverged=div_h,
-        active_count=act_h,
-        residual=float(np.asarray(resid)[0]))
-    res.push_iters = p_i
-    res.pull_iters = k_i - p_i
-    res.resolve_work = float(np.asarray(res_work).sum())
-    res.gather_work = float(np.asarray(gather_work).sum())
-    res.activity_reads = entries * p_i
-    res.tiles_swept = int(np.asarray(tiles).sum())
-    res.grid_steps = _grid_steps(steps, k_i, p_i)
-    res.tile_shape = (block_v, block_e)
+    res = _iteration_result(
+        tuple(s[:n] for s in state), k_i, p_i, float(work_host.sum()),
+        (not div_h) and act_h == 0, div_h, act_h,
+        float(np.asarray(resid)[0]), float(np.asarray(res_work).sum()),
+        float(np.asarray(gather_work).sum()), int(np.asarray(tiles).sum()),
+        entries * p_i, _grid_steps(steps, k_i, p_i), (block_v, block_e))
     res.shards = k_shards
     res.shard_work = tuple(float(w) for w in work_host)
     res.shard_launches = len(use)        # traced sweeps per shard per round

@@ -5,7 +5,8 @@
 * one solo ``run_program`` BFS on a new graph emits the ``grafs.*`` host
   spans nested and in order, the layout builds inside the executor lookup,
   to a listener and into a profiler trace;
-* the optimized HLO of the ``pallas`` executor names every ``grafs.``
+* the optimized HLO of the ``pallas`` executor, and of the
+  ``pallas_sharded`` one on a one-device mesh, names every ``grafs.``
   device scope and Pallas kernel in its ``op_name`` metadata;
 * answers are bitwise equal with and without a listener registered.
 """
@@ -14,6 +15,7 @@ import re
 import jax
 import numpy as np
 import pytest
+from jax.sharding import Mesh
 
 from repro import obs
 from repro.core import engine, fusion
@@ -123,14 +125,19 @@ def test_spans_land_in_a_profiler_trace(tmp_path):
                      "grafs.finish", "grafs.finish"]
 
 
-@pytest.fixture(scope="module")
-def executor_op_names():
-    """The ``op_name`` metadata of the optimized HLO of the ``pallas``
-    executor (auto direction, sorted resolution) that one BFS ran."""
+@pytest.fixture(scope="module", params=["pallas", "pallas_sharded"])
+def executor_op_names(request):
+    """The ``op_name`` metadata of the optimized HLO of the executor (auto
+    direction, sorted resolution) that one BFS ran: the ``pallas`` one, and
+    the ``pallas_sharded`` one on a one-device mesh, which runs the same
+    fixpoint body."""
     kops.clear_executor_cache()
     g = structure.uniform_graph(96, 400, seed=7)
-    engine.run_program(g, fusion.fuse(U.bfs_depth(0)), engine="pallas",
-                       fallback=False)
+    mesh = None
+    if request.param == "pallas_sharded":
+        mesh = Mesh(np.asarray(jax.devices()[:1]), ("data",))
+    engine.run_program(g, fusion.fuse(U.bfs_depth(0)), engine=request.param,
+                       mesh=mesh, fallback=False)
     (text,) = kops.compiled_executor_texts()
     return re.findall(r'op_name="([^"]*)"', text)
 
