@@ -157,6 +157,12 @@ def plan_merge(plan, a: dict, b: dict, comps) -> dict:
     return out
 
 
+def recompute_reads_has_pred(plans, comps_by_idx) -> bool:
+    """Whether ``_recompute_merge`` reads ``has_pred``: only for a plan whose
+    component has no epilogue."""
+    return any(comps_by_idx[p.comp].e_fn is None for p in plans)
+
+
 def _recompute_merge(plans, comps_by_idx, state_d, red, has_pred) -> dict:
     """Update rule of the non-idempotent (−) models: the recomputed value
     wins unless the previous value is strictly better (protects the source's

@@ -109,8 +109,13 @@ _INT_OP = {"or": "max", "and": "min"}
 # sweeps, so the sweep-launch contract tests stay direction-symmetric.
 # What the executed iterations did (direction split, resolution and gather
 # work) is per query: ``IterationResult`` / ``ExecStats``.
+# "loop_slot_gathers" counts the per-slot operands a pull sweep gathers
+# itself (bumped with its launch: inside a fixpoint body, once per
+# iteration); "hoisted_slot_gathers" those a fixpoint gathers once per
+# call, before its loop, and hands to every sweep (``slot_env``).
 SWEEP_STATS = {"launches": 0, "pull_launches": 0, "push_launches": 0,
-               "resolve_launches": 0}
+               "resolve_launches": 0, "loop_slot_gathers": 0,
+               "hoisted_slot_gathers": 0}
 
 
 def reset_sweep_stats():
@@ -214,6 +219,19 @@ def env_keys_read(p_fns, dtypes) -> frozenset:
                        jax.ShapeDtypeStruct(tile, jnp.float32),
                        jax.ShapeDtypeStruct(tile, jnp.int32))
     return frozenset(read)
+
+
+def slot_env(keys, outdeg, wdeg, shape, slots=None, idx=None, mask=None):
+    """The ``[n_pad, width]`` rectangles of the per-source env entries among
+    ``keys`` (``env_keys_read``): ``outdeg`` and ``wdeg``, gathered through
+    the layout like ``gather_slots``.  They do not depend on the state, so a
+    fixpoint builds them once per call, before its loop, and hands them to
+    every pull sweep (``fused_ell_sweep(env_slots=...)``)."""
+    tables = {"outdeg": outdeg, "wdeg": wdeg}
+    env = {k: gather_slots(t, 0, shape, slots, idx, mask)
+           for k, t in tables.items() if k in keys}
+    SWEEP_STATS["hoisted_slot_gathers"] += len(env)
+    return env
 
 
 def _check_block_v(block_v: int) -> None:
@@ -548,7 +566,8 @@ def _reduce_sweep(vals, env, act, tile_act, live, *, plan_specs,
 def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
                     outdeg, *, plans, idents, p_fns, nv,
                     need_haspred: bool = False, wdeg=None, slots=None,
-                    live=None, block_v: int = BLOCK_V, block_e: int = BLOCK_E,
+                    env_slots=None, live=None, block_v: int = BLOCK_V,
+                    block_e: int = BLOCK_E,
                     interpret: Optional[bool] = None,
                     return_candidates: bool = False):
     """Single-launch fused edge sweep over every plan of a fused round.
@@ -559,11 +578,15 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
               only those tiles; None walks the full grid.  Either way every
               candidate comes out the same, bit for bit
     states    {comp: [n_pad] value vector}
-    active    [n_pad] int32 frontier (1 = source eligible)
+    active    [n_pad] int32 frontier (1 = source eligible); None: every
+              source is (a full recompute), so the has-pred probe's frontier
+              rectangle is ``mask`` itself and nothing is gathered for it
     outdeg    [n_pad] float32 (gathered per edge into the P environment)
     wdeg      [n_pad] float32 weighted out-degree (env "wdeg"; None → 1s)
     slots     the layout's (slot_pos, slot_nbr) list: gathers go through it
               (``gather_slots``); None gathers per slot
+    env_slots {env key: [n_pad, width]} per-slot env rectangles the caller
+              built already (``slot_env``): streamed as given, not gathered
     plans     static: per plan a tuple of (comp, op) lex levels, primary first
     idents    {comp: identity scalar};  p_fns {comp: propagation closure}
 
@@ -578,6 +601,7 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
     source is frontier-inactive, and only the env entries P reads
     (``env_keys_read``) are streamed.  A has-pred sweep masks the values by
     the real slots only and streams the frontier mask separately.
+    ``SWEEP_STATS["loop_slot_gathers"]`` counts the gathers made here.
     """
     interpret = resolve_interpret(interpret)
     comps_order = comps_in_plan_order(plans)
@@ -591,28 +615,41 @@ def fused_ell_sweep(srcs, weight, capacity, mask, tile_act, states, active,
 
     if wdeg is None:
         wdeg = jnp.ones_like(outdeg)
-    gather = functools.partial(gather_slots, shape=srcs.shape, slots=slots,
-                               idx=srcs, mask=mask)
-    active = jnp.asarray(active, jnp.int32)
-    act = gather(active, 0) != 0
-    # a value-only sweep masks frontier-inactive sources to ⊥ per vertex,
-    # before the gather; the has-pred sweep streams the frontier instead
-    vals = [gather(states[c] if need_haspred else jnp.where(
-                active != 0, states[c],
-                jnp.asarray(ident_scalars[k], states[c].dtype)),
-                ident_scalars[k])
+    env_slots = env_slots or {}
+    n_gathers = 0
+
+    def gather(table, fill):
+        nonlocal n_gathers
+        n_gathers += 1
+        return gather_slots(table, fill, srcs.shape, slots, srcs, mask)
+
+    # the has-pred sweep streams the frontier's rectangle; a value-only
+    # sweep masks frontier-inactive sources to ⊥ per vertex, before the
+    # gather
+    act = mask if need_haspred else None
+    if active is not None:
+        active = jnp.asarray(active, jnp.int32)
+        if need_haspred:
+            act = gather(active, 0) != 0
+        else:
+            states = {c: jnp.where(active != 0, states[c], jnp.asarray(
+                ident_scalars[k], states[c].dtype))
+                for k, c in enumerate(comps_order)}
+    vals = [gather(states[c], ident_scalars[k])
             for k, c in enumerate(comps_order)]
     per_slot = {"w": lambda: weight, "c": lambda: capacity,
                 "esrc": lambda: srcs, "outdeg": lambda: gather(outdeg, 0),
                 "wdeg": lambda: gather(wdeg, 0)}
-    env = {k: get() for k, get in per_slot.items() if k in keys}
+    env = {k: env_slots[k] if k in env_slots else get()
+           for k, get in per_slot.items() if k in keys}
     outs = _reduce_sweep(
-        vals, env, act if need_haspred else None, tile_act, live,
+        vals, env, act, tile_act, live,
         plan_specs=plan_specs, hp_positions=hp_positions, p_fns=fns,
         idents=ident_scalars, nv=float(nv), block_v=block_v,
         block_e=block_e, interpret=interpret, name="grafs_pull_sweep")
     SWEEP_STATS["launches"] += 1
     SWEEP_STATS["pull_launches"] += 1
+    SWEEP_STATS["loop_slot_gathers"] += n_gathers
 
     # Cross-tile lexicographic resolution (the "short second pass"): a fold
     # of the plan_merge recurrence over the tile axis, in plain jnp — zero
@@ -763,7 +800,7 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
     tile_act  [n_pad/block_v, width/block_e] int32 — 0 short-circuits a tile
     states    {comp: [n_pad] value vector}
     active    [n_pad] int32 frontier (1 = source eligible; push+ masks
-              inactive sources, push− passes all-ones)
+              inactive sources); None: every source is (push−)
     wdeg      [n_pad] float32 weighted out-degree (env "wdeg"; None → 1s)
     plans     static: per plan a tuple of (comp, op) lex levels, primary first
     idents    {comp: identity scalar};  p_fns {comp: propagation closure}
@@ -822,6 +859,8 @@ def fused_ell_push_sweep(dsts, weight, capacity, mask, tile_act, states,
 
     if wdeg is None:
         wdeg = jnp.ones_like(outdeg)
+    if active is None:
+        active = jnp.ones(n_pad, jnp.int32)
     edge = {k: a for k, a in (("w", weight), ("c", capacity), ("edst", dsts))
             if k in keys}
     rows = {k: a for k, a in (("outdeg", outdeg), ("wdeg", wdeg))

@@ -329,6 +329,8 @@ def _fixpoint_program(comps, plans, n, tol, block_v, block_e, interpret, use,
     idents = {c: comps_by_idx[c].ident for c in comps_order}
     p_fns = {c: comps_by_idx[c].p_fn for c in comps_order}
     sorted_res = push_resolution == "sorted" and "push" in use
+    # a full recompute probes has-pred only for a merge that reads it
+    recompute_hp = iterate.recompute_reads_has_pred(plans, comps_by_idx)
 
     def _split(arrays):
         """(ELL dict, out_deg, wdeg, resolution arrays|None, rest)."""
@@ -363,7 +365,16 @@ def _fixpoint_program(comps, plans, n, tol, block_v, block_e, interpret, use,
             wdeg.astype(jnp.float32))
         num_edges = jnp.sum(ell[use[0]][3].astype(jnp.float32))
         all_edges = mesh.total(num_edges)   # the switch compares GLOBAL |E|
-        ones_act = jnp.ones(n_pad, jnp.int32)
+        # the per-slot env rectangles P reads do not change in the loop:
+        # gathered once per call, not once per pull sweep
+        env_pull = {}
+        if "pull" in use:
+            dtypes = {cr.idx: s.dtype for cr, s in zip(comps, carry0[0])}
+            keys = _er.env_keys_read(tuple(p_fns[c] for c in comps_order),
+                                     [dtypes[c] for c in comps_order])
+            nbrs, _w, _c, mask, _nnz, s_pos, s_nbr, _live = ell["pull"]
+            env_pull = _er.slot_env(keys, out_deg_pad, wdeg_pad, nbrs.shape,
+                                    (s_pos, s_nbr), nbrs, mask)
 
         def sweep(d, state_d, active_i32, tile_act, need_hp):
             """One fused sweep + its dst-keyed resolution.  Returns
@@ -382,7 +393,8 @@ def _fixpoint_program(comps, plans, n, tol, block_v, block_e, interpret, use,
             if d == "pull":
                 red, hp = _er.fused_ell_sweep(
                     nbrs, weight, capacity, mask, tile_act, states,
-                    active_i32, out_deg_pad, slots=(s_pos, s_nbr), **common)
+                    active_i32, out_deg_pad, slots=(s_pos, s_nbr),
+                    env_slots=env_pull, **common)
                 return red, hp, jnp.float32(0), jnp.float32(0)
             if sorted_res:
                 res_tile_act = _er.resolution_tile_activity(
@@ -466,14 +478,15 @@ def _fixpoint_program(comps, plans, n, tol, block_v, block_e, interpret, use,
                         new_d.update(iterate.plan_merge(p, state_d, red,
                                                         comps_by_idx))
             else:
-                # full recompute (− models): has-pred probe in the same
-                # launch; only all-padding tiles skip.  The epilogue applies
-                # to the combined reduction.
+                # full recompute (− models): every source active, the
+                # has-pred probe in the same launch where the merge reads
+                # it; only all-padding tiles skip.  The epilogue applies to
+                # the combined reduction.
                 d = use[0]
                 work = work + num_edges
                 tiles_static = (ell[d][4] > 0).astype(jnp.int32)
-                red, hp, res_w, gat_w = sweep(d, state_d, ones_act,
-                                              tiles_static, True)
+                red, hp, res_w, gat_w = sweep(d, state_d, None, tiles_static,
+                                              recompute_hp)
                 res_work = res_work + res_w
                 gather_work = gather_work + gat_w
                 tiles = tiles + jnp.sum(tiles_static)

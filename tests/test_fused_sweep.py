@@ -15,7 +15,11 @@ Covers the acceptance contract of the fused execution layer:
 * the compiled-executor cache reuses traced fixpoints across repeats,
 * the live-tile grid: a sweep over a layout's live-tile list gives the
   full grid's candidates bit for bit, the executor takes it only where it
-  is shorter than the grid, and ``ExecStats.grid_steps`` counts its steps.
+  is shorter than the grid, and ``ExecStats.grid_steps`` counts its steps,
+* a full recompute gathers only its state each iteration: the frontier's
+  rectangle is the real-slot mask, the probe runs only where the merge
+  reads it, and the env rectangles of P are built once a call — bit for
+  bit the answers of gathering everything every iteration.
 """
 import numpy as np
 import pytest
@@ -426,6 +430,109 @@ def test_tile_nnz_marks_padding_tiles():
     n_i, n_j = nnz.shape
     want = mask.reshape(n_i, ell.block_v, n_j, ell.block_e).sum(axis=(1, 3))
     np.testing.assert_array_equal(nnz, want)
+
+
+# ---------------------------------------------------------------------------
+# loop-invariant operands: the full recompute gathers only the state
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("need_haspred", [False, True])
+def test_prebuilt_operands_match_gathered(need_haspred):
+    """An all-ones frontier passed as None (the probe's rectangle is the
+    real-slot mask) and an out-degree rectangle built by ``slot_env`` give
+    the gathered operands' reductions, has-pred and candidates bit for
+    bit, and the sweep counts only the gathers it makes itself."""
+    g = rmat_graph(96, 900, seed=7)
+    ell = to_blocked_ell(g)
+    rng = np.random.default_rng(7)
+    state = jnp.asarray(rng.uniform(0, 1, ell.n_pad).astype(np.float32))
+    outdeg = jnp.asarray(rng.integers(1, 5, ell.n_pad).astype(np.float32))
+    slots = (ell.slot_pos, ell.slot_nbr)
+    kw = dict(plans=(((0, "sum"),),), idents={0: 0.0},
+              p_fns={0: lambda env: env["n"] * (0.85 / env["outdeg"])},
+              nv=g.n, slots=slots, return_candidates=True)
+    args = (ell.nbrs, ell.weight, ell.capacity, ell.mask, ell.tile_nnz,
+            {0: state})
+    er.reset_sweep_stats()
+    want = er.fused_ell_sweep(*args, jnp.ones(ell.n_pad, jnp.int32), outdeg,
+                              need_haspred=True, **kw)
+    assert er.SWEEP_STATS["loop_slot_gathers"] == 3
+    env = er.slot_env(frozenset({"outdeg"}), outdeg, None, ell.nbrs.shape,
+                      slots, ell.nbrs, ell.mask)
+    assert er.SWEEP_STATS["hoisted_slot_gathers"] == 1
+    got = er.fused_ell_sweep(*args, None, outdeg, need_haspred=need_haspred,
+                             env_slots=env, **kw)
+    assert er.SWEEP_STATS["loop_slot_gathers"] == 3 + 1
+    assert np.asarray(got[0][0]).tobytes() == np.asarray(want[0][0]).tobytes()
+    assert np.asarray(got[2][0]).tobytes() == np.asarray(want[2][0]).tobytes()
+    if need_haspred:
+        assert np.asarray(got[1][0]).tobytes() == \
+            np.asarray(want[1][0]).tobytes()
+    else:
+        assert got[1] == {} and len(got[2]) == 1
+
+
+def _per_iteration_operands(monkeypatch):
+    """Make the executors' pull sweep gather every operand on every launch,
+    as the full recompute did before its invariant operands left the loop:
+    the frontier from all ones, the has-pred probe always on, the env
+    rectangles of P re-gathered."""
+    sweep = er.fused_ell_sweep
+
+    def per_iteration(srcs, weight, capacity, mask, tile_act, states, active,
+                      outdeg, *, need_haspred=False, env_slots=None, **kw):
+        if active is None:
+            active = jnp.ones(srcs.shape[0], jnp.int32)
+            need_haspred = True
+        return sweep(srcs, weight, capacity, mask, tile_act, states, active,
+                     outdeg, need_haspred=need_haspred, **kw)
+
+    monkeypatch.setattr(er, "fused_ell_sweep", per_iteration)
+
+
+@pytest.mark.parametrize("mutated", [False, True], ids=["built", "deleted"])
+@pytest.mark.parametrize("graph", ["rmat", "uniform"])
+@pytest.mark.parametrize("name", ["PR", "NSP"])
+def test_full_recompute_matches_per_iteration_operands(name, graph, mutated,
+                                                       monkeypatch):
+    """PageRank (an epilogue: no has-pred probe, the out-degree rectangle
+    built once a call) and NSP (no epilogue: the probe stays, on the
+    real-slot mask) answer bit for bit as with every operand gathered each
+    iteration, in as many iterations — also on layouts patched by a delete
+    batch, where the mask and the slot list must still agree, and, for
+    PageRank, on the warm delta path over them."""
+    from repro.graph import mutate
+    from repro.kernels import ops
+    g = rmat_graph(128, 900, seed=5) if graph == "rmat" \
+        else uniform_graph(128, 900, seed=5)
+    prog = fusion.fuse(U.pagerank() if name == "PR" else U.ALL_SPECS[name]())
+    dk = U.handwritten_pagerank(g.n)
+    kw = dict(engine="pallas", fallback=False)
+    runs = [lambda: engine.run_program(g, prog, **kw)]
+    if mutated:
+        prev = engine.run_direct(g, dk, engine="pallas")
+        engine.run_program(g, prog, **kw)
+        src, dst, _w, _c = g.host_edges()
+        g, md = mutate.mutate_edges(g, delete=(src[::100], dst[::100]))
+        assert md.has_deletes and md.patched_layouts > 0
+        ell = blocked_ell_cached(g)
+        real = np.zeros(ell.mask.size, bool)
+        real[np.asarray(ell.slot_pos)] = True
+        assert (real.reshape(ell.mask.shape) == np.asarray(ell.mask)).all()
+        if name == "PR":
+            runs.append(lambda: engine.run_direct(
+                g, dk, engine="pallas", init_state=[np.asarray(prev.value)],
+                delta=np.asarray(md.touched)))
+    er.reset_sweep_stats()
+    got = [run() for run in runs]
+    assert (er.SWEEP_STATS["hoisted_slot_gathers"] > 0) == (name == "PR")
+    ops.clear_executor_cache()
+    _per_iteration_operands(monkeypatch)
+    want = [run() for run in runs]
+    for a, b in zip(got, want):
+        assert a.stats.engine_used == "pallas"
+        assert np.asarray(a.value).tobytes() == np.asarray(b.value).tobytes()
+        assert a.stats.iterations == b.stats.iterations > 1
 
 
 # ---------------------------------------------------------------------------

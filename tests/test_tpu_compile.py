@@ -14,6 +14,8 @@ its Mosaic kernels (``tpu_custom_call``) and fit the chip's HBM.
 The topology is described inside a module fixture — never at import — so
 only the test worker that runs this file loads the TPU compiler.
 """
+import re
+
 import pytest
 
 import jax
@@ -53,7 +55,7 @@ def spec(topo):
 def _compile(fn, *args, kernels=1, operand=None):
     """Compile for the described v5e; the program holds ``kernels`` Mosaic
     kernels, each of them taking ``operand`` (an HLO shape) when given, and
-    fits HBM."""
+    fits HBM.  Returns the optimized HLO text."""
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
     calls = [line for line in text.splitlines()
@@ -65,6 +67,33 @@ def _compile(fn, *args, kernels=1, operand=None):
     used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
     assert used < HBM_BYTES, f"{used / 2 ** 30:.2f} GiB"
+    return text
+
+
+def _loop_lines(text):
+    """The instruction lines of the optimized HLO ``text`` that run inside
+    its ``while`` loops: the bodies and every computation they call."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    callee = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
+                        r"false_computation)=%([\w.\-]+)")
+    todo = [m.group(1) for lines in comps.values() for line in lines
+            if " while(" in line
+            for m in re.finditer(r"body=%([\w.\-]+)", line)]
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            todo += [m.group(1) for line in comps[c]
+                     for m in callee.finditer(line)]
+    return [line for c in seen for line in comps[c]]
 
 
 def _layout(spec, n, width):
@@ -271,3 +300,73 @@ def test_pr_fixpoint_compiles(spec):
     assert live is not None
     _compile(run, *layout, spec((n,), jnp.int32), spec((n,), jnp.float32),
              spec((len(comps),), jnp.int32), operand=f"s32[{n_live}]")
+
+
+def _gap_executor(term, graph):
+    """The single-chip executor of ``term``'s one round and its abstract
+    arguments at a GAP graph's shapes, as ``test_bfs_fixpoint_compiles``
+    and ``test_pr_fixpoint_compiles`` build them."""
+    from repro.core import engine, fusion, iterate, synthesis
+    from repro.kernels import ops
+    n, _width, arcs, classes, _n_live = GAP[graph]
+    round_ = fusion.fuse(term).rounds[0][1]
+    comps, plans = engine._round_runtime(round_,
+                                         synthesis.synthesize_round(round_))
+    use, dense, switch_k, resolution = ops._apply_plan(
+        None, "auto", ops.DENSE_FRONTIER, "auto", "sorted",
+        all(iterate.plan_idempotent(p) for p in plans))
+    run = ops._build_pallas_executor(
+        comps, plans, n, 2 * n + 4, 0.0, er.BLOCK_V, er.BLOCK_E, False, use,
+        dense, switch_k, resolution)
+    return run, use, comps, n, arcs, classes
+
+
+def test_pr_fixpoint_gathers_only_the_rank(spec):
+    """At the GAP kron-15 shapes the PageRank loop gathers one per-slot
+    operand an iteration, the rank vector: one ``[882416]`` slot gather and
+    one scatter into the ``[197132288]``-slot rectangle inside the
+    ``while``, the out-degree rectangle built once before it, and a kernel
+    that writes the sums alone (no has-pred output: the epilogue's merge
+    never reads it)."""
+    from repro.core import usecases
+    n, width, arcs, _classes, n_live = GAP["gap-kron-15"]
+    er.reset_sweep_stats()
+    run, use, comps, *_ = _gap_executor(usecases.pagerank(), "gap-kron-15")
+    assert use == ("pull",)
+    _tiles, layout, _live = _gap_layout(spec, "gap-kron-15")
+    text = _compile(run, *layout, spec((n,), jnp.int32),
+                    spec((n,), jnp.float32), spec((len(comps),), jnp.int32),
+                    operand=f"s32[{n_live}]")
+    assert (er.SWEEP_STATS["loop_slot_gathers"],
+            er.SWEEP_STATS["hoisted_slot_gathers"]) == (1, 1)
+    gather = re.compile(rf"\[{arcs}\]\S* gather\(")
+    scatter = re.compile(rf"\[{n * width}\]\S* scatter\(")
+    loop = _loop_lines(text)
+    assert sum(bool(gather.search(x)) for x in loop) == 1
+    assert sum(bool(scatter.search(x)) for x in loop) == 1
+    lines = text.splitlines()
+    assert sum(bool(gather.search(x)) for x in lines) == 2
+    assert sum(bool(scatter.search(x)) for x in lines) == 2
+    n_g, n_j = n // 128, width // er.BLOCK_E
+    kernel = [x for x in loop if 'custom_call_target="tpu_custom_call"' in x]
+    assert len(kernel) == 1
+    assert re.search(rf"= f32\[{n_g},{n_j},128\]\S* custom-call\(", kernel[0])
+
+
+@pytest.mark.parametrize("query", ["bfs", "sssp"])
+def test_idempotent_fixpoint_hoists_nothing(spec, query):
+    """BFS and SSSP read no per-source env entry: their executors at the
+    GAP kron-15 shapes build no rectangle outside the loop."""
+    from repro.core import usecases
+    er.reset_sweep_stats()
+    run, use, comps, n, arcs, classes = _gap_executor(
+        getattr(usecases, query)(0), "gap-kron-15")
+    tiles, layout, live = _gap_layout(spec, "gap-kron-15")
+    contrib = tuple((spec((r,), jnp.int32), spec((w, r), jnp.int32))
+                    for w, r in classes)
+    jax.jit(run).lower(*layout, *layout, spec((n,), jnp.int32),
+                       spec((n,), jnp.float32), *_slots(spec, arcs), contrib,
+                       tiles, live, spec((len(comps),), jnp.int32))
+    assert use == ("pull", "push")
+    assert er.SWEEP_STATS["hoisted_slot_gathers"] == 0
+    assert er.SWEEP_STATS["loop_slot_gathers"] > 0
